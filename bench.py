@@ -49,7 +49,7 @@ def _serve_main() -> int:
     env grammar: BENCH_MODEL (a decoder/classify member),
     BENCH_ARRIVAL, BENCH_ARRIVAL_RATE, BENCH_REQUESTS, BENCH_SERVE_BUCKETS,
     BENCH_BATCHING, BENCH_DECODE_ATTENTION (gather|paged), BENCH_QUANT
-    (off|int8_w|int8_kv), BENCH_DECODE_BLOCK_PAGES, BENCH_COMPILE_CACHE,
+    (off|int8_w|int8_kv), BENCH_DECODE_BLOCK_PAGES,
     BENCH_METRICS_DIR, BENCH_CONFIG=auto (resolves the <model>@serve
     registry row).  The extras carry decode_attention/quant and the
     worst decode bucket's AOT temp bytes so `obs regress`/`obs diff`
@@ -73,7 +73,6 @@ def _serve_main() -> int:
         quant=os.environ.get("BENCH_QUANT", "off"),
         decode_block_pages=int(
             os.environ.get("BENCH_DECODE_BLOCK_PAGES", "0")),
-        compile_cache=os.environ.get("BENCH_COMPILE_CACHE") or None,
         metrics_dir=os.environ.get("BENCH_METRICS_DIR") or None,
     ).resolve()
     log = lambda m: print(m, file=sys.stderr, flush=True)  # noqa: E731
@@ -143,7 +142,6 @@ def main() -> int:
     # debug/CI escape hatch: BENCH_FORCE_CPU=1 runs the identical protocol
     # on a virtual 8-device CPU mesh (numbers meaningless, plumbing real)
     if os.environ.get("BENCH_FORCE_CPU") == "1":
-        import tpu_hc_bench  # noqa: F401  (JAX version shims before config)
         import jax
 
         jax.config.update("jax_platforms", "cpu")

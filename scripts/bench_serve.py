@@ -2,7 +2,7 @@
 
 ``--mode batching`` (default) is the round-16 acceptance experiment:
 ONE warmed engine (every (batch, seqlen) bucket AOT-compiled once,
-through ``--compile_cache`` when given), ONE identical seeded request
+through the shared compile cache), ONE identical seeded request
 trace, TWO scheduler arms —
 
 - ``static``: the classic control — collect a full batch, run it to
@@ -66,9 +66,10 @@ Env knobs (CI parity with bench.py):
 
 - ``BENCH_MODEL`` (default moe_tiny), ``BENCH_ARRIVAL_RATE``,
   ``BENCH_SERVE_BUCKETS``, ``BENCH_REQUESTS``, ``BENCH_MAX_IN_FLIGHT``,
-  ``BENCH_DECODE_ATTENTION``, ``BENCH_QUANT``, ``BENCH_MODE``,
-  ``BENCH_COMPILE_CACHE`` (a dir makes the zero-recompile assertion
-  measured, not vacuous).
+  ``BENCH_DECODE_ATTENTION``, ``BENCH_QUANT``, ``BENCH_MODE``.  The
+  compile cache is the one every entry point shares
+  (``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``), so the
+  zero-recompile assertion is a measured cache-entry delta.
 
 Usage:
   JAX_PLATFORMS=cpu python scripts/bench_serve.py \
@@ -102,7 +103,6 @@ def _build_cfg(args, **overrides):
         decode_attention=args.decode_attention,
         quant=args.quant,
         decode_block_pages=args.decode_block_pages,
-        compile_cache=args.compile_cache,
         seed=args.seed,
     )
     kw.update(overrides)
@@ -950,11 +950,6 @@ def main() -> int:
     ap.add_argument("--decode_block_pages", type=int,
                     default=int(env("BENCH_DECODE_BLOCK_PAGES", "0")))
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--compile_cache",
-                    default=env("BENCH_COMPILE_CACHE") or None,
-                    help="persistent compile cache dir — makes the "
-                         "post_warmup_compiles=0 assertion a measured "
-                         "cache-entry delta instead of a trivial 0")
     ap.add_argument("--metrics_root", default=None,
                     help="write per-arm metrics dirs here; compare with "
                          "`python -m tpu_hc_bench.obs diff "

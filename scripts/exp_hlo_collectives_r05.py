@@ -30,7 +30,6 @@ import sys
 
 sys.path.insert(0, ".")
 
-import tpu_hc_bench  # noqa: F401, E402  (JAX version shims before config)
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -29,7 +29,6 @@ REPO = Path(__file__).resolve().parent.parent
 
 WORKER = textwrap.dedent("""
     import sys, time
-    import tpu_hc_bench  # noqa: F401  (JAX version shims before config)
     import jax
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_num_cpu_devices", 2)

@@ -75,10 +75,11 @@ def run_config(world: int, fabric: str, model: str, batch: int,
         env.update({
             "JAX_PLATFORMS": "cpu",
             "PYTHONPATH": f"{REPO}:{env.get('PYTHONPATH', '')}",
-            # share the suite's warm XLA executable cache
-            "JAX_COMPILATION_CACHE_DIR": env.get(
-                "JAX_COMPILATION_CACHE_DIR", "/tmp/tpu_hc_bench_jax_cache"),
         })
+        # share the suite's warm XLA executable cache, placed the way a
+        # user would place it (and out of the checkout)
+        env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                       "/tmp/tpu_hc_bench_jax_cache")
         if world > 1:
             env.update({
                 "TPU_HC_BENCH_HOSTFILE": str(hostfile),

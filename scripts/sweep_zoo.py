@@ -54,7 +54,9 @@ def main() -> None:
 
     # (model, batch, extra flags, provenance) rows to run
     if args.from_registry:
-        hardware = args.hardware or registry_mod.hardware_key()
+        # from a child: this process launches the sweep's children and
+        # must never hold the chip itself
+        hardware = args.hardware or registry_mod.hardware_key_from_child()
         rows = registry_mod.load_rows(hardware)
         if not rows:
             print(f"no tuned rows for hardware {hardware!r} "

@@ -6,51 +6,29 @@ operational.  We close that gap with unit tests running on a simulated
 
 NOTE: ``jax_num_cpu_devices`` must be set before the backend initializes,
 hence the config calls at conftest import time (before any test module
-imports build arrays).  On jax stacks predating the option (0.4.x, where
-a bare ``config.update`` raises AttributeError and killed collection of
-the whole suite) the ``tpu_hc_bench._compat`` shim — installed by the
-package import below, BEFORE the config call — reroutes the update to
-the legacy ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` env
-flag, which equally must land before backend init.  No try/except here
-on purpose: if the (shimmed) call still fails, the backend is already
-initialized with the wrong device count, and aborting collection loudly
-beats every mesh test failing with confusing shape errors.
+imports build arrays).  No try/except on purpose: if a call fails, the
+backend is already initialized with the wrong device count, and aborting
+collection loudly beats every mesh test failing with confusing shape
+errors.
 """
 
 import os
 
-import tpu_hc_bench  # noqa: F401  (installs the JAX version shims first)
-import jax
-import pytest
+# Persistent XLA executable cache: the suite's cost is dominated by
+# compiles of 8-device CPU programs, which are identical run to run —
+# a warm cache turns the ~20-min cold lane into a few minutes.  Placed
+# the way a user would place it (utils.compile_cache honors the
+# variable), before jax is imported, and outside the checkout: a tree
+# that fills with cache entries is too large to copy to the chip.
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                      "/tmp/tpu_hc_bench_jax_cache")
+
+import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
-# Persistent XLA executable cache: the suite's cost is dominated by
-# compiles of 8-device CPU programs, which are identical run to run —
-# a warm cache turns the ~20-min cold lane into a few minutes.  Gated
-# on the stack: on 0.4.x jaxlib, *executing* a cache-deserialized
-# CPU executable corrupts the heap (glibc "corrupted double-linked
-# list" abort in the PP/donation programs of test_checkpoint_driver),
-# so warm runs crashed mid-suite — cold compiles are the price of
-# finishing.
-from tpu_hc_bench._compat import CAPABILITIES  # noqa: E402
-
-if CAPABILITIES["persistent_compilation_cache"]:
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/tpu_hc_bench_jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-else:
-    # No cache means EVERY run pays full compiles, and LLVM codegen at
-    # the default -O3 is the bulk of each one.  -O0 codegen keeps IEEE
-    # semantics and the HLO pipeline (fusion/partitioning untouched —
-    # only LLVM's optimization of the emitted kernels is skipped) and
-    # measures ~60% faster on the compile-bound majority of the suite,
-    # against a ~20% runtime penalty on the few conv-runtime-bound
-    # tests — the difference between fitting the CI budget and not.
-    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-             if not f.startswith("--xla_backend_optimization_level")]
-    flags.append("--xla_backend_optimization_level=0")
-    os.environ["XLA_FLAGS"] = " ".join(flags)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
 def pytest_addoption(parser):

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from tpu_hc_bench import flags
-from tpu_hc_bench._compat import CAPABILITIES
 from tpu_hc_bench.train import driver
 
 
@@ -163,10 +162,6 @@ def test_eval_under_pp_matches_dp(mesh8, tmp_path):
                                rtol=1e-4)
 
 
-@pytest.mark.skipif(
-    not CAPABILITIES["partial_auto_shard_map"],
-    reason="this jax's SPMD partitioner cannot compile the partial-manual "
-           "SP eval arm (PartitionId unimplemented)")
 def test_eval_under_sp_matches_dp(mesh8, tmp_path):
     """Round 3: --eval under --sequence_parallel — the (data, seq)
     shard_map eval arm reports the same top-1/loss as DP eval of the same

@@ -225,6 +225,24 @@ def test_plan_grows_one_settled_job_toward_pref():
 # churn
 
 
+def test_local_backend_refuses_an_accelerator(tmp_path, monkeypatch):
+    """LocalBackend simulates a pool with --virtual_devices gangs; on a
+    chip its concurrent children would contend for it (one process per
+    chip), so it refuses — and `fleet run` says so in one line."""
+    import jax
+
+    from tpu_hc_bench.fleet import __main__ as fleet_cli
+    from tpu_hc_bench.fleet.supervisor import LocalBackend
+
+    LocalBackend()                      # the CPU test mesh: fine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        LocalBackend()
+    with pytest.raises(SystemExit, match="fleet run: .*CPU-mesh simulator"):
+        fleet_cli.main(["run", "--demo", "--chips", "2",
+                        "--out", str(tmp_path / "out")])
+
+
 def test_churn_parse_format_roundtrip():
     ev = churn_mod.parse_churn("kill@8:jobA, shrink@14:jobB,arrive@6:c")
     assert [e.op for e in ev] == ["arrive", "kill", "shrink"]  # sorted
@@ -813,9 +831,7 @@ def test_fleet_soak_e2e(tmp_path):
     out = str(tmp_path / "fleet")
     ctl = FleetController(
         DevicePool(8), soak_real_specs(), out,
-        backend=LocalBackend(
-            base_env=env,
-            cache_dir=os.path.join(out, "compile_cache")),
+        backend=LocalBackend(base_env=env),
         churn=events, settle_s=4.0, kill_grace_s=30.0,
         deadline_s=600.0, print_fn=lambda s: None)
     result = ctl.run()
@@ -887,9 +903,7 @@ def test_fleet_soak_e2e(tmp_path):
     out2 = str(tmp_path / "control_fleet")
     ctl2 = FleetController(
         DevicePool(8), soak_real_specs(), out2,
-        backend=LocalBackend(
-            base_env=env,
-            cache_dir=os.path.join(out2, "compile_cache")),
+        backend=LocalBackend(base_env=env),
         churn=churn_mod.parse_churn("arrive@30.5:triv-hi"),
         settle_s=4.0, kill_grace_s=30.0, deadline_s=600.0,
         print_fn=lambda s: None)

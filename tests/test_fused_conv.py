@@ -2,7 +2,7 @@
 
 Runs in Pallas interpreter mode on the CPU backend (same pattern as
 tests/test_flash.py); the performance claims live in BASELINE.md's
-round-3 table (scripts/exp_fused_conv.py on hardware).
+round-3 table.
 """
 
 import jax

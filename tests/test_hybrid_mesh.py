@@ -21,15 +21,6 @@ from tpu_hc_bench.topology import (
     DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS, build_mesh, compute_layout,
 )
 from tpu_hc_bench.train import step as step_mod
-from tpu_hc_bench._compat import CAPABILITIES
-
-# the SP x TP / PP x TP hybrids compose manual shard_map axes with an
-# auto (GSPMD) model axis; the 0.4.x CPU SPMD partitioner rejects the
-# program ("PartitionId instruction is not supported")
-requires_partial_auto = pytest.mark.skipif(
-    not CAPABILITIES["partial_auto_shard_map"],
-    reason="this jax's SPMD partitioner cannot compile "
-           "partial-manual (auto model axis) shard_map programs")
 
 
 def test_build_mesh_composes_minor_axes(devices):
@@ -73,7 +64,6 @@ def _sp_tp_setup(devices, n_devices, tp):
     return state, train_step, dev_batch
 
 
-@requires_partial_auto
 def test_dp_sp_tp_matches_dp_sp(devices):
     """dp2 x sp2 x tp2 (8 devs) == dp2 x sp2 (4 devs): TP transparent."""
     rng = jax.random.PRNGKey(0)
@@ -115,7 +105,6 @@ def _pp_tp_setup(devices, n_devices, tp):
     return params, opt_state, step, dev_batch
 
 
-@requires_partial_auto
 def test_dp_pp_tp_matches_dp_pp(devices):
     """dp2 x pp2 x tp2 (8 devs) == dp2 x pp2 (4 devs)."""
     losses = []
@@ -131,7 +120,6 @@ def test_dp_pp_tp_matches_dp_pp(devices):
     np.testing.assert_allclose(losses[0], losses[1], rtol=1e-4)
 
 
-@requires_partial_auto
 def test_driver_sp_tp_end_to_end(mesh8):
     """--sequence_parallel 2 --model_parallel 2 through run_benchmark."""
     from tpu_hc_bench.train import driver
@@ -148,7 +136,6 @@ def test_driver_sp_tp_end_to_end(mesh8):
     assert np.isfinite(res.final_loss)
 
 
-@requires_partial_auto
 def test_driver_pp_tp_end_to_end(mesh8):
     """--pipeline_parallel 2 --model_parallel 2 through run_benchmark."""
     from tpu_hc_bench.train import driver

@@ -26,6 +26,7 @@ from tpu_hc_bench import flags
 from tpu_hc_bench.obs import metrics as obs_metrics
 from tpu_hc_bench.train import driver
 from tpu_hc_bench.utils import checkpoint as ckpt
+from tpu_hc_bench.utils import compile_cache
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -279,52 +280,66 @@ def test_sigkill_mid_async_save_falls_back_to_complete_step(
 # 4. persistent compile cache resolution + accounting
 
 
-def test_compile_cache_off_disables(tmp_path):
-    cfg = tiny_cfg(compile_cache="off", train_dir=str(tmp_path))
-    assert driver._resolve_compile_cache(cfg, lambda s: None) is None
-
-
-def test_compile_cache_reuses_preconfigured_dir(tmp_path):
+@pytest.fixture()
+def jax_cache_config():
+    """Restore the process's compile-cache config after a test that
+    lets the resolver write it."""
     import jax
 
-    try:
-        old = jax.config.jax_compilation_cache_dir
-    except Exception:
-        old = None
-    pre = str(tmp_path / "pre")
-    jax.config.update("jax_compilation_cache_dir", pre)
-    try:
-        # auto (unset flag): an already-configured cache wins, untouched
-        assert driver._resolve_compile_cache(
-            tiny_cfg(), lambda s: None) == pre
-        # an explicit dir overrides it
-        explicit = str(tmp_path / "mine")
-        out: list[str] = []
-        assert driver._resolve_compile_cache(
-            tiny_cfg(compile_cache=explicit), out.append) == explicit
-        assert jax.config.jax_compilation_cache_dir == explicit
-        assert os.path.isdir(explicit)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    yield
+    jax.config.update("jax_compilation_cache_dir", saved[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      saved[1])
 
 
-def test_compile_cache_auto_without_train_dir_is_off(tmp_path):
+def test_compile_cache_env_wins_and_nothing_else_is_configured(
+        tmp_path, monkeypatch):
     import jax
 
-    try:
-        preconfigured = jax.config.jax_compilation_cache_dir
-    except Exception:
-        preconfigured = None
-    if preconfigured:
-        pytest.skip("harness configured a global compile cache")
-    assert driver._resolve_compile_cache(tiny_cfg(), lambda s: None) is None
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv(compile_cache.ENV_VAR, placed)
+    before = (jax.config.jax_compilation_cache_dir,
+              jax.config.jax_persistent_cache_min_compile_time_secs)
+    assert compile_cache.resolve(None) == placed
+    assert os.path.isdir(placed)
+    # JAX read the variable itself at import; the resolver wrote nothing
+    assert (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs) == before
+    # a path on the flag cannot override the environment: the flag
+    # takes only "off", loudly
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        compile_cache.resolve(str(tmp_path / "mine"))
+    with pytest.raises(ValueError, match="only 'off'"):
+        tiny_cfg(compile_cache=str(tmp_path / "mine"))
+    assert compile_cache.resolve("off") is None
+
+
+def test_compile_cache_default_is_the_checkout(tmp_path, monkeypatch,
+                                               jax_cache_config):
+    import jax
+
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    want = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    assert compile_cache.default_dir() == want
+    # identical across calls, with and without --train_dir: never a
+    # per-run directory (the path is part of the cache key)
+    first = compile_cache.resolve(tiny_cfg().compile_cache)
+    second = compile_cache.resolve(
+        tiny_cfg(train_dir=str(tmp_path / "run")).compile_cache)
+    assert first == second == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert not (tmp_path / "run").exists()
 
 
 def test_cache_entry_count(tmp_path):
     (tmp_path / "sub").mkdir()
     (tmp_path / "a").write_text("x")
     (tmp_path / "sub" / "b").write_text("y")
-    assert driver._cache_entry_count(str(tmp_path)) == 2
+    assert compile_cache.entry_count(str(tmp_path)) == 2
 
 
 def test_update_manifest_merges(tmp_path):

@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 
 from tpu_hc_bench import flags
-from tpu_hc_bench._compat import CAPABILITIES
 from tpu_hc_bench.data.synthetic import SyntheticTokens
 from tpu_hc_bench.models import create_model
 from tpu_hc_bench.models.moe import MoEFFN, top_k_routing
@@ -115,12 +114,7 @@ def test_ep_matches_replicated(devices):
         for _ in range(3):
             state, metrics = train_step(state, batch, rng)
         losses.append(float(jax.device_get(metrics["loss"])))
-    # the 0.4.x SPMD partitioner computes the expert-sharded forward with
-    # a ~0.7% systematic loss offset vs the replicated arm (from step 0);
-    # the modern partitioner is exact to 1e-4 — keep the wiring signal on
-    # both stacks at the tolerance each can meet
-    rtol = 1e-4 if CAPABILITIES["exact_gspmd_numerics"] else 2e-2
-    np.testing.assert_allclose(losses[0], losses[1], rtol=rtol)
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-4)
 
 
 def test_ragged_matches_einsum_no_drops():

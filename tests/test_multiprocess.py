@@ -22,14 +22,6 @@ import pytest
 # price of the advertised `pytest` command actually exercising the
 # distributed path (round-3 verdict, next-round item 8).
 
-from tpu_hc_bench._compat import CAPABILITIES
-
-pytestmark = pytest.mark.skipif(
-    not CAPABILITIES["cpu_multiprocess_collectives"],
-    reason="this jax's CPU backend cannot execute cross-process "
-           "collectives (XLA: 'Multiprocess computations aren't "
-           "implemented on the CPU backend')")
-
 REPO = Path(__file__).resolve().parent.parent
 
 WORKER = textwrap.dedent("""

@@ -444,11 +444,10 @@ def test_no_bytecode_tracked_in_git():
     assert "__pycache__/" in gitignore and "*.pyc" in gitignore
 
 
-def test_exp_scripts_have_no_local_perfetto_parsing():
-    """The acceptance check: both trace experiment scripts are thin
-    consumers of obs.trace, with no trace-parsing code of their own."""
-    for script in ("exp_vit_trace.py", "exp_moe_trace_r05.py"):
-        src = (REPO / "scripts" / script).read_text()
-        assert "obs.trace import" in src, script
-        assert "traceEvents" not in src, script
-        assert "trace.json.gz" not in src, script
+def test_scripts_have_no_local_perfetto_parsing():
+    """obs.trace is the one home of trace parsing: no script under
+    scripts/ carries a perfetto parser of its own."""
+    for path in sorted((REPO / "scripts").glob("*.py")):
+        src = path.read_text()
+        assert "traceEvents" not in src, path.name
+        assert "trace.json.gz" not in src, path.name

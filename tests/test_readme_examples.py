@@ -54,3 +54,21 @@ def test_readme_cli_example_parses(argv):
     get_model_spec(cfg.model)          # model name must exist in the zoo
     if pos:
         resolve_fabric(pos[3])         # the launcher's own validator
+
+
+@pytest.mark.slow
+def test_chip_smoke_refuses_without_a_chip(tmp_path):
+    """chip_smoke.py is the proof the system starts ON THE CHIP: with
+    none it exits non-zero and prints no result line (no CPU fallback)."""
+    import os
+    import subprocess
+    import sys
+
+    proc = subprocess.run(
+        [sys.executable, str(README.parent / "chip_smoke.py"), "--out",
+         str(tmp_path)],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode != 0
+    assert "refusing to fall back" in proc.stdout
+    assert '"ok"' not in proc.stdout

@@ -479,6 +479,42 @@ def test_fetcher_propagates_original_error_not_hang(mesh8):
     assert "_run" in frames
 
 
+def test_fetcher_coalesces_completed_markers_only():
+    """The loop dispatches far ahead of the device, so most queued
+    markers are steps still running: the fetch thread may skip over a
+    marker that has already completed, never over one that has not —
+    otherwise every timed interval is a multi-step window (what the
+    first run on a local chip measured: granularity 10, not 1)."""
+    import threading
+
+    entered, gate = threading.Event(), threading.Event()
+
+    class Marker:
+        def __init__(self, ready, blocks=False):
+            self.ready, self.blocks = ready, blocks
+
+        def is_ready(self):
+            return self.ready
+
+        def __array__(self, dtype=None):
+            if self.blocks:
+                entered.set()
+                assert gate.wait(10)
+            return np.zeros(())
+
+    fetcher = driver._ArrivalFetcher()
+    fetcher.put(0, Marker(True, blocks=True))
+    assert entered.wait(10)     # the thread is inside marker 0's fetch
+    for i, ready in ((1, True), (2, True), (3, False), (4, False)):
+        fetcher.put(i, Marker(ready))
+    gate.set()
+    steps = [i for i, _, _ in fetcher.finish()]
+    # 1 is skipped for 2 (both done); 3 and 4 were still running when
+    # the thread looked, so each is timed on its own
+    assert steps == [0, 2, 3, 4]
+    assert [i for i, _ in fetcher.skipped] == [1]
+
+
 def test_fetcher_record_surfaces_error(mesh8):
     import jax.numpy as jnp
 

@@ -11,18 +11,9 @@ import subprocess
 import sys
 from pathlib import Path
 
-import pytest
-
-from tpu_hc_bench._compat import CAPABILITIES
-
 REPO = Path(__file__).resolve().parent.parent
 
 
-@pytest.mark.skipif(
-    not CAPABILITIES["cpu_multiprocess_collectives"],
-    reason="this jax's CPU backend cannot execute cross-process "
-           "collectives; the surviving rank hangs until the harness "
-           "timeout (same gate as tests/test_multiprocess.py)")
 def test_scaling_harness_two_process_cell(tmp_path):
     out_dir = tmp_path / "scaling"
     r = subprocess.run(

@@ -697,13 +697,13 @@ def test_serve_cli_end_to_end(tmp_path):
 @pytest.mark.slow
 def test_bench_serve_ab_harness(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
                BENCH_ARRIVAL_RATE="40", BENCH_REQUESTS="16",
                BENCH_SERVE_BUCKETS="auto")
     proc = subprocess.run(
         [sys.executable, "scripts/bench_serve.py",
          "--max_prompt_len", "8", "--max_output_len", "4",
          "--max_in_flight", "2", "--kv_page_size", "4",
-         "--compile_cache", str(tmp_path / "cc"),
          "--metrics_root", str(tmp_path / "ab")],
         capture_output=True, text=True, env=env, timeout=570,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))

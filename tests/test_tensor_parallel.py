@@ -11,15 +11,11 @@ import numpy as np
 import pytest
 
 from tpu_hc_bench import flags
-from tpu_hc_bench._compat import CAPABILITIES
 
-# the 0.4.x SPMD partitioner computes the TP-sharded forward with a
-# systematic loss offset vs the replicated arm (~0.9% for bert, ~6% for
-# vit; same mechanism as the EP arm in test_moe); the modern partitioner
-# is exact to 1e-4 — keep the wiring signal on both stacks at the
-# tolerance each can meet (a band that still catches NaN/garbage)
-TP_RTOL = 1e-4 if CAPABILITIES["exact_gspmd_numerics"] else 2e-2
-VIT_TP_RTOL = 1e-4 if CAPABILITIES["exact_gspmd_numerics"] else 1.5e-1
+# the TP-sharded forward matches the replicated arm to the GSPMD
+# partitioner's reassociation error
+TP_RTOL = 1e-4
+VIT_TP_RTOL = 1e-4
 from tpu_hc_bench.data.synthetic import SyntheticTokens
 from tpu_hc_bench.models import create_model
 from tpu_hc_bench.topology import MODEL_AXIS, build_mesh, compute_layout

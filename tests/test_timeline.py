@@ -421,8 +421,14 @@ def test_regress_mad_adapts_to_noisy_history(tmp_path):
     assert rc == 0
 
 
-def test_regress_parses_repo_bench_wrapper():
-    rec = regress.load_bench_record(str(REPO / "BENCH_r05.json"))
+def test_regress_parses_driver_bench_wrapper(tmp_path):
+    # the driver's wrapper shape: the bench.py record under "parsed",
+    # beside the command, its exit code and the captured tail
+    path = tmp_path / "BENCH_r00.json"
+    path.write_text(json.dumps({
+        "n": 0, "cmd": "python bench.py", "rc": 0, "tail": "...",
+        "parsed": _bench_rec(2669.89)}))
+    rec = regress.load_bench_record(str(path))
     assert rec is not None and rec["value"] > 0
     assert regress.fingerprint(rec)[0].startswith("resnet50")
 
@@ -696,10 +702,6 @@ def test_two_rank_run_merges_one_trace(tmp_path):
     import sys as _sys
     import textwrap
 
-    from tpu_hc_bench._compat import CAPABILITIES
-
-    if not CAPABILITIES["cpu_multiprocess_collectives"]:
-        pytest.skip("CPU backend lacks cross-process collectives")
     script = tmp_path / "worker.py"
     script.write_text(textwrap.dedent(_MERGE_WORKER))
     hostfile = tmp_path / "nodeips.txt"

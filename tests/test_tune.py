@@ -592,11 +592,8 @@ def test_full_batch_identity_flag_parses():
 def test_shard_batch_local_identity_at_world_one(mesh8):
     # world=1: the local rows ARE the global batch, so the sliced path
     # must place bitwise-identical arrays to the device_put path
-    from tpu_hc_bench._compat import CAPABILITIES
     from tpu_hc_bench.train import step as step_mod
 
-    if not CAPABILITIES["process_local_arrays"]:
-        pytest.skip("jax lacks make_array_from_process_local_data")
     mesh = mesh8
     rng = np.random.default_rng(0)
     batch = (rng.standard_normal((16, 4, 4, 3)).astype(np.float32),

@@ -70,9 +70,60 @@ def test_hostfile_parsing(tmp_path):
 
 
 def test_peak_flops_table():
-    # CPU test devices fall into the nominal row
+    # the CPU test mesh has its own (nominal) row
     assert hw.peak_flops(dtype="bfloat16") > 0
     assert hw.peak_flops(dtype="float32") > 0
+
+
+def test_peak_flops_is_exact_match_and_unknown_raises():
+    class Fake:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    # what the v5e reports
+    assert hw.peak_flops(Fake("TPU v5 lite")) == 197e12
+    assert hw.peak_flops(Fake("TPU v5 lite"), dtype="float32") == 98e12
+    # a kind a substring match would have waved through, and one it
+    # would have handed the CPU's nominal figure
+    for kind in ("TPU v5 lite pod", "Some Future Chip"):
+        with pytest.raises(KeyError, match="no peak-FLOPs row"):
+            hw.peak_flops(Fake(kind))
+
+
+def test_pallas_interprets_on_cpu_only(monkeypatch):
+    from tpu_hc_bench.ops import _pallas
+
+    assert _pallas.interpret() is True          # the CPU test mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _pallas.interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        _pallas.interpret()
+
+
+def test_lanes_refuse_a_cpu_nobody_asked_for(tmp_path, monkeypatch):
+    """JAX only warns when it finds no TPU and falls back to the CPU;
+    both lanes must stop there instead of benchmarking it."""
+    from tpu_hc_bench import launcher
+    from tpu_hc_bench.serve import cli as serve_cli
+
+    monkeypatch.setenv("HOME", str(tmp_path))
+    # the backend query says cpu (it is), and nothing asked for it
+    monkeypatch.setattr(hw, "_requested_platforms", lambda: "")
+    with pytest.raises(RuntimeError, match="CPU was not asked for"):
+        launcher.main(["1", "0", "2", "ici", "--model=trivial",
+                       "--num_batches=1"])
+    with pytest.raises(RuntimeError, match="CPU was not asked for"):
+        serve_cli.main(["--model=trivial", "--num_requests=1"],
+                       print_fn=lambda m: None)
+    # the two ways of asking: --virtual_devices, or naming the platform
+    hw.require_accelerator(virtual_devices=8)
+    monkeypatch.setattr(hw, "_requested_platforms", lambda: "cpu")
+    hw.require_accelerator()
+    # an accelerator that is not a TPU is refused either way
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        hw.require_accelerator(virtual_devices=8)
 
 
 def test_ici_topology_lines():

@@ -57,6 +57,12 @@ def _quiet(_msg):
     pass
 
 
+def _pool(x):
+    """``[..., pages, ps, kvh, d]`` (the reference's token-major order)
+    -> the kernel's head-major pool ``[..., kvh, pages, ps, d]``."""
+    return jnp.asarray(np.moveaxis(x, -2, -4))
+
+
 def _gather_reference(q, k_pages, v_pages, tables, lengths):
     """Dense-gather reference in serve.decode._softmax_attend's exact
     convention (page gather -> GQA repeat -> masked f32 softmax)."""
@@ -93,7 +99,7 @@ def test_paged_kernel_matches_gather_reference(b, heads, kvh, d, pages,
     tables = rng.integers(0, pages, (b, w)).astype(np.int32)
     lengths = rng.integers(1, w * ps + 1, (b,)).astype(np.int32)
     out = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _pool(kp), _pool(vp),
         jnp.asarray(tables), jnp.asarray(lengths), pages_per_block=ppb)
     want = _gather_reference(q, kp, vp, tables, lengths)
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-5)
@@ -114,7 +120,7 @@ def test_paged_kernel_lse_merges_fresh_token():
     vf = rng.standard_normal((b, heads, d)).astype(np.float32)
 
     out, lse = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _pool(kp), _pool(vp),
         jnp.asarray(tables), jnp.asarray(lengths), return_lse=True)
     s_new = np.einsum("bhd,bhd->bh", q, kf) / d ** 0.5
     w_new = np.asarray(jax.nn.sigmoid(jnp.asarray(
@@ -151,7 +157,7 @@ def test_paged_kernel_int8_within_tolerance():
     tables = rng.integers(0, pages, (b, w)).astype(np.int32)
     lengths = rng.integers(1, w * ps + 1, (b,)).astype(np.int32)
     out = paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq),
+        jnp.asarray(q), _pool(kq), _pool(vq),
         jnp.asarray(tables), jnp.asarray(lengths), layer=1,
         k_scales=jnp.asarray(ks.astype(np.float32)),
         v_scales=jnp.asarray(vs.astype(np.float32)),
@@ -171,12 +177,12 @@ def test_paged_kernel_validation_loud():
     z = jnp.zeros
     with pytest.raises(ValueError, match="kv_heads"):
         paged_decode_attention(
-            z((1, 3, 8)), z((4, 4, 2, 8)), z((4, 4, 2, 8)),
+            z((1, 3, 8)), z((2, 4, 4, 8)), z((2, 4, 4, 8)),
             z((1, 2), jnp.int32), z((1,), jnp.int32))
     with pytest.raises(ValueError, match="scales"):
         paged_decode_attention(
-            z((1, 2, 8)), z((4, 4, 2, 8), jnp.int8),
-            z((4, 4, 2, 8), jnp.int8),
+            z((1, 2, 8)), z((2, 4, 4, 8), jnp.int8),
+            z((2, 4, 4, 8), jnp.int8),
             z((1, 2), jnp.int32), z((1,), jnp.int32))
 
 
@@ -335,10 +341,10 @@ def test_int8_append_ignores_recycled_page_garbage():
     rows would otherwise inflate the fresh token's quantization scale
     arbitrarily (reads stay masked; precision is what's at stake)."""
     L, pages, ps, kvh, d = 1, 3, 4, 1, 4
-    pages_q = jnp.zeros((L, pages, ps, kvh, d), jnp.int8)
+    pages_q = jnp.zeros((L, kvh, pages, ps, d), jnp.int8)
     # page 2: previous occupant left full-range int8 rows at a scale
     # 1000x the new request's values
-    pages_q = pages_q.at[0, 2].set(127)
+    pages_q = pages_q.at[0, :, 2].set(127)
     scales = jnp.ones((L, pages), jnp.float32).at[0, 2].set(100.0)
     new = jnp.full((L, 1, kvh, d), 0.125, jnp.float32)  # tiny fresh K
     out_q, out_sc = decode_mod._append_quantized(
@@ -346,10 +352,10 @@ def test_int8_append_ignores_recycled_page_garbage():
         jnp.array([0], jnp.int32), new)
     # scale reflects ONLY the fresh row, not the 12700.0 stale garbage
     assert float(out_sc[0, 2]) == pytest.approx(0.125 / 127.0)
-    got = np.asarray(out_q[0, 2, 0], np.float32) * float(out_sc[0, 2])
+    got = np.asarray(out_q[0, :, 2, 0], np.float32) * float(out_sc[0, 2])
     np.testing.assert_allclose(got, 0.125, rtol=0.02)
     # stale rows were zeroed, not requantized garbage
-    assert (np.asarray(out_q[0, 2, 1:]) == 0).all()
+    assert (np.asarray(out_q[0, :, 2, 1:]) == 0).all()
 
 
 def test_regress_fingerprint_back_compat_with_pre_r18_history():

@@ -18,6 +18,4 @@ See SURVEY.md at the repo root for the full structural mapping with
 file:line citations into the reference.
 """
 
-from tpu_hc_bench import _compat  # noqa: F401  (installs JAX version shims)
-
 __version__ = "0.1.0"

@@ -209,11 +209,4 @@ def main(argv: list[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
-    rc = main()
-    # hard-exit: 0.4.x jaxlib can segfault in interpreter teardown after
-    # a lowering (model-dependent; `trivial` reproduces it), which would
-    # overwrite the gate's verdict with 139 — flush and skip teardown so
-    # the exit code is always the comparison result
-    sys.stdout.flush()
-    sys.stderr.flush()
-    os._exit(rc)
+    raise SystemExit(main())

@@ -407,13 +407,12 @@ class BenchmarkConfig:
                                               # io_error@ckpt injection,
                                               # multi-host, and PP saves stay
                                               # synchronous (driver)
-    compile_cache: str | None = None          # persistent XLA compile cache
-                                              # dir.  unset = auto: reuse an
-                                              # already-configured jax cache,
-                                              # else <train_dir>/compile_cache
-                                              # on stacks where the cache is
-                                              # safe; "off" disables; an
-                                              # explicit dir is always honored
+    compile_cache: str | None = None          # persistent XLA compile cache:
+                                              # unset = on, at
+                                              # $JAX_COMPILATION_CACHE_DIR or
+                                              # <checkout>/.jax_cache
+                                              # (utils.compile_cache); "off"
+                                              # is the only value it takes
     prefetch_depth: int = 2                   # host->device input pipeline
                                               # lookahead (real-data runs):
                                               # batches kept in flight so
@@ -1139,8 +1138,11 @@ class BenchmarkConfig:
                     "training input plane)")
                 self.input_service = "off"
         # --compile_cache stays filesystem-pure here (same principle as
-        # --fabric_ceiling): the driver resolves auto/off and creates the
-        # directory at run start
+        # --fabric_ceiling): only the value is checked; the lanes resolve
+        # and create the directory at run start
+        from tpu_hc_bench.utils.compile_cache import check_flag
+
+        check_flag(self.compile_cache)
         if self.hbm_budget is not None:
             from tpu_hc_bench.obs.memory import parse_hbm_budget
 
@@ -1360,7 +1362,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--async_checkpoint", type=_parse_bool,
                    default=d.async_checkpoint)
     p.add_argument("--compile_cache", type=str, default=d.compile_cache,
-                   metavar="DIR|off")
+                   metavar="off")
     p.add_argument("--prefetch_depth", type=int, default=d.prefetch_depth)
     p.add_argument("--input_service", type=str, default=d.input_service,
                    choices=["on", "off", "auto"])

@@ -62,10 +62,13 @@ def _cmd_run(args, out) -> int:
         print(f"seeded churn ({args.churn_seed}): "
               f"{churn_mod.format_churn(events)}", file=out)
     pool = DevicePool(args.chips)
+    try:
+        backend = LocalBackend()
+    except RuntimeError as e:
+        raise SystemExit(f"fleet run: {e}")
     ctl = FleetController(
         pool, specs, args.out,
-        backend=LocalBackend(
-            cache_dir=os.path.join(args.out, "compile_cache")),
+        backend=backend,
         churn=events,
         tick_s=args.tick_s, settle_s=args.settle_s,
         kill_grace_s=args.kill_grace_s,

@@ -114,19 +114,29 @@ class _LocalHandle:
 
 
 class LocalBackend:
-    """Real subprocess jobs on this host's device pool (virtual CPU
-    devices in the container — each job gets ``--virtual_devices=
-    <world>``, its granted gang).  ``base_env`` extends os.environ for
-    every job (the soak pins ``JAX_PLATFORMS=cpu``).  ``cache_dir``
-    is a fleet-shared ``--compile_cache``: a relaunch at a world any
-    fleet job has compiled before pays a cache load, not a recompile —
-    the PR-5 persistent cache is what keeps the restart tax of
+    """Real subprocess jobs on a SIMULATED device pool: each job gets
+    ``--virtual_devices=<world>`` CPU devices, its granted gang.  A
+    chip belongs to one process at a time, so on an accelerator backend
+    the concurrent children would contend for it — construction refuses
+    there.  ``base_env`` extends os.environ for every job (the soak
+    pins ``JAX_PLATFORMS=cpu``).  The children share the one compile
+    cache every entry point resolves (``utils.compile_cache``): a
+    relaunch at a world any fleet job has compiled before pays a cache
+    load, not a recompile — what keeps the restart tax of
     preempt/shrink/grow from eating the goodput the scheduler wins."""
 
-    def __init__(self, base_env: dict | None = None,
-                 cache_dir: str | None = None):
+    def __init__(self, base_env: dict | None = None):
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"fleet LocalBackend is a CPU-mesh simulator "
+                f"(--virtual_devices gangs) and this process sees the "
+                f"{backend!r} backend: its concurrent children would "
+                f"contend for the chip (one process per chip); run it "
+                f"with JAX_PLATFORMS=cpu")
         self.base_env = dict(base_env or {})
-        self.cache_dir = cache_dir
 
     def launch(self, spec: JobSpec, world: int, resume: str,
                run_dir: str, incarnation: int) -> _LocalHandle:
@@ -142,11 +152,6 @@ class LocalBackend:
             f"--save_model_steps={spec.save_every}",
             *spec.flags,
         ]
-        if self.cache_dir:
-            from tpu_hc_bench._compat import CAPABILITIES
-
-            if CAPABILITIES["persistent_compilation_cache"]:
-                flags.append(f"--compile_cache={self.cache_dir}")
         # f32 end to end: the soak's bitwise fingerprint proof needs
         # deterministic params; members that want fp16 say so in flags
         cmd = runner_mod.build_cmd(
@@ -435,8 +440,7 @@ class FleetController:
         self.pool = pool
         self.out_dir = out_dir
         os.makedirs(out_dir, exist_ok=True)
-        self.backend = backend if backend is not None else LocalBackend(
-            cache_dir=os.path.join(out_dir, "compile_cache"))
+        self.backend = backend if backend is not None else LocalBackend()
         self.churn = sorted(churn or [])
         self._churn_applied = [False] * len(self.churn)
         self.now_fn = now_fn

@@ -271,8 +271,7 @@ class ResNet(nn.Module):
                                         # run the stem as a 4x4/s1 conv — the
                                         # standard TPU stem transform (3-ch
                                         # 7x7/s2 convs map poorly to the MXU)
-    barrier: str = "none"               # fusion-split experiment knob
-                                        # (scripts/exp_resnet_mfu.py):
+    barrier: str = "none"               # fusion-split experiment knob:
                                         # pre  = barrier conv-out -> BN-in
                                         # post = barrier BN-out -> act/conv
                                         # both = both edges

@@ -4,10 +4,8 @@ The runtime counterpart of the static ``tpu_hc_bench.analysis`` package.
 Where ``analysis`` inspects the *compiled program* (HLO, jaxpr),
 ``obs`` inspects *runs*:
 
-- ``obs.trace`` — reusable perfetto-trace analysis promoted out of the
-  one-off experiment scripts (``scripts/exp_vit_trace.py``,
-  ``scripts/exp_moe_trace_r05.py``): leaf-op extraction with the
-  same-tid containment rule, op classification, per-step timeline
+- ``obs.trace`` — reusable perfetto-trace analysis: leaf-op extraction
+  with the same-tid containment rule, op classification, per-step timeline
   reconstruction, and compute/collective/host-transfer/idle-bubble
   bucket attribution.
 - ``obs.metrics`` — the per-run artifact: a ``metrics.jsonl`` stream of

@@ -9,8 +9,8 @@ lifecycle, and folding those records (plus the resilience events of
 ``tpu_hc_bench.resilience``) yields a wall-clock account —
 
 - ``init``           backend/layout/model/data construction
-- ``compile``        the warmup loop (includes XLA compile; with
-                     ``--compile_cache`` warm starts collapse this to
+- ``compile``        the warmup loop (includes XLA compile; a warm
+                     compile cache collapses this to
                      trace/lower + cache loads — the AOT cost-analysis
                      probe runs on a background thread and is not
                      billed here)

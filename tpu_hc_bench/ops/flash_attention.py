@@ -18,7 +18,7 @@ saved per-row logsumexp — the standard flash-attention backward:
     dV  += P^T dO;   dS = P * (dO V^T - D);   dQ += dS K;   dK += dS^T Q
 
 Accumulation is always float32 regardless of input dtype (bf16-safe).  On
-non-TPU backends the kernels run in Pallas interpreter mode, which is how
+the CPU backend the kernels run in Pallas interpreter mode, which is how
 the unit tests exercise them on the virtual CPU mesh.
 """
 

@@ -4,8 +4,8 @@ The ResNet bottleneck's hot pattern is ``conv -> BN -> relu -> conv``:
 in training, the producer conv's raw output must be materialized (its BN
 statistics aren't ready until the whole tensor exists), but the
 *normalize + relu + next conv* consumption can run in one pass.  The
-round-3 measurement (`scripts/exp_fused_conv.py`, closing VERDICT item
- #1's conv question left open by the round-2 matmul proxy) showed XLA
+round-3 measurement (closing VERDICT item #1's conv question left open
+by the round-2 matmul proxy) showed XLA
 fuses this well at stage-1 shapes (56x56x64: fused/xla = 1.07, no win)
 but NOT at wider channels:
 
@@ -195,7 +195,7 @@ fused_bn_relu_conv.defvjp(_fwd, _bwd)
 
 def eligible(shape: tuple, kernel: tuple, strides, cin: int) -> bool:
     """Where the kernel beats XLA — the measured win region (round-3
-    A/B, `scripts/exp_fused_conv.py` at bs=128):
+    A/B at bs=128):
 
         56x56x 64: 1.07x (XLA already fuses; stays on XLA)
         28x28x128: 0.65x  WIN

@@ -13,7 +13,7 @@ GPT's ``LayerNorm`` (mean/variance, scale+bias) and Llama's ``RMSNorm``
 Numerics match the Flax modules they replace (``nn.LayerNorm`` fast
 variance ``E[x^2] - E[x]^2`` clamped at 0; ``models.llama.RMSNorm``'s
 f32 stats) — pinned by ``tests/test_zz_decode_kernels.py``.  Stats always
-accumulate in float32.  Non-TPU backends run the Pallas interpreter
+accumulate in float32.  The CPU backend runs the Pallas interpreter
 (``ops._pallas.interpret``), same as every kernel in this package.
 """
 

@@ -2,8 +2,11 @@
 
 The serving lane's decode step attends one fresh query token per
 request over that request's KV cache, which lives in a shared *paged*
-pool (``serve.decode``: ``[layers, pages, page_size, kv_heads,
-head_dim]`` plus an int32 page table per request).  The round-16
+pool (``serve.decode``: ``[layers, kv_heads, pages, page_size, lanes]``
+plus an int32 page table per request; ``lanes`` is ``head_dim`` padded
+to the 128-lane tile, so one (page, kv head) is a contiguous
+tile-aligned ``(page_size, lanes)`` slab — the only slice shape Mosaic
+accepts for an HBM->VMEM page copy).  The round-16
 reference path gathers every request's pages into a dense
 worst-case-length ``[b, S, heads, d]`` temporary and runs a plain
 softmax — the single hottest per-token cost in the lane, and all of it
@@ -34,12 +37,13 @@ kv head's slice of each page is fetched.
 
 **Int8 KV** (``--quant=int8_kv``): the pool may be int8 with one f32
 scale per (layer, page), written at prefill/append time
-(``serve.decode``).  Scales ride the scalar-prefetch channel and the
-dequantize happens *inside* the kernel, fused with the score/value
+(``serve.decode``).  The layer's row of scales rides the
+scalar-prefetch channel (SMEM holds ``[pages]``, not ``[layers,
+pages]``) and the dequantize happens *inside* the kernel, fused with the score/value
 matmuls — never a dense ``astype`` of the cache in the layer loop (the
 ``dequantize-in-hot-loop`` lint exists to keep it that way).
 
-Accumulation is always float32.  On non-TPU backends the kernel runs in
+Accumulation is always float32.  On the CPU backend the kernel runs in
 Pallas interpreter mode (``ops._pallas.interpret``), which is how the
 parity tests pin it against the gather reference on the CPU mesh.
 """
@@ -92,10 +96,10 @@ def _kernel(tables_ref, lengths_ref, k_scales_ref, v_scales_ref,
             page = tables_ref[b, j * ppb + i]
             rows = pl.ds(i * page_size, page_size)
             out.append(pltpu.make_async_copy(
-                k_pool.at[layer, page, :, h, :],
+                k_pool.at[layer, h, page],
                 k_buf.at[rows, :], sem.at[0, i]))
             out.append(pltpu.make_async_copy(
-                v_pool.at[layer, page, :, h, :],
+                v_pool.at[layer, h, page],
                 v_buf.at[rows, :], sem.at[1, i]))
         return out
 
@@ -112,9 +116,9 @@ def _kernel(tables_ref, lengths_ref, k_scales_ref, v_scales_ref,
                 page = tables_ref[b, j * ppb + i]
                 rows = pl.ds(i * page_size, page_size)
                 ks.append(k_buf[rows, :].astype(jnp.float32)
-                          * k_scales_ref[layer, page])
+                          * k_scales_ref[page])
                 vs.append(v_buf[rows, :].astype(jnp.float32)
-                          * v_scales_ref[layer, page])
+                          * v_scales_ref[page])
             k = ks[0] if ppb == 1 else jnp.concatenate(ks, axis=0)
             v = vs[0] if ppb == 1 else jnp.concatenate(vs, axis=0)
         else:
@@ -163,13 +167,16 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
 
     Args:
       q: ``[b, heads, head_dim]`` — one query token per request row.
-      k_pages, v_pages: ``[layers, pages, page_size, kv_heads,
-        head_dim]`` pool (a 4-D single-layer pool is accepted too).
-        Passing the WHOLE pool with a static ``layer`` index matters:
-        the pool stays an ``ANY``-space operand the kernel DMAs pages
-        out of — a ``k_pages[l]`` slice at the call site would
-        materialize a per-layer pool copy as a temp.  f32/bf16, or
-        int8 with ``*_scales``.
+      k_pages, v_pages: ``[layers, kv_heads, pages, page_size,
+        lanes]`` pool, ``lanes >= head_dim`` (a 4-D single-layer pool
+        is accepted too).  Lanes past ``head_dim`` must hold zeros
+        (``serve.decode`` pads to the 128-lane tile; q is zero-padded
+        to match and the output sliced back).  Passing the WHOLE pool
+        with a static ``layer`` index matters: the pool stays an
+        ``ANY``-space operand the kernel DMAs pages out of — a
+        ``k_pages[l]`` slice at the call site would materialize a
+        per-layer pool copy as a temp.  f32/bf16, or int8 with
+        ``*_scales``.
       tables: ``[b, w]`` int32 page tables (slot t holds tokens
         ``t*page_size..``); every slot must hold a valid pool index
         (the serving engine's trash page 0 covers unused slots).
@@ -195,7 +202,10 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
             k_scales, v_scales = k_scales[None], v_scales[None]
         layer = 0
     b, heads, d = q.shape
-    _, pages, page_size, kv_heads, _ = k_pages.shape
+    _, kv_heads, pages, page_size, lanes = k_pages.shape
+    if lanes < d:
+        raise ValueError(f"pool lanes={lanes} narrower than "
+                         f"head_dim={d}")
     layer = int(layer)
     w = tables.shape[1]
     if heads % kv_heads:
@@ -214,32 +224,38 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
         w += pad
     nb = w // ppb
 
-    qg = q.reshape(b, kv_heads, group, d)
-    if not quantized:
+    # zero lanes add nothing to q.k, and the pool's zero lanes leave
+    # the output's pad lanes zero: slice them off after the call
+    qg = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - d))).reshape(
+        b, kv_heads, group, lanes)
+    if quantized:
+        # only this layer's row rides SMEM
+        k_scales, v_scales = k_scales[layer], v_scales[layer]
+    else:
         # dummy f32 scales keep ONE kernel signature; never read
-        k_scales = v_scales = jnp.ones((1, 1), jnp.float32)
+        k_scales = v_scales = jnp.ones((1,), jnp.float32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, kv_heads, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d),
+            pl.BlockSpec((1, 1, group, lanes),
                          lambda b_, h, j, tbl, ln, ks, vs: (b_, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),       # k pool (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),       # v pool (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),          # k pool (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),          # v pool (HBM)
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, group, d),
+            pl.BlockSpec((1, 1, group, lanes),
                          lambda b_, h, j, tbl, ln, ks, vs: (b_, h, 0, 0)),
             pl.BlockSpec((1, 1, group, 1),
                          lambda b_, h, j, tbl, ln, ks, vs: (b_, h, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((ppb * page_size, d), k_pages.dtype),  # k block
-            pltpu.VMEM((ppb * page_size, d), v_pages.dtype),  # v block
+            pltpu.VMEM((ppb * page_size, lanes), k_pages.dtype),  # k block
+            pltpu.VMEM((ppb * page_size, lanes), v_pages.dtype),  # v block
             pltpu.VMEM((group, 1), jnp.float32),       # running max
             pltpu.VMEM((group, 1), jnp.float32),       # running sum
-            pltpu.VMEM((group, d), jnp.float32),       # output acc
+            pltpu.VMEM((group, lanes), jnp.float32),   # output acc
             pltpu.SemaphoreType.DMA((2, ppb)),         # k/v page fetches
         ],
     )
@@ -250,13 +266,13 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, kv_heads, group, d), q.dtype),
+            jax.ShapeDtypeStruct((b, kv_heads, group, lanes), q.dtype),
             jax.ShapeDtypeStruct((b, kv_heads, group, 1), jnp.float32),
         ],
         interpret=_interpret(),
         compiler_params=_PARAMS,
     )(tables, lengths, k_scales, v_scales, qg, k_pages, v_pages)
-    out = out.reshape(b, heads, d)
+    out = out.reshape(b, heads, lanes)[..., :d]
     if return_lse:
         return out, lse.reshape(b, heads)
     return out

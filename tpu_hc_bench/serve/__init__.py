@@ -16,8 +16,8 @@ the two techniques the related work canonized:
   rather than worst-case sequence slabs (``serve.decode``).
 
 Everything runs over a small ladder of AOT-compiled ``(batch, seqlen)``
-bucket shapes, warmed at startup through the training lane's
-``--compile_cache`` and the ``obs.efficiency`` lowering path — after
+bucket shapes, warmed at startup through the shared persistent compile
+cache and the ``obs.efficiency`` lowering path — after
 warmup the engine only ever calls AOT executables, so a mid-traffic
 recompile is structurally impossible (an off-ladder shape raises).
 SLO reporting (p50/p95/p99 TTFT + end-to-end, queue depth, tokens/s,

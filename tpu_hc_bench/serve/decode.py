@@ -14,8 +14,11 @@ with ``model.apply`` over the full context is pinned by
 ``tests/test_serve.py`` and ``tests/test_zz_decode_kernels.py``.
 
 **Paged KV cache** (vLLM): one pool of fixed-size pages per run,
-``k_pages``/``v_pages`` shaped ``[layers, pages, page_size, kv_heads,
-head_dim]``.  A request holds a page *table* (int32 page indices); the
+``k_pages``/``v_pages`` shaped ``[layers, kv_heads, pages, page_size,
+lanes]`` with ``lanes`` = ``head_dim`` zero-padded to the 128-lane
+tile — one (page, kv head) is a contiguous tile-aligned slab, the only
+page slice the TPU compiler will DMA (``ops.paged_attention``).  A
+request holds a page *table* (int32 page indices); the
 decode step reads its keys through the table and scatters the new
 token's K/V into ``table[pos // page]``.  Page 0 is the reserved
 *trash* page: padded/inactive rows write there (and are masked on
@@ -78,6 +81,7 @@ from tpu_hc_bench.ops.paged_attention import paged_decode_attention
 
 _NEG_INF = -1e30
 _QUANT_EPS = 1e-8
+_LANES = 128        # TPU lane tile: the pool's minor dim is a multiple
 
 QUANT_ARMS = ("off", "int8_w", "int8_kv")
 DECODE_ATTENTION_ARMS = ("gather", "paged")
@@ -384,10 +388,18 @@ def quantize_weights(family: _Family, params: dict) -> dict:
 
 def init_kv_pages(family: _Family, num_pages: int, page_size: int,
                   dtype) -> tuple[jax.Array, jax.Array]:
-    """The zeroed page pool: ``[L, pages, page_size, kv_heads, d]`` x2."""
-    shape = (family.num_layers, num_pages, page_size, family.kv_heads,
-             family.head_dim)
+    """The zeroed page pool: ``[L, kv_heads, pages, page_size, lanes]``
+    x2, ``lanes`` = head_dim padded up to the 128-lane tile."""
+    shape = (family.num_layers, family.kv_heads, num_pages, page_size,
+             _pad_up(family.head_dim, _LANES))
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def _pool_rows(new, lanes: int):
+    """``[L, n, kvh, d]`` fresh K/V rows -> ``[L, kvh, n, lanes]``: the
+    pool's head-major order, head_dim zero-padded to the pool's lanes."""
+    new = jnp.swapaxes(new, 1, 2)
+    return jnp.pad(new, ((0, 0),) * 3 + ((0, lanes - new.shape[-1]),))
 
 
 def init_kv_state(family: _Family, num_pages: int, page_size: int,
@@ -406,19 +418,23 @@ def build_page_copy_fn():
     """The copy-on-write program (round 25): duplicate physical page
     ``src`` into ``dst`` across every KV leaf, all layers at once.
 
-    Every carry leaf — f32/int8 pools ``[L, pages, ps, kvh, d]`` AND
-    the int8_kv per-(layer, page) scale planes ``[L, pages]`` — indexes
-    pages on axis 1, so one tree_map covers both quant arms; an int8
-    page is copied in its final quantized layout, scale and all (no
-    dequant round-trip).  Args at call time: ``(kv, src [], dst [])``;
+    The f32/int8 pools ``[L, kvh, pages, ps, lanes]`` index pages on
+    axis 2 and the int8_kv per-(layer, page) scale planes ``[L,
+    pages]`` on axis 1; one tree_map covers both quant arms, and an
+    int8 page is copied in its final quantized layout, scale and all
+    (no dequant round-trip).  Args at call time: ``(kv, src [], dst [])``;
     one AOT program per engine (page count is baked into the pool
     shapes, not the program), warmed beside the decode buckets so a
     first mid-traffic COW is never a compile.
     """
 
     def page_copy(kv, src, dst):
-        return jax.tree_util.tree_map(
-            lambda x: x.at[:, dst].set(x[:, src]), kv)
+        def copy(x):
+            if x.ndim == 2:                         # scale plane
+                return x.at[:, dst].set(x[:, src])
+            return x.at[:, :, dst].set(x[:, :, src])
+
+        return jax.tree_util.tree_map(copy, kv)
 
     return page_copy
 
@@ -441,7 +457,10 @@ def _write_quantized_chunks(pages_q, scales, new, table, length,
     sc = jnp.maximum(amax / 127.0, _QUANT_EPS)              # [L, c]
     q = jnp.clip(jnp.round(chunks / sc[:, :, None, None, None]),
                  -127, 127).astype(jnp.int8)
-    return pages_q.at[:, cpage].set(q), scales.at[:, cpage].set(sc)
+    q = jnp.pad(q.transpose(0, 3, 1, 2, 4),         # [L, kvh, c, ps, d]
+                ((0, 0),) * 4 + ((0, pages_q.shape[-1] - q.shape[-1]),))
+    return (pages_q.at[:, :, cpage].set(q),
+            scales.at[:, cpage].set(sc))
 
 
 def _append_quantized(pages_q, scales, page_idx, offset, new):
@@ -456,19 +475,20 @@ def _append_quantized(pages_q, scales, page_idx, offset, new):
     masked either way; the fresh row's precision is what's at stake)."""
     b = page_idx.shape[0]
     rows = jnp.arange(b)
-    old = pages_q[:, page_idx]                      # [L, b, ps, kvh, d]
-    sc = scales[:, page_idx]                        # [L, b]
-    page = old.astype(jnp.float32) * sc[..., None, None, None]
-    page_size = page.shape[2]
+    old = pages_q[:, :, page_idx]               # [L, kvh, b, ps, lanes]
+    sc = scales[:, page_idx][:, None, :, None, None]        # [L,1,b,1,1]
+    page = old.astype(jnp.float32) * sc
+    page_size = page.shape[3]
     own = (jnp.arange(page_size)[None, :]
            <= offset[:, None])                      # [b, ps]
-    page = jnp.where(own[None, :, :, None, None], page, 0.0)
-    page = page.at[:, rows, offset].set(new.astype(jnp.float32))
-    amax = jnp.max(jnp.abs(page), axis=(2, 3, 4))
+    page = jnp.where(own[None, None, :, :, None], page, 0.0)
+    page = page.at[:, :, rows, offset].set(
+        _pool_rows(new.astype(jnp.float32), page.shape[-1]))
+    amax = jnp.max(jnp.abs(page), axis=(1, 3, 4))           # [L, b]
     new_sc = jnp.maximum(amax / 127.0, _QUANT_EPS)
-    q = jnp.clip(jnp.round(page / new_sc[..., None, None, None]),
+    q = jnp.clip(jnp.round(page / new_sc[:, None, :, None, None]),
                  -127, 127).astype(jnp.int8)
-    return (pages_q.at[:, page_idx].set(q),
+    return (pages_q.at[:, :, page_idx].set(q),
             scales.at[:, page_idx].set(new_sc))
 
 
@@ -545,8 +565,11 @@ def build_prefill_fn(family: _Family, page_size: int, table_width: int,
             pos < length,
             table[jnp.clip(pos // page_size, 0, table_width - 1)], 0)
         offset = pos % page_size
-        k_pages = k_pages.at[:, page_idx, offset].set(kn)
-        v_pages = v_pages.at[:, page_idx, offset].set(vn)
+        lanes = k_pages.shape[-1]
+        k_pages = k_pages.at[:, :, page_idx, offset].set(
+            _pool_rows(kn, lanes))
+        v_pages = v_pages.at[:, :, page_idx, offset].set(
+            _pool_rows(vn, lanes))
         return next_token, logits, (k_pages, v_pages)
 
     return prefill
@@ -594,9 +617,19 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
                 v_pages, v_scales, page_idx, offset, vn)
             return k_pages, v_pages, k_scales, v_scales
         k_pages, v_pages = kv
-        k_pages = k_pages.at[:, page_idx, offset].set(kn)
-        v_pages = v_pages.at[:, page_idx, offset].set(vn)
+        lanes = k_pages.shape[-1]
+        k_pages = k_pages.at[:, :, page_idx, offset].set(
+            _pool_rows(kn, lanes))
+        v_pages = v_pages.at[:, :, page_idx, offset].set(
+            _pool_rows(vn, lanes))
         return k_pages, v_pages
+
+    def gather_cache(layer_pages, tables):
+        """``[kvh, pages, ps, lanes]`` through ``tables [b, w]`` -> the
+        dense ``[b, span, kvh, d]`` cache rows (pad lanes dropped)."""
+        rows = layer_pages[:, tables][..., :family.head_dim]
+        return rows.transpose(1, 2, 3, 0, 4).reshape(
+            tables.shape[0], -1, family.kv_heads, family.head_dim)
 
     def decode_gather(params, kv, tokens, tables, lengths, active):
         k_pages, v_pages = kv
@@ -614,10 +647,8 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
             q, k, v = family.qkv(p_l, h, lengths[:, None])
             new_k.append(k[:, 0])
             new_v.append(v[:, 0])
-            kc = k_pages[l][tables].reshape(
-                b, span, family.kv_heads, family.head_dim)
-            vc = v_pages[l][tables].reshape(
-                b, span, family.kv_heads, family.head_dim)
+            kc = gather_cache(k_pages[l], tables)
+            vc = gather_cache(v_pages[l], tables)
             keys = jnp.concatenate([kc, k], axis=1)
             values = jnp.concatenate([vc, v], axis=1)
             if group > 1:

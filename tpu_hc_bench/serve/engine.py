@@ -7,8 +7,8 @@ half).  Design constraints, in order:
    — one prefill program per prompt-length bucket, one decode program
    per batch bucket, one classify program per batch bucket — is
    AOT-compiled at construction through ``obs.efficiency.aot_compile``
-   (the ``StepFlopsProbe`` lowering path, so ``--compile_cache`` warms
-   them across runs).  After warmup the engine only calls AOT
+   (the ``StepFlopsProbe`` lowering path, so the persistent compile
+   cache warms them across runs).  After warmup the engine only calls AOT
    executables: an off-ladder shape *raises* instead of recompiling,
    and the ``serve-bucket-recompile`` analysis lint guards the source
    so no jit/lower call site creeps into the traffic path.  Measured
@@ -339,9 +339,9 @@ class ServeEngine:
         import jax.numpy as jnp
 
         from tpu_hc_bench.models import get_model_spec, create_model
-        from tpu_hc_bench.train.driver import (
-            _cache_entry_count, _resolve_compile_cache)
+        from tpu_hc_bench.utils import compile_cache, hw
 
+        hw.require_accelerator(cfg.virtual_devices)
         if cfg.workload != "serve":
             raise ValueError(
                 "ServeEngine needs a workload='serve' config (use "
@@ -352,10 +352,10 @@ class ServeEngine:
         self._jnp = jnp
 
         # persistent compile cache first, so the warmup compiles hit or
-        # populate it (the round-10 mechanism, reused verbatim)
-        self.cache_dir = _resolve_compile_cache(cfg, print_fn)
+        # populate it (the same resolver as the training lane)
+        self.cache_dir = compile_cache.resolve(cfg.compile_cache)
         self._count_cache = (
-            (lambda: _cache_entry_count(self.cache_dir))
+            (lambda: compile_cache.entry_count(self.cache_dir))
             if self.cache_dir else (lambda: 0))
         entries_before = self._count_cache()
 
@@ -482,6 +482,11 @@ class ServeEngine:
                 arm += (f"; worst decode bucket AOT temp "
                         f"{tb / 2**20:.1f} MiB")
             print_fn(arm)
+        devs = jax.local_devices()
+        print_fn(
+            f"serve device: {devs[0].device_kind} d{devs[0].id}"
+            + (f" (1 of {len(devs)} local devices: the serve lane is "
+               f"single-device)" if len(devs) > 1 else ""))
         kinds = collections.Counter(k for k, _ in self.compiled)
         print_fn(
             "serve warmup: "

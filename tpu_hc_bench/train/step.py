@@ -87,8 +87,8 @@ def make_train_state(
     """Initialize params on host-side abstract init, then TrainState."""
     rng = rng if rng is not None else jax.random.PRNGKey(cfg.seed)
     inputs = example_batch[0]
-    # jit the whole init: one compiled program instead of hundreds of eager
-    # ops (eager dispatch is pathological over remote/tunneled devices)
+    # jit the whole init: one compiled program instead of hundreds of
+    # eager dispatches
     init_fn = jax.jit(functools.partial(model.init, train=False))
     variables = init_fn(
         {"params": rng, "dropout": jax.random.fold_in(rng, 1)},
@@ -1117,9 +1117,8 @@ def shard_batch_local(batch: tuple, mesh: Mesh,
     never hold).  Here each process passes only its own rows and
     ``jax.make_array_from_process_local_data`` assembles the global
     array.  At world=1 the two are identical (the local rows ARE the
-    global batch).  Callers gate on
-    ``_compat.CAPABILITIES["process_local_arrays"]`` and fall back to
-    ``shard_batch`` (the driver's ``--full_batch_identity`` arm).
+    global batch).  ``shard_batch`` stays as the driver's
+    ``--full_batch_identity`` arm.
     """
     from tpu_hc_bench.topology import DCN_AXIS
 

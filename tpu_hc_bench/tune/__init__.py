@@ -45,6 +45,7 @@ from tpu_hc_bench.tune.space import (  # noqa: F401
 )
 from tpu_hc_bench.tune.registry import (  # noqa: F401
     hardware_key,
+    hardware_key_from_child,
     lookup,
     promote,
 )

@@ -34,7 +34,15 @@ def _cmd_search(args) -> int:
     from tpu_hc_bench.tune import registry as registry_mod
     from tpu_hc_bench.tune import search as search_mod
 
-    hardware = args.hardware or registry_mod.hardware_key()
+    # this process launches the measurement children and must never
+    # hold the chip itself (one process per chip): the hardware key comes
+    # from a child, and whatever this process traces on its own (the
+    # lint prune) is pinned to the CPU — through the config, which the
+    # children do not inherit
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    hardware = args.hardware or registry_mod.hardware_key_from_child()
     models = []
     for m in args.model or []:
         models.extend(m.split(","))
@@ -127,7 +135,7 @@ def _cmd_show(args) -> int:
             journal = json_mod.load(f)
         _render_journal(journal)
         return 0
-    hardware = args.hardware or registry_mod.hardware_key()
+    hardware = args.hardware or registry_mod.hardware_key_from_child()
     rows = registry_mod.load_rows(hardware, args.registry)
     path = registry_mod.registry_path(hardware, args.registry)
     if not rows:

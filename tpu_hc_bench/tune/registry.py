@@ -31,7 +31,8 @@ from pathlib import Path
 
 __all__ = [
     "REGISTRY_ENV", "HW_ENV", "default_registry_dir", "registry_path",
-    "hardware_key", "load_rows", "lookup", "promote", "resolve_auto",
+    "hardware_key", "hardware_key_from_child", "load_rows", "lookup",
+    "promote", "resolve_auto",
 ]
 
 REGISTRY_ENV = "TPU_HC_TUNE_REGISTRY"
@@ -69,6 +70,34 @@ def hardware_key(world: int | None = None) -> str:
         pass
     w = world if world is not None else jax.device_count()
     return f"{kind}-{hbm_gb}gb-w{w}"
+
+
+def hardware_key_from_child() -> str:
+    """``hardware_key()`` without initializing a backend in THIS
+    process: a chip belongs to one process at a time, and the tuner and
+    sweep parents go on to launch children that need it.  A short-lived
+    child queries the backend and has exited — released the chip —
+    before this returns.  The ``TPU_HC_TUNE_HW`` pin needs no child."""
+    import subprocess
+    import sys
+
+    pinned = os.environ.get(HW_ENV)
+    if pinned:
+        return pinned
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(Path(__file__).resolve().parents[2]),
+         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "from tpu_hc_bench.tune import registry; "
+         "print(registry.hardware_key())"],
+        env=env, capture_output=True, text=True, timeout=600)
+    if probe.returncode != 0:
+        raise RuntimeError(
+            f"hardware-key probe child failed (exit {probe.returncode}):"
+            f"\n{probe.stderr[-2000:]}")
+    return probe.stdout.strip().splitlines()[-1]
 
 
 def registry_path(hardware: str,

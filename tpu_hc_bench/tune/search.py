@@ -7,8 +7,8 @@ The search protocol::
     -> rung 1: re-measure 2x longer -> ... until one survivor, the rung
     cap, or the wall-clock budget.
 
-All measurements share one ``--compile_cache`` dir (the PR-5 persistent
-cache), so the marginal candidate costs its steps, not its compile —
+All measurements share the one persistent compile cache every entry
+point resolves (``utils.compile_cache``), so the marginal candidate costs its steps, not its compile —
 the thing that makes a budgeted search affordable at all.
 
 State lives in ``<out_dir>/tune_state.json`` and is committed after
@@ -77,16 +77,12 @@ class SearchSettings:
 
 def _default_runner(model: str, out_dir: str,
                     settings: SearchSettings) -> Callable:
-    """The real subprocess runner: one shared compile cache, one
-    metrics dir per (candidate, rung) so goodput feeds the score."""
-    from tpu_hc_bench._compat import CAPABILITIES
-
-    cache_dir = os.path.join(out_dir, "compile_cache")
+    """The real subprocess runner: one metrics dir per (candidate,
+    rung) so goodput feeds the score.  The children share the one
+    compile cache every entry point resolves (utils.compile_cache)."""
 
     def run(c: Candidate, rung: int, batches: int) -> dict:
         flags = c.to_flags()
-        if CAPABILITIES["persistent_compilation_cache"]:
-            flags.append(f"--compile_cache={cache_dir}")
         mdir = os.path.join(out_dir, "runs",
                             f"{c.key.replace('/', '_')}-r{rung}")
         return runner_mod.run_one(

@@ -3,39 +3,61 @@
 The reference never computes MFU (its metric is raw images/sec); the
 BASELINE.json north star for this repo is ">=60% MFU on v5e", so the driver
 needs peak numbers.  Figures are the public per-chip peak dense-matmul
-rates (bf16 / fp32-equivalent) for each TPU generation; CPU gets a nominal
-figure so MFU stays defined (if meaningless) on the test mesh.
+rates (bf16 / fp32-equivalent), keyed by the exact ``device_kind`` string
+JAX reports.  A kind that is not in the table is an error, not a default:
+an MFU against the wrong peak is worse than no MFU.
 """
 
 from __future__ import annotations
 
 import jax
 
-# (bf16_peak_flops, fp32_peak_flops) per chip
+# device_kind -> (bf16_peak_flops, fp32_peak_flops) per chip
 _PEAKS: dict[str, tuple[float, float]] = {
-    "v5 lite": (394e12, 197e12),   # v5e: 394 TFLOPs int8/bf16-class MXU,
-                                   # 197 TFLOPs bf16 — use (197, 98) conservatively
-    "v5litepod": (197e12, 98e12),
-    "v5e": (197e12, 98e12),
-    "v5p": (459e12, 229e12),
-    "v4": (275e12, 137e12),
-    "v3": (123e12, 61e12),
-    "v2": (45e12, 22e12),
-    "v6": (918e12, 459e12),        # v6e (Trillium)
-    "cpu": (1e11, 5e10),           # nominal, test-mesh only
+    "TPU v5 lite": (197e12, 98e12),    # v5e
+    "TPU v5": (459e12, 229e12),        # v5p
+    "TPU v4": (275e12, 137e12),
+    "TPU v3": (123e12, 61e12),
+    "TPU v2": (45e12, 22e12),
+    "TPU v6 lite": (918e12, 459e12),   # v6e (Trillium)
+    "cpu": (1e11, 5e10),               # nominal: the virtual test mesh only
 }
-# v5e correction: bf16 peak is 197 TFLOPs/chip; keep the conservative row.
-_PEAKS["v5 lite"] = (197e12, 98e12)
 
 
 def peak_flops(device: jax.Device | None = None, dtype: str = "bfloat16") -> float:
-    """Best-effort peak FLOPs/s for one chip of this device kind."""
+    """Peak FLOPs/s for one chip of this device's kind; raises KeyError
+    on a kind the table does not hold."""
     device = device or jax.devices()[0]
-    kind = device.device_kind.lower()
-    for key, (bf16, f32) in _PEAKS.items():
-        if key in kind:
-            return bf16 if dtype == "bfloat16" else f32
-    return _PEAKS["cpu"][0 if dtype == "bfloat16" else 1]
+    try:
+        bf16, f32 = _PEAKS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak-FLOPs row for device_kind={device.device_kind!r} "
+            f"(known: {sorted(_PEAKS)}); add the sourced figure to "
+            f"tpu_hc_bench/utils/hw.py rather than guess") from None
+    return bf16 if dtype == "bfloat16" else f32
+
+
+def require_accelerator(virtual_devices: int = 0) -> None:
+    """Refuse to carry on without the chip: raise unless the backend is
+    ``tpu``, or the CPU was asked for (``JAX_PLATFORMS``/``jax_platforms``
+    names it, or ``--virtual_devices``).  JAX itself only warns and falls
+    back to the CPU when it finds no TPU; a benchmark that then runs to
+    exit 0 reports CPU numbers under device names."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return
+    if backend == "cpu" and (virtual_devices or "cpu" in _requested_platforms()):
+        return
+    raise RuntimeError(
+        f"JAX initialised the {backend!r} backend, not 'tpu', and the CPU "
+        f"was not asked for (JAX_PLATFORMS={_requested_platforms()!r}, no "
+        f"--virtual_devices): refusing to benchmark a device nobody "
+        f"chose.  Set JAX_PLATFORMS=cpu for a CPU run.")
+
+
+def _requested_platforms() -> str:
+    return jax.config.jax_platforms or ""
 
 
 def device_kind() -> str:
