@@ -1,0 +1,32 @@
+# The chip call that reads what the limits are set from (PERF.md section 2),
+# one process per cell, a dozen seeds each for the serve cells (the program,
+# and the bf16_all control on the same rows and tokens; fp8 on the first
+# three), three for the train cell with its fp8 control and its planted
+# fault; every side through checks.verdict and the cell's own limits.
+#   chiprun --timeout 2400 -- bash benchmarks/tools/call_readings.sh [cells]
+out=chiprun_out/readings; mkdir -p $out
+cells=${*:-chat burst train}
+for c in $cells; do
+  case $c in
+    chat)  w=gpt2m-serve-chat;  seeds=411,412,413,414,415,416,417,418,419,420,421,3000000422; extra="--also fp8" ;;
+    burst) w=gpt2m-serve-burst; seeds=431,432,433,434,435,436,437,438,439,440,441,3000000442; extra="--also fp8" ;;
+    train) w=gpt2m-train-1k;    seeds=71,72,3000000073; extra="--control_seeds 3" ;;
+  esac
+  t0=$(date +%s)
+  python3 benchmarks/tools/readings.py --workload $w --seeds $seeds --seconds 15 $extra > $out/$c.out 2> $out/$c.err
+  echo "$c rc $? wall $(( $(date +%s) - t0 )) s"
+  grep '^{"reading"' $out/$c.out > $out/$c.jsonl
+  python3 - $out/$c.jsonl <<'PY'
+import json, sys
+for l in open(sys.argv[1]):
+    r = json.loads(l)
+    sides = {k: v for k, v in r.items() if isinstance(v, dict) and "correct" in v}
+    print(r["seed"], {k: (v["correct"], {n: round(x["value"], 7) if isinstance(x["value"], float) else x["value"]
+                                          for n, x in v["compared"].items() if x["limit"] is not None})
+                      for k, v in sides.items()},
+          {k: round(v, 4) for k, v in r.get("stats", {}).get("program", {}).items()
+           if k in ("stated_error_share", "distance_from_stated")},
+          r.get("sampled"), round(r.get("reference_s", 0), 1))
+PY
+  tail -n 3 $out/$c.err | cut -c1-300
+done
