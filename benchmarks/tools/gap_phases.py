@@ -1,0 +1,157 @@
+#!/usr/bin/env python3
+"""Names the device's idle gaps of a kept trace by what the PROGRAM says
+it was doing: the ``hc:`` spans that ``tpu_hc_bench.obs.timeline`` writes
+into the profiler's trace (the serve loop's phases among them).
+
+    BENCH_KEEP_TRACE=1 python3 benchmarks/run.py --workload W ... --trace 1
+    python3 benchmarks/tools/gap_phases.py .bench_work/trace [--chips N]
+
+The trace is reduced by the harness's own ``xplane.reduce_trace``; each
+idle gap of its first chip is then charged twice: whole, to the INNERMOST
+``hc:`` span open when the gap began (spans nest: the one that started
+last among those open), and split, second by second, among the innermost
+spans open while it lasted.  A gap begins while the host still waits for
+the program that just ended, so ``began in`` reads ``decode_wait`` where
+``split`` shows who held the device up afterwards.  Prints a table and,
+last, one JSON line.
+"""
+
+from __future__ import annotations
+
+import argparse
+import bisect
+import json
+import os
+import sys
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [BENCH_DIR]
+
+from harness import xplane  # noqa: E402
+
+PREFIX = "hc:"
+NO_SPAN = "no span open"
+
+
+def program_spans(profile) -> list[tuple[str, float, float]]:
+    """``(name, start_s, end_s)`` of every ``hc:`` event of the host
+    planes, prefix stripped, by start."""
+    out = []
+    for plane in profile.planes:
+        if xplane.DEVICE_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(PREFIX):
+                    out.append((e.name[len(PREFIX):], e.start_ns * 1e-9,
+                                (e.start_ns + e.duration_ns) * 1e-9))
+    return sorted(out, key=lambda s: s[1])
+
+
+def innermost_points(spans) -> list[tuple[float, str]]:
+    """Change points ``(t, label)``: from ``t`` until the next point the
+    innermost open span (the one that started last among those open) is
+    ``label``.  ``spans`` are sorted by start, so the greatest open index
+    is the innermost."""
+    edges = sorted([(s, 0, i) for i, (_, s, _) in enumerate(spans)]
+                   + [(e, 1, i) for i, (_, _, e) in enumerate(spans)])
+    open_now: set[int] = set()
+    points: list[tuple[float, str]] = []
+    for t, closing, i in edges:
+        (open_now.discard if closing else open_now.add)(i)
+        label = spans[max(open_now)][0] if open_now else NO_SPAN
+        if points and points[-1][0] == t:
+            points[-1] = (t, label)
+        elif not points or points[-1][1] != label:
+            points.append((t, label))
+    return points
+
+
+def charge_gaps(gaps, spans) -> dict[str, dict]:
+    """phase -> idle seconds ``began_in_s`` / ``split_s`` and the number
+    of gaps that began in it."""
+    points = innermost_points(spans)
+    times = [t for t, _ in points]
+    out: dict[str, dict] = {}
+
+    def row(k):
+        return out.setdefault(
+            points[k][1] if k >= 0 else NO_SPAN,
+            {"began_in_s": 0.0, "gaps": 0, "split_s": 0.0})
+
+    for g0, g1 in gaps:
+        k = bisect.bisect_right(times, g0) - 1
+        first = row(k)
+        first["began_in_s"] += g1 - g0
+        first["gaps"] += 1
+        t = g0
+        while k + 1 < len(times) and times[k + 1] < g1:
+            row(k)["split_s"] += times[k + 1] - t
+            k, t = k + 1, times[k + 1]
+        row(k)["split_s"] += g1 - t
+    return out
+
+
+def span_totals(spans, t0: float, t1: float) -> dict[str, list]:
+    """phase -> [spans, seconds] inside the traced window."""
+    out: dict[str, list] = {}
+    for name, s, e in spans:
+        s, e = max(s, t0), min(e, t1)
+        if e > s:
+            acc = out.setdefault(name, [0, 0.0])
+            acc[0] += 1
+            acc[1] += e - s
+    return out
+
+
+def report(profile, chips: int | None = None) -> dict:
+    red = xplane.reduce_trace(profile, chips)
+    spans = program_spans(profile)
+    idle = sum(g1 - g0 for g0, g1 in red["gaps"])
+    phases = charge_gaps(red["gaps"], spans)
+    totals = span_totals(spans, red["t0"], red["t0"] + red["window_s"])
+    for name, (n, secs) in totals.items():
+        phases.setdefault(
+            name, {"began_in_s": 0.0, "gaps": 0, "split_s": 0.0}
+        ).update(spans=n, span_s=secs)
+    named = idle - phases.get(NO_SPAN, {}).get("split_s", 0.0)
+    return {"window_s": red["window_s"], "busy_s": red["busy_s"],
+            "idle_s": idle, "gaps": len(red["gaps"]),
+            "idle_named_share": named / idle if idle else None,
+            "phases": phases}
+
+
+def table(rep: dict) -> list[str]:
+    lines = [f"traced {rep['window_s']:.3f} s, busy {rep['busy_s']:.3f} s, "
+             f"idle {rep['idle_s']:.4f} s in {rep['gaps']} gaps; "
+             f"under a span: "
+             + ("-" if rep["idle_named_share"] is None
+                else f"{100 * rep['idle_named_share']:.1f}%"),
+             f"{'phase':<18}{'idle split s':>13}{'share %':>9}"
+             f"{'began in s':>12}{'gaps':>6}{'spans':>7}{'span s':>9}"]
+    idle = rep["idle_s"] or 1.0
+    for name, p in sorted(rep["phases"].items(),
+                          key=lambda kv: -kv[1]["split_s"]):
+        lines.append(
+            f"{name:<18}{p['split_s']:>13.4f}{100 * p['split_s'] / idle:>9.1f}"
+            f"{p['began_in_s']:>12.4f}{p['gaps']:>6}"
+            f"{p.get('spans', 0):>7}{p.get('span_s', 0.0):>9.3f}")
+    return lines
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("trace", help="a kept trace directory, or an "
+                    ".xplane.pb / .xplane.txt file")
+    ap.add_argument("--chips", type=int, default=None)
+    args = ap.parse_args()
+    path = (xplane.find_xplane(args.trace) if os.path.isdir(args.trace)
+            else args.trace)
+    rep = report(xplane.load(path), args.chips)
+    print("\n".join(table(rep)))
+    print(json.dumps(rep))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -435,6 +435,28 @@ def test_span_registry_lint_flags_typo():
     assert "KNOWN_SPANS" in found[0].message
 
 
+def test_span_registry_lint_reads_phase_names():
+    """``Phases.enter`` names phases (and their ``parent=``) the way
+    ``span`` names spans: a literal outside the registry is flagged, a
+    computed one (``kind + "_wait"``) is the caller's contract."""
+    src = """
+from tpu_hc_bench.obs import timeline as timeline_mod
+def f(kind):
+    phases = timeline_mod.Phases()
+    phases.enter("retire")
+    phases.enter("retir")
+    phases.enter("decode_wait", parent="decod")
+    phases.enter(kind + "_wait", parent=kind)
+    phases.enter(None)
+    other.enter("not_a_phase")
+"""
+    found = [f.message for f in lints.lint_source_text(
+        src, filename="tpu_hc_bench/serve/engine.py")
+        if f.lint == lints.SPAN_REGISTRY]
+    assert len(found) == 2
+    assert "'retir'" in found[0] and "'decod'" in found[1]
+
+
 def test_span_registry_lint_skips_variables_and_foreign_calls():
     src = """
 from tpu_hc_bench.obs import timeline as timeline_mod

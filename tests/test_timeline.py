@@ -78,6 +78,82 @@ def test_span_context_manager_and_instant():
     assert spans[1]["name"] == "mark" and spans[1]["t0"] == spans[1]["t1"]
 
 
+def test_span_keeps_its_clock_pair_whatever_the_switch():
+    rec = tl.SpanRecorder(capacity=4)
+    rec.enabled = False
+    with rec.span("work") as sp:
+        time.sleep(0.001)
+    assert sp.t1 - sp.t0 >= 0.001 and rec.tail() == []
+
+
+def test_live_span_is_in_the_profiler_trace(tmp_path):
+    """The second sink: a live ``span()`` is an ``hc:<name>`` event in
+    an open profiler session, lasting what its ring record lasts;
+    ``record_span`` (after the fact) is ring-only, and a disabled
+    recorder writes neither."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    rec = tl.SpanRecorder(capacity=16)
+    off = tl.SpanRecorder(capacity=16)
+    off.enabled = False
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("ckpt_write", step=3):
+            with rec.span("ckpt_snapshot"):
+                time.sleep(0.002)
+        rec.record("device_step", 0.0, 1.0)
+        with off.span("ckpt_restore"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+    events = {e.name: e for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith(tl.TRACE_PREFIX)}
+    assert set(events) == {"hc:ckpt_write", "hc:ckpt_snapshot"}
+    outer, inner = events["hc:ckpt_write"], events["hc:ckpt_snapshot"]
+    assert outer.start_ns <= inner.start_ns
+    assert (inner.start_ns + inner.duration_ns
+            <= outer.start_ns + outer.duration_ns)
+    ring = {s["name"]: s for s in rec.tail()}
+    assert set(ring) == {"ckpt_write", "ckpt_snapshot", "device_step"}
+    for name in ("ckpt_write", "ckpt_snapshot"):
+        assert abs(events["hc:" + name].duration_ns * 1e-9
+                   - (ring[name]["t1"] - ring[name]["t0"])) < 1e-3
+
+
+def test_phases_tile_the_wall_and_nest_under_a_parent():
+    rec = tl.SpanRecorder(capacity=16)
+    ph = tl.Phases(rec)
+    t0 = time.monotonic()
+    assert ph.enter("pack") is None
+    assert ph.enter("decode_dispatch", parent="decode") == "pack"
+    ph.enter("decode_wait", parent="decode")
+    assert ph.enter("decode_wait", parent="decode") == "decode_wait"
+    ph.enter("retire")
+    ph.close()
+    wall = time.monotonic() - t0
+    assert {k: c for k, (c, _) in ph.fold.items()} == {
+        "pack": 1, "decode_dispatch": 1, "decode_wait": 1, "retire": 1}
+    assert sum(w for _, w in ph.fold.values()) == pytest.approx(
+        wall, abs=1e-4)
+    spans = {s["name"]: s for s in rec.tail()}
+    # the parent spans its two phases exactly; neighbours share a boundary
+    assert spans["decode"]["t0"] == spans["decode_dispatch"]["t0"]
+    assert spans["decode"]["t1"] == spans["decode_wait"]["t1"]
+    assert spans["pack"]["t1"] == spans["decode_dispatch"]["t0"]
+    assert spans["decode_wait"]["t1"] == spans["retire"]["t0"]
+    # the fold is kept with the recorder off; the sinks are not
+    rec.enabled = False
+    ph = tl.Phases(rec)
+    ph.enter("pack")
+    ph.close()
+    assert ph.fold["pack"][0] == 1 and len(rec.tail()) == 5
+
+
 def test_phase_lane_transitions_and_current_phase():
     rec = tl.SpanRecorder(capacity=16)
     rec.transition("init")
@@ -635,36 +711,76 @@ def test_flight_recorder_off_flag(tmp_path):
     assert rec.tail() == []
 
 
-def test_recorder_overhead_under_one_percent(rewind_run):
-    """The bounded-overhead guard: the driver records <= 4 spans per
-    step (input_wait, step_dispatch, one fetch-thread device_step, an
-    amortized share of the sync-window flush); 4x the measured per-span
-    cost must stay under 1% of the fixture's measured steady-state
-    step time."""
+def _train_overhead(request):
+    """The driver records <= 4 spans per step (input_wait,
+    step_dispatch, one fetch-thread device_step, an amortized share of
+    the sync-window flush), against the fixture's measured steady-state
+    step."""
     rec = tl.SpanRecorder(capacity=1024)
     n = 20_000
     t0 = time.perf_counter()
     for i in range(n):
         rec.record("overhead_probe", 0.0, 1.0, step=i)
     per_span_s = (time.perf_counter() - t0) / n
-    step_s = rewind_run["result"].mean_step_ms / 1e3
+    rewind_run = request.getfixturevalue("rewind_run")
+    return 4 * per_span_s, rewind_run["result"].mean_step_ms / 1e3
+
+
+def _serve_overhead(request):
+    """The serve loop crosses ten phase boundaries an iteration (eight
+    phases, the ``decode`` parent's open and close), each one clock
+    read, a fold update, a ring store and an inactive TraceMe pair;
+    against a tiny engine's measured decode step (dispatch + wait)."""
+    import jax  # noqa: F401  (the annotation sink needs jax imported)
+
+    engine = request.getfixturevalue("moe_engine")
+    summary = engine.run(request.getfixturevalue("moe_requests"))
+    lp = summary["loop_phases"]
+    step_s = ((lp["decode_dispatch"]["wall_s"] + lp["decode_wait"]["wall_s"])
+              / summary["decode_steps"])
+    costs = []
+    for _ in range(5):      # the least of five: the cost, not the noise
+        ph = tl.Phases(tl.SpanRecorder(capacity=1024))
+        n = 2_000
+        t0 = time.perf_counter()
+        for _ in range(n):
+            ph.enter("arrivals")
+            ph.enter("admit_host")
+            ph.enter("telemetry")
+            ph.enter("pack")
+            ph.enter("decode_dispatch", parent="decode")
+            ph.enter("decode_wait", parent="decode")
+            ph.enter("retire")
+            ph.enter("telemetry")
+        costs.append((time.perf_counter() - t0) / n)
+        assert ph.fold["decode_wait"][0] == n
+    return min(costs), step_s
+
+
+@pytest.mark.parametrize("lane", ["train", "serve"])
+def test_recorder_overhead_under_one_percent(lane, request):
+    """The bounded-overhead guard: what a lane records per step must
+    stay under 1% of its measured step."""
+    per_step_s, step_s = {"train": _train_overhead,
+                          "serve": _serve_overhead}[lane](request)
     assert step_s > 0
-    assert 4 * per_span_s < 0.01 * step_s, (
-        f"recorder overhead {4 * per_span_s * 1e6:.1f}us/step vs 1% of "
+    assert per_step_s < 0.01 * step_s, (
+        f"recorder overhead {per_step_s * 1e6:.1f}us/step vs 1% of "
         f"step {0.01 * step_s * 1e6:.1f}us")
 
 
 def test_serve_engine_records_spans(tmp_path):
     """Serving lane instrumentation without a new engine warmup: the
-    span call sites live in ``_timed``/admit/retire, pinned here by
-    source inspection (a full engine run is test_serve's job)."""
+    span call sites live in ``_timed``/admit and the loop's phases,
+    pinned here by source inspection (full engine runs are
+    test_serve_phases' job)."""
     import inspect
 
     from tpu_hc_bench.serve import engine as engine_mod
 
     src = inspect.getsource(engine_mod.ServeEngine)
-    assert "timeline_mod.record_span(kind" in src
-    assert 'timeline_mod.instant("retire"' in src
+    assert 'phases.enter(kind + "_dispatch", parent=kind)' in src
+    assert 'phases.enter("retire")' in src
     assert 'timeline_mod.instant("admit"' in src
 
 

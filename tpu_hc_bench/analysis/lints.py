@@ -894,7 +894,7 @@ class _FileLinter:
 
     # -- span-name-registry --------------------------------------------
 
-    _SPAN_NAME_CALLEES = {"record_span", "instant", "span"}
+    _SPAN_NAME_CALLEES = {"record_span", "instant", "span", "enter"}
 
     @register_pass(
         SPAN_REGISTRY, "warning", "file",
@@ -905,8 +905,9 @@ class _FileLinter:
                 "appears in any timeline")
     def _check_span_name_registry(self):
         """**span-name-registry** (warning): a literal span name passed
-        to ``timeline.span``/``record_span``/``instant`` that is not in
-        ``obs.timeline.KNOWN_SPANS``.
+        to ``timeline.span``/``record_span``/``instant``, or as the
+        phase or ``parent=`` of a ``phases.enter`` (``timeline.Phases``),
+        that is not in ``obs.timeline.KNOWN_SPANS``.
 
         Every fold keys on span names (``timeline_lines`` totals, the
         heartbeat phase column, the Chrome-trace lanes) — a typo'd name
@@ -932,25 +933,26 @@ class _FileLinter:
                 any(h in name.lower() for h in self._SPAN_MODULE_HINTS)
                 or (isinstance(node.func, ast.Name)
                     and node.func.id in self._timeline_imported_names))
+            if base == "enter":
+                timeline_owned = "phases" in name.lower()
             if not timeline_owned and base != "record_span":
-                continue    # a generic .instant()/.span() that is not
-                            # the flight recorder's
-            if not node.args:
-                continue
-            arg = node.args[0]
-            if not (isinstance(arg, ast.Constant)
-                    and isinstance(arg.value, str)):
-                continue    # variable span names are the caller's
-                            # contract, not a typo class
-            if arg.value in KNOWN_SPANS:
-                continue
-            self._emit(
-                SPAN_REGISTRY, node,
-                f"span name {arg.value!r} at `{name or base}(...)` is "
-                f"not in obs.timeline.KNOWN_SPANS — an unregistered "
-                f"(or typo'd) name records fine and then silently "
-                f"vanishes from every timeline fold; register it in "
-                f"KNOWN_SPANS or fix the spelling")
+                continue    # a generic .instant()/.span()/.enter() that
+                            # is not the flight recorder's
+            # variable span names are the caller's contract, not a typo
+            # class: only literals are checked
+            for arg in node.args[:1] + [k.value for k in node.keywords
+                                        if k.arg == "parent"]:
+                if not (isinstance(arg, ast.Constant)
+                        and isinstance(arg.value, str)) \
+                        or arg.value in KNOWN_SPANS:
+                    continue
+                self._emit(
+                    SPAN_REGISTRY, node,
+                    f"span name {arg.value!r} at `{name or base}(...)` is "
+                    f"not in obs.timeline.KNOWN_SPANS — an unregistered "
+                    f"(or typo'd) name records fine and then silently "
+                    f"vanishes from every timeline fold; register it in "
+                    f"KNOWN_SPANS or fix the spelling")
 
     # -- signal-name-registry ------------------------------------------
 

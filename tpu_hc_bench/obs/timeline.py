@@ -33,6 +33,18 @@ Three consumers:
   ``memory_dump.json``, so "what phase was every rank in when it died"
   survives the death.
 
+Two sinks, one API: a live ``span()`` (and each phase of a ``Phases``
+partition) also opens a ``jax.profiler.TraceAnnotation`` named
+``hc:<name>`` for its duration.  The ring's monotonic clock and the
+device trace share no epoch (an xplane's times count from the profiler
+session's start), so only an event written INTO the trace sits on the
+device's clock: whenever a profiler session is open (the benchmark's
+window tracer, ``--profile_steps``, a ``start_server`` capture) the
+program's spans are in its host plane, nested as they were opened;
+with none open the annotation is an atomic load.  ``--flight_recorder
+off`` silences both sinks.  ``record_span`` stays ring-only, for
+intervals that are not a stretch of the calling thread's time.
+
 Recorder calls are host-side by contract: the ``span-in-compiled-fn``
 analysis lint rejects any recorder call inside traced code (it would
 bake one constant timestamp into the compiled program and recompile or
@@ -43,6 +55,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -50,6 +63,9 @@ SPANS_RE_FMT = "spans.{rank}.jsonl"
 TIMELINE_DUMP_NAME = "timeline_dump.json"
 DEFAULT_CAPACITY = 4096
 DUMP_LAST_K = 64
+#: prefix of the program's spans in a profiler trace (the benchmark's
+#: own wrappers are ``bench:``)
+TRACE_PREFIX = "hc:"
 
 #: coarse goodput-lane phases (mirrored from obs.goodput.PHASES without
 #: the import — timeline must stay import-light); summarize's span
@@ -70,8 +86,18 @@ KNOWN_SPANS = frozenset((
     "input_wait", "step_dispatch", "device_step", "eval_dispatch",
     # data service
     "svc_decode", "ring_put", "ring_get",
-    # serve engine
-    "prefill", "decode", "classify", "admit", "retire",
+    # serve engine: the device programs, each the parent of its
+    # ``<kind>_dispatch`` / ``<kind>_wait`` pair
+    "prefill", "decode", "classify", "page_copy",
+    # serve engine: the exclusive phases that tile ``ServeEngine.run``'s
+    # loop (``loop_phases`` in the summary); a phase named ``*_wait`` is
+    # the host waiting, for the device or for an arrival
+    "arrivals", "admit_host", "prefill_dispatch", "prefill_wait", "pack",
+    "decode_dispatch", "decode_wait", "retire", "telemetry",
+    "arrival_wait", "classify_dispatch", "classify_wait",
+    "page_copy_dispatch", "page_copy_wait",
+    # serve engine instants
+    "admit",
     # serve admission forensics (round 22): edge-triggered instants the
     # moment the queue blocks on a resource
     "pool_starved", "batch_full",
@@ -254,10 +280,34 @@ class SpanRecorder:
         return [_to_record(item) for item in batch if item is not None]
 
 
-class _Span:
-    """Tiny context manager: ``with recorder.span("ckpt_save"): ...``."""
+_ANNOTATION = None   # jax.profiler.TraceAnnotation, once jax is imported
 
-    __slots__ = ("_rec", "_name", "_step", "_meta", "_t0")
+
+def _annotate(name: str):
+    """Open the profiler-side sink of a span: an entered
+    ``TraceAnnotation`` named ``hc:<name>``, or None in a process that
+    never imported jax (no profiler session can be open there, and this
+    module must not be the one to import it: the import-light
+    contract).  With no session open the annotation is an atomic load."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    ann = _ANNOTATION(TRACE_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
+class _Span:
+    """Tiny context manager: ``with recorder.span("ckpt_save"): ...``.
+    A stretch of the calling thread's time, written to both sinks; the
+    clock pair it read stays on it as ``t0`` / ``t1`` (read whatever
+    the recorder's switch says, so a caller needs no clock of its own)."""
+
+    __slots__ = ("_rec", "_name", "_step", "_meta", "_ann", "t0", "t1")
 
     def __init__(self, rec: SpanRecorder, name: str, step, meta):
         self._rec = rec
@@ -266,13 +316,84 @@ class _Span:
         self._meta = meta
 
     def __enter__(self):
-        self._t0 = time.monotonic()
+        self._ann = _annotate(self._name) if self._rec.enabled else None
+        self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        self._rec.record(self._name, self._t0, time.monotonic(),
+        self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._rec.record(self._name, self.t0, self.t1,
                          step=self._step, **(self._meta or {}))
         return False
+
+
+class Phases:
+    """One thread's wall partitioned into exclusive phases.
+
+    ``enter(name)`` closes the open phase and opens the next on ONE
+    clock read, so the phases tile the interval from the first ``enter``
+    to ``close()`` without holes or overlap.  A phase may name a
+    ``parent`` span: it opens with the first phase that names it and
+    closes when a phase under another parent (or none) is entered, on
+    the same clock reads, so the trace nests ``hc:decode_wait`` in
+    ``hc:decode``.  Every closed phase and parent goes to both sinks
+    like a ``span()``; ``fold`` — phase -> ``[count, wall_s]`` on the
+    monotonic clock — is kept whatever the recorder's switch says: the
+    fold is a counter its owner reports, the spans are the same
+    boundaries made visible.  ``t`` is the newest boundary's time.
+    """
+
+    __slots__ = ("_rec", "fold", "t", "_name", "_t0", "_ann", "_parent",
+                 "_parent_t0", "_parent_ann")
+
+    def __init__(self, rec: SpanRecorder | None = None):
+        self._rec = rec if rec is not None else _RECORDER
+        self.fold: dict[str, list] = {}
+        self.t = self._t0 = 0.0
+        self._name = self._ann = None
+        self._parent = self._parent_ann = None
+        self._parent_t0 = 0.0
+
+    def enter(self, name: str | None,
+              parent: str | None = None) -> str | None:
+        """Switch to ``name`` (None: to no phase); returns the phase
+        this one replaced, for the caller to re-enter afterwards.
+        Entering the open phase again changes nothing."""
+        prev = self._name
+        if name == prev and parent == self._parent:
+            return prev
+        now = self.t = time.monotonic()
+        # the trace's sink first, hard by the clock read (innermost
+        # closes first, outermost opens first), so that a trace event
+        # lasts what its ring span lasts; then the books
+        on = self._rec.enabled
+        swap = parent != self._parent
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if swap:
+            if self._parent_ann is not None:
+                self._parent_ann.__exit__(None, None, None)
+            self._parent_ann = (_annotate(parent)
+                                if on and parent is not None else None)
+        self._ann = _annotate(name) if on and name is not None else None
+        if prev is not None:
+            acc = self.fold.get(prev)
+            if acc is None:
+                acc = self.fold[prev] = [0, 0.0]
+            acc[0] += 1
+            acc[1] += now - self._t0
+            self._rec.record(prev, self._t0, now)
+        if swap:
+            if self._parent is not None:
+                self._rec.record(self._parent, self._parent_t0, now)
+            self._parent, self._parent_t0 = parent, now
+        self._name, self._t0 = name, now
+        return prev
+
+    def close(self) -> None:
+        self.enter(None)
 
 
 # ---------------------------------------------------------------------
@@ -300,10 +421,17 @@ def configure(enabled: bool = True, run_dir: str | None = None,
 
 def record_span(name: str, t0: float, t1: float,
                 step: int | None = None, **meta) -> None:
+    """Ring-only, after the fact: for an interval that is not a stretch
+    of the calling thread's time (``device_step`` between two completion
+    markers on the fetcher thread, the data service's worker spans).  It
+    cannot sit in a profiler trace; what IS the caller's own work takes
+    a live ``span()``."""
     _RECORDER.record(name, t0, t1, step=step, **meta)
 
 
 def span(name: str, step: int | None = None, **meta) -> _Span:
+    """A live span of the calling thread: ring record + ``hc:<name>``
+    in any open profiler trace."""
     return _RECORDER.span(name, step=step, **meta)
 
 

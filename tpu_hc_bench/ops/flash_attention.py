@@ -154,6 +154,7 @@ def _fwd_call(q, k, v, scale, causal, seq_k, block_q, block_k):
         ],
         interpret=_interpret(),
         compiler_params=_PARAMS,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 
@@ -256,6 +257,7 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, seq_k, block_q, block_k):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
         compiler_params=_PARAMS,
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # same specs with the (j, i) grid order: i is now the innermost dim
@@ -278,6 +280,7 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, seq_k, block_q, block_k):
         ],
         interpret=_interpret(),
         compiler_params=_PARAMS,
+        name="flash_attention_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

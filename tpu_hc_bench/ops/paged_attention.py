@@ -271,6 +271,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths,
         ],
         interpret=_interpret(),
         compiler_params=_PARAMS,
+        name="paged_attention",
     )(tables, lengths, k_scales, v_scales, qg, k_pages, v_pages)
     out = out.reshape(b, heads, lanes)[..., :d]
     if return_lse:

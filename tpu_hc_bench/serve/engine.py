@@ -617,19 +617,23 @@ class ServeEngine:
 
     # -- traffic path: AOT executables only -----------------------------
 
-    def _timed(self, clock, kind: str, fn):
+    def _timed(self, clock, kind: str, fn, phases, then: str):
+        """Run one device program: ``<kind>_dispatch`` is the call until
+        it returns, ``<kind>_wait`` the ``block_until_ready``, both under
+        the parent span ``kind`` (flight recorder + any open profiler
+        trace, real wall even under a VirtualClock); the loop goes on in
+        phase ``then``.  The boundaries' own clock reads charge the
+        engine clock."""
         import jax
 
         c0 = clock.now()
-        m0 = time.monotonic()
-        t0 = time.perf_counter()
+        phases.enter(kind + "_dispatch", parent=kind)
+        m0 = phases.t
         out = fn()
+        phases.enter(kind + "_wait", parent=kind)
         jax.block_until_ready(out)
-        clock.charge(kind, time.perf_counter() - t0)
-        # flight recorder (obs.timeline): every engine step kind
-        # (prefill/decode/classify) lands as a span — the serving lane's
-        # always-on host timeline, real wall even under a VirtualClock
-        timeline_mod.record_span(kind, m0, time.monotonic())
+        phases.enter(then)
+        clock.charge(kind, phases.t - m0)
         return out, clock.now() - c0
 
     def _classify_input(self, req: Request) -> np.ndarray:
@@ -798,7 +802,14 @@ class ServeEngine:
         steps = {"prefill": 0, "decode": 0, "classify": 0}
         tokens_out = 0
         productive_s = 0.0
-        queue_depths: list[int] = []
+        loop_iters = queue_depth_max = queue_depth_sum = 0
+        # the loop's wall by exclusive phase (obs.timeline.Phases): the
+        # summary's ``loop_phases``, on the real monotonic clock whatever
+        # clock drives the scheduler
+        phases = timeline_mod.Phases()
+        # rid -> engine time of the loop's first look at the request
+        # (``queue_unseen_ms``); kept across preempt/requeue
+        first_look: dict[int, float] = {}
         # per-(kind,bucket) utilization: key -> [steps, rows, active
         # rows, wall s] — the occupancy heatmap's raw counts
         butil: dict[str, list] = {}
@@ -891,9 +902,14 @@ class ServeEngine:
             u[2] += active_rows
             u[3] += dt
 
+        def unseen_ms(req: Request) -> float:
+            seen = first_look.pop(req.rid, req.arrival_s)
+            return round(1e3 * max(0.0, seen - req.arrival_s), 3)
+
         def finish(fl: _InFlight, t_done: float, status: str = "ok",
                    cause: str | None = None) -> None:
             nonlocal finished, service_ewma_s, completed_ok
+            back = phases.enter("retire")
             finished += 1
             rec = {
                 "id": fl.req.rid,
@@ -922,12 +938,15 @@ class ServeEngine:
                 fl.t_last if fl.t_last is not None else t_done,
                 t_done, fl.active_s))
             # queue-wait cause split (obs.kv): which resource this
-            # request's queue_ms was blocked on; the remainder (if any)
-            # is arrival-to-first-scheduler-look alignment, not a
-            # resource
+            # request's queue_ms was blocked on; ``queue_unseen_ms`` is
+            # the part of queue_ms before the loop first looked at the
+            # request (it polls arrivals once an iteration, so one due
+            # mid-step waits for that program to return): alignment,
+            # not a resource
             causes = wait_causes.pop(fl.req.rid, None) or [0.0, 0.0]
             rec["queue_pool_starved_ms"] = round(1e3 * causes[0], 3)
             rec["queue_batch_full_ms"] = round(1e3 * causes[1], 3)
+            rec["queue_unseen_ms"] = unseen_ms(fl.req)
             if self.decode_mode:
                 # the greedy token ids (synthetic anyway) — the decode
                 # parity tests and postmortems read them; <= 32 ints
@@ -985,9 +1004,9 @@ class ServeEngine:
                 writer.event("quarantine", **rec)
                 timeline_mod.instant("quarantine", rid=fl.req.rid,
                                      cause=cause)
-            timeline_mod.instant("retire", rid=fl.req.rid)
             if allocator is not None:
                 allocator.free(fl.pages)
+            phases.enter(back)
 
         def shed_queued(req: Request, cause: str, t: float) -> None:
             """Admit-time shed: the request never became resident, so
@@ -1004,6 +1023,7 @@ class ServeEngine:
                 "waited_ms": round(1e3 * (t - req.arrival_s), 3),
                 "queue_pool_starved_ms": round(1e3 * causes[0], 3),
                 "queue_batch_full_ms": round(1e3 * causes[1], 3),
+                "queue_unseen_ms": unseen_ms(req),
             }
             if c:
                 rec["preempts"] = c["preempts"]
@@ -1193,7 +1213,7 @@ class ServeEngine:
                 clock, "prefill",
                 lambda: self.compiled[("prefill", s)](
                     self.exec_params, kv, toks,
-                    np.int32(plen), wtable))
+                    np.int32(plen), wtable), phases, "admit_host")
             # host-side numpy view BEFORE indexing: jax.Array.__getitem__
             # dispatches a jitted gather — a post-warmup compile the
             # zero-recompile contract (and the cache-entry assertion)
@@ -1283,7 +1303,7 @@ class ServeEngine:
             (kv), dt = self._timed(
                 clock, "page_copy",
                 lambda: self.compiled[("page_copy", 0)](
-                    kv, np.int32(page), np.int32(dst)))
+                    kv, np.int32(page), np.int32(dst)), phases, "pack")
             ledger.charge(dt)
             allocator.bind(fl.table, slot, dst)
             fl.pages[slot] = dst
@@ -1292,6 +1312,7 @@ class ServeEngine:
 
         def decode_step() -> bool:
             nonlocal kv, tokens_out, productive_s
+            phases.enter("pack")
             if faults is not None:
                 hang_s = faults.hang_before_decode(steps["decode"] + 1)
                 if hang_s:
@@ -1328,7 +1349,8 @@ class ServeEngine:
             (next_toks, logits, kv), dt = self._timed(
                 clock, "decode",
                 lambda: self.compiled[("decode", b)](
-                    self.exec_params, kv, toks, tables, lengths, mask))
+                    self.exec_params, kv, toks, tables, lengths, mask),
+                phases, "retire")
             steps["decode"] += 1
             tokens_out += len(sched)
             productive_s += dt * (len(sched) / b)
@@ -1381,13 +1403,15 @@ class ServeEngine:
 
         def classify_step() -> None:
             nonlocal tokens_out, productive_s
+            phases.enter("pack")
             b = pick_bucket(self.batch_buckets, len(active))
             x = np.zeros((b,) + tuple(self.spec.input_shape), np.float32)
             for i, fl in enumerate(active):
                 x[i] = self._classify_input(fl.req)
             _, dt = self._timed(
                 clock, "classify",
-                lambda: self.compiled[("classify", b)](self.variables, x))
+                lambda: self.compiled[("classify", b)](self.variables, x),
+                phases, "retire")
             steps["classify"] += 1
             tokens_out += len(active)
             productive_s += dt * (len(active) / b)
@@ -1442,10 +1466,13 @@ class ServeEngine:
                 forensics_fn=watchdog_forensics).start()
 
         last_blocked: str | None = None
+        loop_m0 = time.monotonic()
         try:
             while finished < n:
+                phases.enter("arrivals")
                 t = now()
                 while idx < n and pending[idx].arrival_s <= t:
+                    first_look[pending[idx].rid] = t
                     queue.append(pending[idx])
                     idx += 1
                 if faults is not None:
@@ -1466,7 +1493,9 @@ class ServeEngine:
                 if handler is not None and handler.requested():
                     drained = drain(t)
                     break
-                queue_depths.append(len(queue))
+                loop_iters += 1
+                queue_depth_sum += len(queue)
+                queue_depth_max = max(queue_depth_max, len(queue))
                 progressed = False
                 if shed != "off":
                     # expiry pass: a request past its deadline decodes
@@ -1483,6 +1512,7 @@ class ServeEngine:
                         finish(fl, t, status="shed",
                                cause="resident_expired")
                         progressed = True
+                phases.enter("admit_host")
                 if batching == "continuous":
                     while queue and len(active) < self.cap:
                         head = queue[0]
@@ -1537,6 +1567,7 @@ class ServeEngine:
                 # always the gate — even a pool-capped batch admits
                 # nothing mid-flight, so scale-out (not pool growth) is
                 # the remedy.
+                phases.enter("telemetry")
                 blocked_cause = None
                 if queue:
                     if batching != "continuous":
@@ -1568,6 +1599,7 @@ class ServeEngine:
                         classify_step()
                         progressed = True
                 if not progressed:
+                    phases.enter("arrival_wait")
                     if idx >= n:
                         if shed == "off" or not queue:
                             raise RuntimeError(
@@ -1589,6 +1621,7 @@ class ServeEngine:
                             # read as a wedged scheduler
                             gap = min(gap, timeout_s / 2)
                         clock.sleep(gap)
+                phases.enter("telemetry")
                 if blocked_cause is not None:
                     # charge the elapsed step/sleep to the blocking
                     # cause for every request that sat in queue through
@@ -1622,6 +1655,10 @@ class ServeEngine:
                                for k, v in steps.items()})
                         if ledger is not None:
                             kv_pool_event()
+                        # persist the ring at the record cadence: ten
+                        # spans an iteration would roll off before the
+                        # run-end flush
+                        timeline_mod.flush()
                     if fleet is not None:
                         fleet.heartbeat(
                             step=total_steps,
@@ -1636,6 +1673,8 @@ class ServeEngine:
                 # waits all count; only a wedged step does not
                 last_iter_t[0] = time.perf_counter()
         finally:
+            phases.close()
+            loop_wall_s = time.monotonic() - loop_m0
             if dog is not None:
                 dog.stop()
             if own_handler is not None:
@@ -1688,9 +1727,9 @@ class ServeEngine:
             "tokens": tokens_out,
             "tokens_per_s": round(tokens_out / wall, 3),
             "goodput": round(productive_s / wall, 4),
-            "queue_depth_max": max(queue_depths, default=0),
+            "queue_depth_max": queue_depth_max,
             "queue_depth_mean": round(
-                float(np.mean(queue_depths)) if queue_depths else 0.0, 3),
+                queue_depth_sum / loop_iters if loop_iters else 0.0, 3),
             "buckets": list(self.batch_buckets),
             "max_in_flight": self.cap,
             "kv_page_size": self.page_size,
@@ -1725,6 +1764,12 @@ class ServeEngine:
                     "wall_s": round(u[3], 4),
                     "occupancy": round(u[2] / u[1], 4) if u[1] else 0.0}
                 for k, u in butil.items()},
+            # the loop's real wall by exclusive phase: conserved (the
+            # phases tile the loop; ``loop_wall_s`` is clocked apart)
+            "loop_phases": {
+                k: {"count": c, "wall_s": round(w, 6)}
+                for k, (c, w) in phases.fold.items()},
+            "loop_wall_s": round(loop_wall_s, 6),
             **{f"{k}_steps": v for k, v in steps.items()},
             **fold,
             # round 24: the mergeable-sketch account — source label,

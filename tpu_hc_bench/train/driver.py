@@ -682,13 +682,10 @@ def _run_eval(cfg, spec, layout, mesh, state, batch_iter, global_batch,
                               global_batch)
     timeline.start(loss)        # drained above: arrival stamps t=0
     for i in range(1, cfg.num_batches + 1):
-        t_dw = time.monotonic()
-        batch = next(batch_iter)
-        t_disp = time.monotonic()
-        timeline_mod.record_span("input_wait", t_dw, t_disp, step=i)
-        loss, correct = eval_step(state, batch)
-        timeline_mod.record_span("eval_dispatch", t_disp,
-                                 time.monotonic(), step=i)
+        with timeline_mod.span("input_wait", step=i):
+            batch = next(batch_iter)
+        with timeline_mod.span("eval_dispatch", step=i):
+            loss, correct = eval_step(state, batch)
         corrects.append(correct)
         timeline.record(i, loss)
     display_recs: list[tuple[int, float, object]] = []
@@ -2055,25 +2052,23 @@ def run_benchmark(
                     and preempt_h.agreed(world)):
                 _emergency(i - 1)
             trace_window.maybe_start(i, timeline.fetcher)
-            t_dw = time.monotonic()
-            batch = next(batch_iter)
-            t_dispatch = time.monotonic()
+            with timeline_mod.span("input_wait", step=i) as waited:
+                batch = next(batch_iter)
             # host time blocked on the input pipeline — carved out of
             # the "step" phase by the ledger (a cheap float add here;
-            # the jsonl write happens once per sync window), and the
-            # same interval recorded as an input_wait span
-            phases.note_data_wait(t_dispatch - t_dw)
-            timeline_mod.record_span("input_wait", t_dw, t_dispatch,
-                                     step=i)
-            if plan is not None:
-                plan.fire_step_faults(i, print_fn, obs_writer)
-                batch = plan.poison_batch(i, batch, print_fn, obs_writer)
-            state, metrics = train_step(
-                state, batch, jax.random.fold_in(rng, warmup_steps + i))
+            # the jsonl write happens once per sync window): the
+            # input_wait span's own clock pair
+            phases.note_data_wait(waited.t1 - waited.t0)
             # host-side dispatch cost only (the step itself is async;
             # device progress is the fetch thread's device_step spans)
-            timeline_mod.record_span("step_dispatch", t_dispatch,
-                                     time.monotonic(), step=i)
+            with timeline_mod.span("step_dispatch", step=i):
+                if plan is not None:
+                    plan.fire_step_faults(i, print_fn, obs_writer)
+                    batch = plan.poison_batch(i, batch, print_fn,
+                                              obs_writer)
+                state, metrics = train_step(
+                    state, batch,
+                    jax.random.fold_in(rng, warmup_steps + i))
             timeline.record(i, metrics["loss"])
             if tracker is not None:
                 tracker.update(metrics["nonfinite"])
