@@ -734,6 +734,27 @@ def child_serve(out: str) -> int:
                 and "fused_residual_norm" in text, f"{calls} custom calls")
     c.check("gather decode: no Mosaic custom call", not custom_call_lines(
         gather.compiled[("decode", gather.cap)].as_text()))
+    # the pool is read and written where it rests: no program holds a
+    # second array of a pool leaf's or a layer's shape (the paged arm
+    # shares the write helper)
+    from tpu_hc_bench.analysis import hlo
+
+    for eng, arm, keys in (
+            (gather, "gather", [("decode", gather.cap),
+                                ("prefill", max(gather.prefill_buckets))]),
+            (paged, "paged", [("prefill", max(paged.prefill_buckets))])):
+        leaf = eng._kv[0].shape
+        shapes = [hlo.shape_text(leaf), hlo.shape_text(leaf[1:])]
+        for key in keys:
+            found = hlo.new_buffers_of_shape(
+                eng.compiled[key].as_text(), shapes)
+            c.check(f"{arm} {key[0]}@{key[1]}: no new pool-shaped array",
+                    not found,
+                    ", ".join(f"{i.name} {i.opcode}" for i in found[:6])
+                    or f"none of {shapes[0]} / {shapes[1]}")
+        ratio = eng.compile_record["kv_pool_temp_ratio"]
+        c.check(f"{arm}: kv_pool_temp_ratio < 1",
+                ratio is not None and ratio < 1, ratio)
 
     rng = np.random.default_rng(0)
     vocab, w = gather.spec.vocab_size, gather.table_width

@@ -15,7 +15,7 @@ trace, TWO scheduler arms —
 one engine PER arm (the arms compile different decode programs), same
 trace, continuous batching —
 
-- ``gather/off``: the dense-gather ``_softmax_attend`` reference;
+- ``gather/off``: the dense-gather reference (``_attend_rows``);
 - ``paged/off``: the Pallas flash-decode kernel reading K/V through
   the page tables (``ops.paged_attention``);
 - ``paged/int8_kv``: + int8 KV pool with per-page scales consumed

@@ -120,6 +120,73 @@ def test_fusion_attribution_through_metadata():
     assert [i.opcode for i in leaves] == ["parameter", "dot", "add"]
 
 
+# the TPU compiler's spelling: tiles and memory spaces inside the layout
+# braces (``T(`` is no opcode), lines lifted from the gather arm's decode
+# program before and after it stopped re-laying the KV pool out
+TPU_HLO = """\
+HloModule jit_decode_gather, is_scheduled=true
+
+%fused_update (p0: f32[4,2,9,8,128], p1: f32[4,2,1,1,128], p2: s32[]) -> f32[4,2,9,8,128] {
+  %p0 = f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)} parameter(0)
+  %p1 = f32[4,2,1,1,128]{4,3,2,1,0:T(1,128)S(1)} parameter(1)
+  %p2 = s32[]{:T(128)} parameter(2)
+  ROOT %dynamic-update-slice.3 = f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)} dynamic-update-slice(%p0, %p1, %p2, %p2, %p2, %p2, %p2)
+}
+
+%body (arg: (s32[], f32[4,2,9,8,128])) -> (s32[], f32[4,2,9,8,128]) {
+  %arg = (s32[]{:T(128)}, f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)}) parameter(0)
+  %get-tuple-element.1 = f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)} get-tuple-element(%arg), index=1
+  %n = s32[]{:T(128)} get-tuple-element(%arg), index=0
+  %dynamic_update_slice.14 = f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)} dynamic-update-slice(%get-tuple-element.1, %get-tuple-element.1, %n, %n, %n, %n, %n)
+  ROOT %tuple.2 = (s32[]{:T(128)}, f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)}) tuple(%n, %dynamic_update_slice.14)
+}
+
+%fused_slice (q0: f32[4,2,9,8,128]) -> f32[2,9,8,128] {
+  %q0 = f32[4,2,9,8,128]{4,1,3,2,0:T(8,128)} parameter(0)
+  %slice.7 = f32[1,2,9,8,128]{4,1,3,2,0:T(8,128)} slice(%q0), slice={[1:2], [0:2], [0:9], [0:8], [0:128]}
+  ROOT %bitcast.7 = f32[2,9,8,128]{3,2,1,0:T(8,128)S(1)} bitcast(%slice.7)
+}
+
+ENTRY %main (kv: f32[4,2,9,8,128], row: f32[4,2,1,1,128], i: s32[]) -> f32[4,2,9,8,128] {
+  %kv = f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)} parameter(0), sharding={replicated}
+  %row = f32[4,2,1,1,128]{4,3,2,1,0:T(1,128)S(1)} parameter(1)
+  %i = s32[]{:T(128)} parameter(2)
+  %copy.348 = f32[4,2,9,8,128]{4,1,3,2,0:T(8,128)} copy(%kv), sharding={replicated}
+  %slice_bitcast_fusion = f32[2,9,8,128]{3,2,1,0:T(8,128)S(1)} fusion(%copy.348), kind=kLoop, calls=%fused_slice
+  %fusion.51 = f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)} fusion(%kv, %row, %i), kind=kLoop, calls=%fused_update
+  %tuple.9 = (s32[]{:T(128)}, f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)}) tuple(%i, %fusion.51)
+  %while.4 = (s32[]{:T(128)}, f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)}) while(%tuple.9), condition=%body, body=%body
+  ROOT %get-tuple-element.709 = f32[4,2,9,8,128]{4,3,2,1,0:T(8,128)} get-tuple-element(%while.4), index=1
+}
+"""
+
+
+def test_tiled_layouts_do_not_hide_the_opcode():
+    m = hlo.parse_hlo(TPU_HLO)
+    copy = m.find("copy.348")
+    assert (copy.opcode, copy.shape) == ("copy", "f32[4,2,9,8,128]")
+    assert m.find("i").opcode == "parameter" and m.find("i").shape == "s32[]"
+    wh = m.find("while.4")
+    assert wh.opcode == "while"
+    assert wh.shape == "(s32[], f32[4,2,9,8,128])"
+    assert wh.called == ("body", "body")
+    assert {i.opcode for i in m.entry.instructions} == {
+        "parameter", "copy", "fusion", "tuple", "while",
+        "get-tuple-element"}
+
+
+def test_new_buffers_of_shape_passes_in_place_updates_only():
+    """A copy or a slice of the held buffer is found; its parameters,
+    tuple elements, the bare dynamic-update-slice of a loop body and a
+    fusion rooted in one are the buffer itself and are not."""
+    pool, layer = "f32[4,2,9,8,128]", "f32[2,9,8,128]"
+    found = hlo.new_buffers_of_shape(TPU_HLO, (pool, layer))
+    assert [i.name for i in found] == ["copy.348", "slice_bitcast_fusion"]
+    clean = "\n".join(ln for ln in TPU_HLO.splitlines()
+                      if "copy.348" not in ln)
+    assert hlo.new_buffers_of_shape(clean, (pool, layer)) == []
+
+
 # ---------------------------------------------------------------------
 # lint fixtures: one deliberately-planted defect per family
 

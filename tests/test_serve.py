@@ -234,6 +234,22 @@ def test_zero_lowering_after_warmup(moe_engine, moe_requests):
     assert (moe_engine.lower_count, set(moe_engine.compiled)) == before
 
 
+def test_kv_pool_temp_ratio_in_compile_record_and_summary(moe_engine,
+                                                         moe_ab):
+    """The counter that says the programs hold no second copy of a pool
+    leaf: the largest decode/prefill AOT temp over one leaf's bytes.
+    Its VALUE is the chip's to judge (the CPU backend neither donates
+    nor lays the pool out as the TPU does); here it must exist, be a
+    finite number, and reach the run summary and its rendering."""
+    ratio = moe_engine.compile_record["kv_pool_temp_ratio"]
+    assert isinstance(ratio, float) and np.isfinite(ratio) and ratio > 0
+    assert ratio == moe_engine.kv_pool_temp_ratio()
+    summary = moe_ab["continuous"]["summary"]
+    assert summary["kv_pool_temp_ratio"] == ratio
+    assert any(f"kv_pool_temp_ratio {ratio:.3f}" in ln
+               for ln in slo.slo_lines(summary))
+
+
 def test_off_ladder_request_rejected(moe_engine, serve_cfg):
     big = arrivals.Request(
         rid=0, arrival_s=0.0,

@@ -129,3 +129,51 @@ def test_paged_kernel_carries_its_name(one_chip, mosaic):
             ).compile().as_text()
     calls = _custom_calls(text)
     assert len(calls) == 1 and "paged_attention" in calls[0], calls
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_gather_arm_holds_no_second_pool(one_chip, mosaic, program):
+    """Two layers of GPT-2 medium's widths over the chat cell's pool
+    geometry (16 KV heads, 641 pages of 16 tokens, 128 lanes; 16 rows,
+    a 512-token prompt): the program the chip's compiler builds reads
+    and writes the donated pool where it rests — no ``copy``, ``slice``,
+    ``scatter`` or fusion makes another array of a pool leaf's or a
+    layer's shape, and its temporaries stay under one leaf's bytes
+    (the engine's ``kv_pool_temp_ratio``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_hc_bench.analysis import hlo
+    from tpu_hc_bench.models import gpt
+    from tpu_hc_bench.serve import decode
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = gpt.GPTLM(hidden=1024, num_layers=2, heads=16, ffn=4096,
+                      dtype=jnp.float32)
+    family = decode.build_family(model)
+    params = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+            train=False))["params"])
+    page, width, rows = 16, 40, 16
+    leaf = (2, 16, 1 + rows * width, page, 128)
+    kv = (sd(leaf, jnp.float32), sd(leaf, jnp.float32))
+    if program == "decode":
+        fn = decode.build_decode_fn(family, page, width)
+        args = (sd((rows,), jnp.int32), sd((rows, width), jnp.int32),
+                sd((rows,), jnp.int32), sd((rows,), jnp.bool_))
+    else:
+        fn = decode.build_prefill_fn(family, page, width)
+        args = (sd((1, 512), jnp.int32), sd((), jnp.int32),
+                sd((width,), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    found = hlo.new_buffers_of_shape(
+        compiled.as_text(), [hlo.shape_text(leaf), hlo.shape_text(leaf[1:])])
+    assert not found, [(i.name, i.opcode) for i in found]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 4 * np.prod(leaf), temp

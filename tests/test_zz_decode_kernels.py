@@ -186,6 +186,120 @@ def test_paged_kernel_validation_loud():
             z((1, 2), jnp.int32), z((1,), jnp.int32))
 
 
+# --- the gather arm: the pool read and written where it rests ---------
+
+
+def _random_pool(rng, layers, kvh, pages, ps, d, lanes=128):
+    """A pool as the programs keep it: ``[L, kvh, pages, ps, lanes]``,
+    head_dim zero-padded to the lane tile."""
+    pool = np.zeros((layers, kvh, pages, ps, lanes), np.float32)
+    pool[..., :d] = rng.standard_normal((layers, kvh, pages, ps, d))
+    return pool
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_head_major_attend_matches_softmax_attend(group):
+    """``_gather_rows`` + ``_attend_rows`` == ``_softmax_attend`` over
+    rows gathered plainly with numpy indexing, fresh token appended,
+    GQA by repeat: the arithmetic the gather arm stated before it read
+    the pool head-major."""
+    rng = np.random.default_rng(40 + group)
+    layers, kvh, d, pages, ps, b, w = 3, 2, 16, 11, 4, 5, 3
+    heads, layer = kvh * group, 1
+    kp = _random_pool(rng, layers, kvh, pages, ps, d)
+    vp = _random_pool(rng, layers, kvh, pages, ps, d)
+    tables = rng.integers(0, pages, (b, w)).astype(np.int32)
+    lengths = rng.integers(0, w * ps + 1, (b,)).astype(np.int32)
+    lengths[0], lengths[1] = 0, w * ps      # empty and full caches
+    q = rng.standard_normal((b, heads, d)).astype(np.float32)
+    k_new = rng.standard_normal((b, kvh, d)).astype(np.float32)
+    v_new = rng.standard_normal((b, kvh, d)).astype(np.float32)
+
+    got = decode_mod._attend_rows(
+        jnp.asarray(q),
+        decode_mod._gather_rows(jnp.asarray(kp), layer, jnp.asarray(tables)),
+        decode_mod._gather_rows(jnp.asarray(vp), layer, jnp.asarray(tables)),
+        jnp.asarray(k_new), jnp.asarray(v_new), jnp.asarray(lengths))
+
+    def dense(pool, fresh):     # [b, span + 1, heads, d]
+        rows = pool[layer][:, tables][..., :d]      # [kvh, b, w, ps, d]
+        rows = rows.transpose(1, 2, 3, 0, 4).reshape(b, w * ps, kvh, d)
+        rows = np.concatenate([rows, fresh[:, None]], axis=1)
+        return jnp.asarray(np.repeat(rows, group, axis=2))
+
+    mask = np.concatenate(
+        [np.arange(w * ps)[None, :] < lengths[:, None],
+         np.ones((b, 1), bool)], axis=1)
+    want = decode_mod._softmax_attend(
+        jnp.asarray(q)[:, None], dense(kp, k_new), dense(vp, v_new),
+        jnp.asarray(mask))[:, 0]
+    assert got.shape == (b, heads, d)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
+
+
+def test_decode_row_write_matches_scatter_bitwise():
+    """``_write_pool`` a row at a time == the ``.at[:, :, page,
+    offset].set`` it replaced, bit for bit on every page a request owns
+    (inactive rows all land on the trash page 0, which nobody reads)."""
+    rng = np.random.default_rng(5)
+    layers, kvh, d, pages, ps, b = 2, 3, 16, 9, 4, 6
+    pool = _random_pool(rng, layers, kvh, pages, ps, d)
+    new = rng.standard_normal((layers, b, kvh, d)).astype(np.float32)
+    active = np.array([True, False, True, True, False, True])
+    page_idx = np.where(active, [3, 7, 8, 1, 2, 5], 0).astype(np.int32)
+    offset = np.array([0, 1, 3, 2, 1, 3], np.int32)
+    rows = decode_mod._pool_rows(jnp.asarray(new), pool.shape[-1])
+    want = jnp.asarray(pool).at[:, :, page_idx, offset].set(rows)
+    got = decode_mod._write_pool(jnp.asarray(pool), rows[:, :, :, None],
+                                 jnp.asarray(page_idx), jnp.asarray(offset))
+    np.testing.assert_array_equal(np.asarray(got)[:, :, 1:],
+                                  np.asarray(want)[:, :, 1:])
+    # and it did write: the active rows hold the fresh K/V
+    np.testing.assert_array_equal(
+        np.asarray(got)[:, :, 3, 0, :d], new[:, 0])
+    np.testing.assert_array_equal(
+        np.asarray(got)[:, :, 2], pool[:, :, 2])    # inactive: untouched
+
+
+@pytest.mark.parametrize("s,length,shared", [
+    (16, 13, 0),        # partial last page, pad pages -> trash
+    (16, 16, 0),        # the bucket exactly full
+    (16, 10, 2),        # a cache hit: the first two slots zeroed
+    (6, 5, 0),          # a bucket that is no whole number of pages
+    (2, 2, 0),          # a bucket under one page
+])
+def test_prompt_page_write_matches_scatter_bitwise(s, length, shared):
+    """``_write_prompt_pages`` == the per-position scatter it replaced
+    on every row a request owns: positions < ``length`` in the pages
+    its write table names, bit for bit; every other page but the trash
+    page keeps what it held."""
+    rng = np.random.default_rng(s * 100 + length)
+    layers, kvh, d, pages, ps, w = 2, 3, 16, 12, 4, 5
+    pool = _random_pool(rng, layers, kvh, pages, ps, d)
+    new = rng.standard_normal((layers, s, kvh, d)).astype(np.float32)
+    table = rng.permutation(np.arange(1, pages))[:w].astype(np.int32)
+    table[:shared] = 0
+    pos = np.arange(s)
+    page_idx = np.where(pos < length,
+                        table[np.clip(pos // ps, 0, w - 1)], 0)
+    want = np.asarray(jnp.asarray(pool).at[:, :, page_idx, pos % ps].set(
+        decode_mod._pool_rows(jnp.asarray(new), pool.shape[-1])))
+    got = np.asarray(decode_mod._write_prompt_pages(
+        jnp.asarray(pool), jnp.asarray(new), jnp.asarray(table),
+        jnp.int32(length)))
+    owned = {int(p) for p in page_idx if p}
+    assert len(owned) == -(-length // ps) - shared
+    for slot, page in enumerate(table):
+        if page not in owned:
+            continue
+        n = min(ps, length - slot * ps)     # the rows up to `length`
+        np.testing.assert_array_equal(got[:, :, page, :n],
+                                      want[:, :, page, :n])
+    others = [p for p in range(1, pages) if p not in owned]
+    np.testing.assert_array_equal(got[:, :, others], pool[:, :, others])
+
+
 # --- ops: fused residual + norm ---------------------------------------
 
 

@@ -36,6 +36,8 @@ __all__ = [
     "collective_counts",
     "fusion_ops",
     "op_attribution",
+    "new_buffers_of_shape",
+    "shape_text",
 ]
 
 # Cross-device collective opcodes (sync spellings; async spellings are
@@ -58,6 +60,10 @@ _DEF_RE = re.compile(
 # shape tokens (f32[2,8]{1,0}, (f32[2], u32[]) tuples, pred[], token[])
 # are never an identifier directly followed by "(".
 _OPCODE_RE = re.compile(r"\b(?P<op>[a-zA-Z][\w\-]*)\(")
+# ... except inside a layout: the TPU compiler's text carries tiles and
+# memory spaces there, ``f32[2,8]{1,0:T(8,128)S(1)}``, and ``T(`` is no
+# opcode.  Layouts are cut out before the opcode and the shape are read.
+_LAYOUT_RE = re.compile(r"(?<=\])\{[^{}]*\}")
 _METADATA_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="(?P<n>[^"]*)"')
 _SOURCE_RE = re.compile(
     r'source_file="(?P<f>[^"]*)"(?:\s+source_line=(?P<l>\d+))?')
@@ -80,6 +86,8 @@ class HloInstruction:
     source: str = ""                # metadata source_file:source_line
     called: tuple[str, ...] = ()    # computations this op calls
     text: str = ""                  # the raw definition line
+    shape: str = ""                 # result shape without layouts, e.g.
+                                    # "f32[2,8]" or "(f32[2], u32[])"
 
     @property
     def base_opcode(self) -> str:
@@ -127,7 +135,8 @@ def _parse_instruction(line: str) -> HloInstruction | None:
     if not m:
         return None
     rest = m.group("rest")
-    om = _OPCODE_RE.search(rest)
+    bare = _LAYOUT_RE.sub("", rest)
+    om = _OPCODE_RE.search(bare)
     if not om:
         return None
     meta = _METADATA_OP_NAME_RE.search(rest)
@@ -146,6 +155,7 @@ def _parse_instruction(line: str) -> HloInstruction | None:
         source=(f"{src.group('f')}:{src.group('l') or '?'}" if src else ""),
         called=called,
         text=line.strip(),
+        shape=bare[:om.start()].strip(),
     )
 
 
@@ -247,6 +257,46 @@ def fusion_ops(module: HloModule,
     for c in instr.called:
         walk(c)
     return out
+
+
+def shape_text(dims, dtype: str = "f32") -> str:
+    """An array shape as ``HloInstruction.shape`` spells it."""
+    return f"{dtype}[{','.join(str(d) for d in dims)}]"
+
+
+# opcodes whose result names a buffer that already exists: views, tuple
+# plumbing, and the update that writes into its own operand
+_NO_NEW_BUFFER = frozenset({
+    "parameter", "get-tuple-element", "bitcast", "dynamic-update-slice"})
+
+
+def new_buffers_of_shape(module: HloModule | str,
+                         shapes) -> list[HloInstruction]:
+    """Instructions, in any computation, that produce a NEW array of one
+    of ``shapes`` (layout-free spellings, ``"f32[24,16,641,16,128]"``).
+
+    What a program that holds a large buffer in place must NOT contain:
+    a ``copy`` (a re-layout), a ``slice``, a ``scatter`` or a fusion
+    whose result is another array of the buffer's shape.  Parameters,
+    tuple elements, bitcasts and ``dynamic-update-slice`` (alone or as a
+    fusion's root) name the buffer they were given and do not count;
+    tuple-shaped results (a ``while`` carrying the buffer) never match a
+    single array's shape.
+    """
+    if isinstance(module, str):
+        module = parse_hlo(module)
+    shapes = frozenset(shapes)
+
+    def in_place(ins: HloInstruction) -> bool:
+        if ins.opcode != "fusion":
+            return ins.opcode in _NO_NEW_BUFFER
+        roots = [i for c in ins.called if c in module.computations
+                 for i in module.computations[c].instructions if i.is_root]
+        return bool(roots) and all(
+            r.opcode == "dynamic-update-slice" for r in roots)
+
+    return [ins for ins in _iter_instructions(module)
+            if ins.shape in shapes and not in_place(ins)]
 
 
 def op_attribution(module: HloModule, opcodes: tuple[str, ...] = ("dot",),

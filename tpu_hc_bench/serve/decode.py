@@ -26,9 +26,12 @@ read), so one compiled program serves any admission pattern.
 
 **Decode attention arms** (round 18, ``--decode_attention``):
 
-- ``gather`` — the reference: gather the tables' pages into a dense
-  worst-case ``[b, S, heads, d]`` temporary and run ``_softmax_attend``.
-  Simple, and the parity anchor for everything else.
+- ``gather`` — the reference: gather the tables' pages of one layer
+  into a dense worst-case ``[kv_heads, b, S, lanes]`` temporary —
+  head-major, as the pool rests — and attend over it (``_gather_rows``,
+  ``_attend_rows``).  Simple, and the parity anchor for everything
+  else.  No program copies or re-lays out the pool: reads gather from
+  it, writes are in-place slabs (``_write_pool``).
 - ``paged`` — ``ops.paged_decode_attention``: a Pallas flash-decode
   kernel that reads K/V *directly through the page tables* (no dense
   gather ever materializes; online softmax over pages; block size =
@@ -88,7 +91,9 @@ DECODE_ATTENTION_ARMS = ("gather", "paged")
 
 
 def _softmax_attend(q, keys, values, mask):
-    """Single-query attention over gathered cache rows.
+    """Single-query attention over dense token-major cache rows: the
+    plain reference of ``_attend_rows`` and of the paged kernel (tests,
+    ``chip_smoke.py``); no serve program calls it.
 
     ``q`` [b, 1, heads, d]; ``keys``/``values`` [b, S, heads, d];
     ``mask`` [b, S] bool (True = attend).  Same convention as
@@ -402,6 +407,100 @@ def _pool_rows(new, lanes: int):
     return jnp.pad(new, ((0, 0),) * 3 + ((0, lanes - new.shape[-1]),))
 
 
+def _write_pool(pool, slabs, page_idx, offset):
+    """Slab ``i`` of ``slabs [L, kvh, n, r, lanes]`` into
+    ``pool[:, :, page_idx[i], offset[i]:offset[i] + r]``, in place.
+
+    ``n`` ``dynamic_update_slice``s on the (donated) pool in the layout
+    it rests in — a decode row (``r`` = 1) or a whole prompt page (``r``
+    = page_size) at a time, every layer and kv head at once.  A scatter
+    over ``(page, offset)`` pairs wants the pool page-major and makes
+    XLA re-lay the whole pool out and back around it; a slab does not.
+    Slabs land in order: where targets coincide (only ever on the trash
+    page 0) the later one stays.
+    """
+    def write(i, pool):
+        slab = jax.lax.dynamic_slice_in_dim(slabs, i, 1, axis=2)
+        return jax.lax.dynamic_update_slice(
+            pool, slab, (0, 0, page_idx[i], offset[i], 0))
+
+    return jax.lax.fori_loop(0, slabs.shape[2], write, pool)
+
+
+def _write_prompt_pages(pool, new, table, length):
+    """Prefill's page write: ``new`` [L, s, kvh, d] into the pages of
+    ``table [w]``, a whole page at a time; pages past the prompt (and
+    the shared-prefix slots a cache hit zeroed) go to the trash page 0.
+    The last page's rows past ``length`` hold pad-token K/V: masked on
+    every read and overwritten by the appends."""
+    page_size, lanes = pool.shape[3], pool.shape[4]
+    s = new.shape[1]
+    chunks = _pad_up(s, page_size) // page_size
+    rows = jnp.pad(_pool_rows(new, lanes),
+                   ((0, 0), (0, 0), (0, chunks * page_size - s), (0, 0)))
+    idx = jnp.arange(chunks)
+    page_idx = jnp.where(idx * page_size < length,
+                         table[jnp.clip(idx, 0, table.shape[0] - 1)], 0)
+    return _write_pool(
+        pool, rows.reshape(*rows.shape[:2], chunks, page_size, lanes),
+        page_idx, jnp.zeros_like(page_idx))
+
+
+def _gather_rows(pool, layer: int, tables):
+    """Layer ``layer``'s cache rows through ``tables [b, w]``, straight
+    from the 5-D pool: ``[kvh, b, w * page_size, lanes]``, head-major
+    as the pool rests.  ONE gather, a ``[page_size, lanes]`` slab per
+    (head, row, slot) index with the static layer in it: a
+    ``pool[layer]`` slice would stand as a temporary of its own, a slab
+    of all heads per page lands page-major and is transposed after, and
+    a head-minor result pushes its transpose onto the whole pool.
+    """
+    _, kvh, _, page_size, lanes = pool.shape
+    head = jnp.arange(kvh, dtype=tables.dtype)[:, None, None]
+    idx = jnp.stack(jnp.broadcast_arrays(
+        jnp.asarray(layer, tables.dtype), head, tables[None]), axis=-1)
+    rows = jax.lax.gather(
+        pool, idx,
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(3, 4), collapsed_slice_dims=(0, 1, 2),
+            start_index_map=(0, 1, 2)),
+        slice_sizes=(1, 1, 1, page_size, lanes),
+        mode="promise_in_bounds")           # [kvh, b, w, ps, lanes]
+    return rows.reshape(kvh, tables.shape[0], -1, lanes)
+
+
+def _attend_rows(q, k_rows, v_rows, k_new, v_new, lengths):
+    """Single-query attention over head-major cache rows plus the
+    fresh token: ``_softmax_attend``'s arithmetic in the pool's order.
+
+    ``q`` [b, heads, d]; ``k_rows``/``v_rows`` [kvh, b, span, lanes]
+    (``_gather_rows``; lanes past ``d`` are zero and stay in the
+    contraction); ``k_new``/``v_new`` [b, kvh, d], the token's own K/V,
+    one more score column (not yet in the pool); ``lengths`` [b] masks
+    the rows.  GQA is a reshape of ``q`` to [b, kvh, group, d]: head
+    ``h`` reads kv head ``h // group`` and no row is repeated.
+    Returns [b, heads, d].
+    """
+    b, heads, d = q.shape
+    kvh, _, span, lanes = k_rows.shape
+    qg = q.reshape(b, kvh, heads // kvh, d)
+    scale = 1.0 / d ** 0.5
+    s_rows = jnp.einsum(
+        "bhgd,hbkd->bhgk",
+        jnp.pad(qg, ((0, 0),) * 3 + ((0, lanes - d),)), k_rows,
+        preferred_element_type=jnp.float32) * scale
+    s_new = jnp.einsum("bhgd,bhd->bhg", qg, k_new,
+                       preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(span)[None, :] < lengths[:, None]
+    s = jnp.concatenate(
+        [jnp.where(valid[:, None, None, :], s_rows, _NEG_INF),
+         s_new[..., None]], axis=-1)
+    p = jax.nn.softmax(s, axis=-1).astype(v_rows.dtype)
+    ctx = (jnp.einsum("bhgk,hbkd->bhgd", p[..., :span], v_rows)[..., :d]
+           + p[..., span:] * v_new[:, :, None, :])
+    return ctx.reshape(b, heads, d)
+
+
 def init_kv_state(family: _Family, num_pages: int, page_size: int,
                   dtype, quant: str = "off") -> tuple:
     """The engine's KV carry: ``(k_pages, v_pages)`` — int8 pools plus
@@ -541,14 +640,13 @@ def build_prefill_fn(family: _Family, page_size: int, table_width: int,
         x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
         logits = family.head(params, x_last)[:, 0]      # [1, vocab]
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        pos = jnp.arange(s)
         kn = jnp.stack([k[0] for k in new_k])       # [L, s, kvh, d]
         vn = jnp.stack([v[0] for v in new_v])
         if quant == "int8_kv":
             k_pages, v_pages, k_scales, v_scales = kv
             # zero the pad positions: their (garbage-token) K/V would
             # otherwise inflate the last page's amax scale
-            valid = (pos < length)[None, :, None, None]
+            valid = (jnp.arange(s) < length)[None, :, None, None]
             kn = jnp.where(valid, kn, 0.0)
             vn = jnp.where(valid, vn, 0.0)
             k_pages, k_scales = _write_quantized_chunks(
@@ -559,18 +657,10 @@ def build_prefill_fn(family: _Family, page_size: int, table_width: int,
                 table_width)
             return next_token, logits, (k_pages, v_pages,
                                         k_scales, v_scales)
-        # scatter the prompt K/V into this request's pages; pads -> trash
         k_pages, v_pages = kv
-        page_idx = jnp.where(
-            pos < length,
-            table[jnp.clip(pos // page_size, 0, table_width - 1)], 0)
-        offset = pos % page_size
-        lanes = k_pages.shape[-1]
-        k_pages = k_pages.at[:, :, page_idx, offset].set(
-            _pool_rows(kn, lanes))
-        v_pages = v_pages.at[:, :, page_idx, offset].set(
-            _pool_rows(vn, lanes))
-        return next_token, logits, (k_pages, v_pages)
+        return next_token, logits, (
+            _write_prompt_pages(k_pages, kn, table, length),
+            _write_prompt_pages(v_pages, vn, table, length))
 
     return prefill
 
@@ -618,28 +708,15 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
             return k_pages, v_pages, k_scales, v_scales
         k_pages, v_pages = kv
         lanes = k_pages.shape[-1]
-        k_pages = k_pages.at[:, :, page_idx, offset].set(
-            _pool_rows(kn, lanes))
-        v_pages = v_pages.at[:, :, page_idx, offset].set(
-            _pool_rows(vn, lanes))
-        return k_pages, v_pages
-
-    def gather_cache(layer_pages, tables):
-        """``[kvh, pages, ps, lanes]`` through ``tables [b, w]`` -> the
-        dense ``[b, span, kvh, d]`` cache rows (pad lanes dropped)."""
-        rows = layer_pages[:, tables][..., :family.head_dim]
-        return rows.transpose(1, 2, 3, 0, 4).reshape(
-            tables.shape[0], -1, family.kv_heads, family.head_dim)
+        return (
+            _write_pool(k_pages, _pool_rows(kn, lanes)[:, :, :, None],
+                        page_idx, offset),
+            _write_pool(v_pages, _pool_rows(vn, lanes)[:, :, :, None],
+                        page_idx, offset))
 
     def decode_gather(params, kv, tokens, tables, lengths, active):
         k_pages, v_pages = kv
-        b = tokens.shape[0]
-        span = table_width * page_size
         x = family.embed_decode(params, tokens, lengths)
-        group = family.heads // family.kv_heads
-        kv_valid = jnp.arange(span)[None, :] < lengths[:, None]
-        mask = jnp.concatenate(
-            [kv_valid, jnp.ones((b, 1), bool)], axis=1)
         new_k, new_v = [], []
         for l in range(family.num_layers):
             p_l = family.layer_params(params, l)
@@ -647,15 +724,16 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
             q, k, v = family.qkv(p_l, h, lengths[:, None])
             new_k.append(k[:, 0])
             new_v.append(v[:, 0])
-            kc = gather_cache(k_pages[l], tables)
-            vc = gather_cache(v_pages[l], tables)
-            keys = jnp.concatenate([kc, k], axis=1)
-            values = jnp.concatenate([vc, v], axis=1)
-            if group > 1:
-                keys = jnp.repeat(keys, group, axis=2)
-                values = jnp.repeat(values, group, axis=2)
-            ctx = _softmax_attend(q, keys, values, mask)
-            x = x + family.attn_out(p_l, ctx)
+            # a layer's gathers wait for its own q: left free, the
+            # scheduler starts all 2 x L of them at once in the small
+            # buckets, and rows that fit the chip's fast memory one
+            # layer at a time are spilled to HBM, L layers at a time
+            q, tabs = jax.lax.optimization_barrier((q, tables))
+            ctx = _attend_rows(
+                q[:, 0], _gather_rows(k_pages, l, tabs),
+                _gather_rows(v_pages, l, tabs), k[:, 0], v[:, 0],
+                lengths)
+            x = x + family.attn_out(p_l, ctx[:, None])
             x = x + family.ffn(p_l, family.ffn_norm(p_l, x))
         logits = family.head(params, x)[:, 0]
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -481,6 +481,10 @@ class ServeEngine:
             if tb is not None:
                 arm += (f"; worst decode bucket AOT temp "
                         f"{tb / 2**20:.1f} MiB")
+            ratio = self.kv_pool_temp_ratio()
+            self.compile_record["kv_pool_temp_ratio"] = ratio
+            if ratio is not None:
+                arm += f"; kv_pool_temp_ratio {ratio:.3f}"
             print_fn(arm)
         devs = jax.local_devices()
         print_fn(
@@ -517,6 +521,25 @@ class ServeEngine:
                        or ma["total_bytes"] > worst["total_bytes"]):
                 worst, worst_key = ma, key
         return worst_key, worst
+
+    def kv_pool_temp_ratio(self) -> float | None:
+        """The largest AOT ``temp`` bytes over the decode and prefill
+        programs, in pool leaves (one of K / V).  A program that holds
+        a second copy of a leaf — a re-layout of the pool around its
+        gather or its write — reads >= 1; one that reads and writes the
+        pool where it rests holds a layer's gathered rows at most.
+        None where the backend exposes no analysis."""
+        from tpu_hc_bench.obs import memory as obs_memory
+
+        temps = []
+        for (kind, _), compiled in self.compiled.items():
+            if kind in ("decode", "prefill"):
+                ma = obs_memory.memory_analysis_of_compiled(compiled)
+                if ma and "temp_bytes" in ma:
+                    temps.append(ma["temp_bytes"])
+        if not temps:
+            return None
+        return round(max(temps) / self._kv[0].nbytes, 4)
 
     def _check_hbm_budget(self, print_fn) -> None:
         """``--hbm_budget`` in the serving lane: the warmed ladder's
@@ -1753,6 +1776,8 @@ class ServeEngine:
                 "decode_block_pages"),
             "aot_decode_temp_bytes": self.compile_record.get(
                 "aot_decode_temp_bytes"),
+            "kv_pool_temp_ratio": self.compile_record.get(
+                "kv_pool_temp_ratio"),
             "post_warmup_compiles": entries_final
                                     - self.entries_after_warmup,
             # round 20 (obs.requests): the tail-attribution fold, its

@@ -345,13 +345,16 @@ def slo_lines(fold: dict) -> list[str]:
             f"{fold.get('classify_steps', 0)}")
     if fold.get("decode_attention"):
         tb = fold.get("aot_decode_temp_bytes")
+        ratio = fold.get("kv_pool_temp_ratio")
         lines.append(
             f"  decode arm: attention={fold['decode_attention']} "
             f"quant={fold.get('quant', 'off')}"
             + (f" block_pages={fold['decode_block_pages']}"
                if fold.get("decode_block_pages") else "")
             + (f"  worst decode bucket AOT temp {tb / 2**20:.1f} MiB"
-               if tb is not None else ""))
+               if tb is not None else "")
+            + (f"  kv_pool_temp_ratio {ratio:.3f}"
+               if ratio is not None else ""))
     # round 20: per-bucket occupancy heatmap (padding waste and ladder
     # sizing read directly off it)
     lines.extend(requests_mod.bucket_util_lines(fold.get("bucket_util")))
