@@ -35,6 +35,17 @@ def request_percentile(ctx, field: str, q: float):
     return _tail([r[field] for r in records], ctx, q)
 
 
+def queue_p50_by_thirds(records) -> list[float] | None:
+    """Whether a backlog grew: the median queue wait, ms, of the first
+    and of the last third of the finished requests, by arrival."""
+    recs = sorted(records, key=lambda r: r["arrival_s"])
+    if not recs:
+        return None
+    third = max(1, len(recs) // 3)
+    return [stats.percentile([r["queue_ms"] for r in part], 50)
+            for part in (recs[:third], recs[-third:])]
+
+
 def tpot_values(records) -> list[float]:
     """Per request with >= 2 tokens: (last token - first token) /
     (tokens - 1), ms."""

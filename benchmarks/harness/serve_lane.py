@@ -227,12 +227,21 @@ def warm_up(engine, cfg: dict, mix: dict, seed: int) -> None:
     run_window(engine, warm, 30.0, None)
 
 
-def window_account(ctx: dict, attempted: int) -> dict:
+def window_account(ctx: dict, attempted: int, unfinished: list) -> dict:
     """What the window held, for telling a seed's work from the host's
     time: wall, tokens, requests begun, and steps and host-timed wall of
-    prefill and decode."""
+    prefill and decode; whether a backlog grew
+    (``readers.queue_p50_by_thirds``) and, where the lane's drain closed the window, how many
+    requests were in flight and how many still queued then."""
     acc = {"wall_s": ctx["window_s"], "tokens": ctx["tokens_done"],
            "begun": attempted}
+    thirds = readers.queue_p50_by_thirds(ctx["records"])
+    if thirds:
+        acc["queue_p50_ms_thirds"] = thirds
+    if ctx["summary"].get("drained"):
+        in_flight = sum(1 for e in unfinished if e.get("produced"))
+        acc["at_close"] = {"in_flight": in_flight,
+                           "queued": len(unfinished) - in_flight}
     for kind in ("prefill", "decode"):
         t = readers.bucket_totals(ctx, kind)
         if t:
@@ -256,7 +265,6 @@ def run_cell(cell: dict, cfg: dict, mix: dict, args, t_proc: float,
     tap = LogitTap(engine, args.seed, args.seconds)
     tracer = None
     if args.trace:
-        tracing.annotate_engine(engine)
         tracer = tracing.WindowTracer(
             os.path.join(workdir, "trace"), args.seconds)
     setup_s = time.monotonic() - t_proc
@@ -324,4 +332,4 @@ def run_cell(cell: dict, cfg: dict, mix: dict, args, t_proc: float,
             "setup_s": setup_s,
             "also": {"serve_ttft_p90_ms":
                      readers.request_percentile(ctx, "ttft_ms", 90),
-                     "window": window_account(ctx, attempted)}}
+                     "window": window_account(ctx, attempted, unfinished)}}

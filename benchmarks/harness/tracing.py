@@ -1,6 +1,7 @@
-"""The traced run's instrumentation, all on the benchmark's side: spans
-around the calls into the program's compiled buckets, and a profiler
-window that opens inside the measured window and is reduced afterwards."""
+"""The traced run's instrumentation on the benchmark's side: a profiler
+window that opens inside the measured window and is reduced afterwards.
+The spans in it are the program's own (``hc:<name>``,
+``tpu_hc_bench.obs.timeline``); the benchmark adds none."""
 
 from __future__ import annotations
 
@@ -12,28 +13,6 @@ from harness import xplane
 
 TRACE_START_FRACTION = 0.25     # of the window, before the trace opens
 TRACE_SECONDS = 6.0             # a few seconds: traces are large
-
-
-class _Annotated:
-    """A compiled bucket with a ``bench:<kind>_<bucket>`` span around
-    each call; everything else is the executable's own."""
-
-    def __init__(self, name: str, exe):
-        self._name, self._exe = xplane.SPAN_PREFIX + name, exe
-
-    def __call__(self, *a, **k):
-        import jax
-
-        with jax.profiler.TraceAnnotation(self._name):
-            return self._exe(*a, **k)
-
-    def __getattr__(self, item):
-        return getattr(self._exe, item)
-
-
-def annotate_engine(engine) -> None:
-    engine.compiled = {key: _Annotated(f"{key[0]}_{key[1]}", exe)
-                       for key, exe in engine.compiled.items()}
 
 
 class WindowTracer:

@@ -1,24 +1,29 @@
 """The reduction from the profiler's xplane trace to device metrics:
 busy union, idle share, per-operation durations, exposed collective time
-and the longest idle gaps named by what the host did last.
+and the idle gaps charged to what the program says it was doing.
 
 Planes named ``/device:TPU:<n>`` are chips; on each, the line ``XLA Ops``
 holds one event per executed operation (start and duration in
 nanoseconds).  ``while`` / ``conditional`` bodies nest inside their
 parent's event, so the busy time is the UNION of intervals, never a sum.
-Host planes hold the harness's ``TraceAnnotation`` spans (``bench:<name>``)
-on the same clock, which is how a gap gets its name.
+Host planes hold the PROGRAM's own spans on the same clock (``hc:<name>``:
+``tpu_hc_bench.obs.timeline`` writes every live span and every phase of the
+serve loop into an open trace), which is how a gap gets its name.  The
+spans nest (``decode`` holds ``decode_dispatch`` and ``decode_wait``), so a
+moment belongs to the INNERMOST span open then.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
 
 OPS_LINE = "XLA Ops"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-SPAN_PREFIX = "bench:"
+SPAN_PREFIX = "hc:"
+NO_SPAN = "no span open"
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute|send|recv)", re.I)
@@ -62,16 +67,19 @@ def device_ops(profile) -> dict[int, list[tuple[str, float, float]]]:
     return out
 
 
-def host_spans(profile) -> list[tuple[str, float, float]]:
-    """The harness's own annotations, ``bench:<name>`` stripped."""
+def host_spans(profile, prefix: str = SPAN_PREFIX
+               ) -> list[tuple[str, float, float]]:
+    """``(name, start_s, end_s)`` of every event of the host planes whose
+    name starts with ``prefix`` (the program's ``hc:`` spans), prefix
+    stripped, by start."""
     out = []
     for plane in profile.planes:
         if DEVICE_PLANE.match(plane.name):
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith(SPAN_PREFIX):
-                    out.append((e.name[len(SPAN_PREFIX):], e.start_ns * 1e-9,
+                if e.name.startswith(prefix):
+                    out.append((e.name[len(prefix):], e.start_ns * 1e-9,
                                 (e.start_ns + e.duration_ns) * 1e-9))
     return sorted(out, key=lambda s: s[1])
 
@@ -183,18 +191,62 @@ def reduce_trace(profile, chips: int | None = None) -> dict:
     }
 
 
-def name_gaps(gaps, spans, top: int = 10) -> list[list]:
-    """Sum idle gaps under the name of the harness span that was open
-    when the gap began, else the one that ended last before it."""
-    sums: dict[str, float] = {}
+def innermost_points(spans) -> list[tuple[float, str]]:
+    """Change points ``(t, label)``: from ``t`` until the next point the
+    innermost open span (the one that started last among those open) is
+    ``label``.  ``spans`` are sorted by start, so the greatest open index
+    is the innermost."""
+    edges = sorted([(s, 0, i) for i, (_, s, _) in enumerate(spans)]
+                   + [(e, 1, i) for i, (_, _, e) in enumerate(spans)])
+    open_now: set[int] = set()
+    points: list[tuple[float, str]] = []
+    for t, closing, i in edges:
+        (open_now.discard if closing else open_now.add)(i)
+        label = spans[max(open_now)][0] if open_now else NO_SPAN
+        if points and points[-1][0] == t:
+            points[-1] = (t, label)
+        elif not points or points[-1][1] != label:
+            points.append((t, label))
+    return points
+
+
+def charge_gaps(gaps, spans) -> dict[str, dict]:
+    """span name -> idle seconds ``began_in_s`` / ``split_s`` and the
+    number of gaps that began in it.  Each gap is charged twice: whole, to
+    the innermost span open when it began, and split, second by second,
+    among the innermost spans open while it lasted.  A gap begins while
+    the host still waits for the program that just ended, so ``began in``
+    reads ``decode_wait`` where ``split`` shows who held the device up
+    afterwards."""
+    points = innermost_points(spans)
+    times = [t for t, _ in points]
+    out: dict[str, dict] = {}
+
+    def row(k):
+        return out.setdefault(
+            points[k][1] if k >= 0 else NO_SPAN,
+            {"began_in_s": 0.0, "gaps": 0, "split_s": 0.0})
+
     for g0, g1 in gaps:
-        label = "before_the_first_span"
-        for name, s, e in spans:
-            if s > g0:
-                break
-            label = ("during_" if e >= g0 else "after_") + name
-        sums[label] = sums.get(label, 0.0) + (g1 - g0)
-    return [[k, v] for k, v in sorted(sums.items(),
+        k = bisect.bisect_right(times, g0) - 1
+        first = row(k)
+        first["began_in_s"] += g1 - g0
+        first["gaps"] += 1
+        t = g0
+        while k + 1 < len(times) and times[k + 1] < g1:
+            row(k)["split_s"] += times[k + 1] - t
+            k, t = k + 1, times[k + 1]
+        row(k)["split_s"] += g1 - t
+    return out
+
+
+def name_gaps(gaps, spans, top: int = 10) -> list[list]:
+    """The result line's ``idle_gaps``: the idle seconds split among the
+    program's innermost spans open while each gap lasted (``arrival_wait``,
+    ``decode_dispatch``, ``retire``), the largest first."""
+    split = {k: v["split_s"] for k, v in charge_gaps(gaps, spans).items()
+             if v["split_s"] > 0}
+    return [[k, v] for k, v in sorted(split.items(),
                                       key=lambda kv: -kv[1])[:top]]
 
 

@@ -1,0 +1,111 @@
+"""``BENCHMARK.json`` against itself and against the files its names
+find: a cell that is retired must take its name out of every metric, and
+no metric may be left without a cell in which it reads a number."""
+
+import os
+
+import pytest
+
+from harness import spec, traffic
+
+BENCH = spec.load_benchmark()
+CELLS = [w["name"] for w in BENCH["workloads"]]
+# what every lane puts into the context of every run
+ALWAYS_THERE = {"setup_s", "device.compiles_in_window"}
+METRICS = [(g, m) for g in ("end_to_end", "per_layer") for m in BENCH[g]]
+
+
+def dangling(bench: dict) -> list[str]:
+    """What is wrong between the metrics' ``workloads`` lists and the
+    cells: a name that is no cell, a list that names none that exists, a
+    ``moves`` that is no end-to-end metric or that one of the metric's
+    cells does not report, a cell without an end-to-end metric beside
+    ``setup_s`` or without a per-layer one."""
+    cells = {w["name"] for w in bench["workloads"]}
+    wrong = []
+    for group in ("end_to_end", "per_layer"):
+        for m in bench[group]:
+            listed = m.get("workloads")
+            if listed is None:
+                continue
+            wrong += [f"{m['name']} lists {w}, which is no cell"
+                      for w in listed if w not in cells]
+            if not cells & set(listed):
+                wrong.append(f"{m['name']} names no cell that exists")
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    for m in bench["per_layer"]:
+        moved = e2e.get(m["moves"])
+        if moved is None:
+            wrong.append(f"{m['name']} moves {m['moves']}, no such metric")
+            continue
+        for w in cells & set(m.get("workloads", cells)):
+            if "workloads" in moved and w not in moved["workloads"]:
+                wrong.append(f"{m['name']} is read in {w}, which does not "
+                             f"report {m['moves']}")
+    for w in sorted(cells):
+        mine = [m["name"] for m in spec.metrics_for(bench, "end_to_end", w)]
+        if "setup_s" not in mine or len(mine) < 2:
+            wrong.append(f"{w} reports {mine}")
+        if not spec.metrics_for(bench, "per_layer", w):
+            wrong.append(f"{w} has no per-layer metric")
+    return wrong
+
+
+def test_no_metric_names_a_cell_that_is_gone():
+    assert dangling(BENCH) == []
+
+
+def test_the_check_sees_a_retired_cell_left_in_a_list():
+    import copy
+
+    bench = copy.deepcopy(BENCH)
+    gone = bench["workloads"].pop(0)["name"]
+    wrong = dangling(bench)
+    assert any(f"lists {gone}" in w for w in wrong)
+    assert any("names no cell that exists" in w for w in wrong)
+    bench = copy.deepcopy(BENCH)
+    bench["per_layer"][0]["workloads"] = ["gpt2m-serve-chat"]
+    assert len(dangling(bench)) == 2
+
+
+def test_pr_27s_cells_are_there_and_the_retired_ones_gone():
+    """Later PRs add cells beside these three; none brings a retired
+    name back (the ledger's ``level`` of it is of other traffic)."""
+    mine = {"gpt2m-serve-backlog", "gpt2m-serve-chat-loaded",
+            "gpt2m-train-1k"}
+    assert mine <= set(CELLS)
+    assert not {"gpt2m-serve-chat", "gpt2m-serve-burst"} & set(CELLS)
+    assert all(w["chips"] == 1 and w["config"] == "gpt2_medium"
+               for w in BENCH["workloads"] if w["name"] in mine)
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    assert BENCH["run_seconds"] == 51
+    bounds = {m["name"]: m["bound"] for m in BENCH["end_to_end"]}
+    assert bounds["serve_tpot_p90_ms"] <= 0.06
+    assert bounds["serve_tokens_per_s"] <= 0.02
+    assert bounds["train_examples_per_s"] <= 0.01
+    assert bounds["setup_s"] <= 0.1
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_finds_its_files_by_name(cell):
+    w = spec.cell_of(BENCH, cell)
+    assert len(w["why"]) <= 200 and "\n" not in w["why"]
+    cfg = spec.config_of(BENCH, w["config"])
+    mix = traffic.load_mix(w["traffic"])
+    assert mix["lane"] + "_arm" in cfg
+    assert hasattr(traffic.generator_of(mix), "tiny")
+    # nothing of a retired mix is left beside the ones in use
+    used = {x["traffic"] + ".json" for x in BENCH["workloads"]}
+    assert set(os.listdir(traffic.TRAFFIC_DIR)) == used
+
+
+@pytest.mark.parametrize("group,metric", METRICS,
+                         ids=[m["name"] for _, m in METRICS])
+def test_every_metric_has_a_reader_that_returns_nothing_on_nothing(
+        group, metric):
+    read = spec.reader_of(metric["name"])
+    if metric["name"] not in ALWAYS_THERE:
+        assert read({}) is None
+    assert set(metric) <= {"name", "unit", "better", "bound", "source",
+                           "layer", "moves", "workloads"}

@@ -69,7 +69,7 @@ def test_gap_phases_charges_gaps_to_the_innermost_open_span(gap_phases):
     tracer's own events, which are not the program's."""
     profile = xplane.load(os.path.join(
         HERE, "data", "nested_spans_handmade.xplane.txt"))
-    assert [n for n, _, _ in gap_phases.program_spans(profile)][:3] == [
+    assert [n for n, _, _ in xplane.host_spans(profile)][:3] == [
         "decode", "decode_dispatch", "decode_wait"]
     rep = gap_phases.report(profile)
     assert rep["window_s"] == pytest.approx(400 * US)
@@ -85,10 +85,31 @@ def test_gap_phases_charges_gaps_to_the_innermost_open_span(gap_phases):
     split = {k: round(v["split_s"] / US) for k, v in ph.items()
              if v["split_s"]}
     assert split == {"decode_wait": 20, "retire": 50, "telemetry": 10,
-                     gap_phases.NO_SPAN: 10, "pack": 20,
+                     xplane.NO_SPAN: 10, "pack": 20,
                      "decode_dispatch": 10}
     assert rep["idle_named_share"] == pytest.approx(110 / 120)
     assert ph["decode"]["spans"] == 2
     assert ph["decode"]["span_s"] == pytest.approx(250 * US)
     text = "\n".join(gap_phases.table(rep))
     assert "retire" in text and "91.7%" in text
+
+
+def test_the_result_lines_idle_gaps_carry_the_loops_phases():
+    """``xplane.name_gaps`` on the same trace: what a traced run prints
+    as ``breakdown.idle_gaps`` is the split charge by the program's
+    ``hc:`` spans, largest first — ``retire``, not ``after_decode_16``
+    (the ``bench:`` span beside them is nobody's)."""
+    profile = xplane.load(os.path.join(
+        HERE, "data", "nested_spans_handmade.xplane.txt"))
+    red = xplane.reduce_trace(profile)
+    gaps = xplane.name_gaps(red["gaps"], xplane.host_spans(profile))
+    assert gaps[0] == ["retire", pytest.approx(50 * US)]
+    assert {k: round(v / US) for k, v in gaps} == {
+        "retire": 50, "decode_wait": 20, "pack": 20, "telemetry": 10,
+        xplane.NO_SPAN: 10, "decode_dispatch": 10}
+    assert sum(v for _, v in gaps) == pytest.approx(
+        red["window_s"] - red["busy_s"])
+    assert not any("decode_16" in k or k == "decode" for k, _ in gaps)
+    assert [k for k, _ in xplane.name_gaps(red["gaps"], [], top=3)] == [
+        xplane.NO_SPAN]
+

@@ -225,9 +225,10 @@ def rehearse_cell(bench, name, seed=31):
 
 
 @pytest.mark.parametrize("name,fault,correct", [
-    ("gpt2m-serve-chat", None, True),
-    ("gpt2m-serve-chat", "token_altered", False),
-    ("gpt2m-serve-burst", "token_altered", False),
+    ("gpt2m-serve-chat-loaded", None, True),
+    ("gpt2m-serve-chat-loaded", "token_altered", False),
+    ("gpt2m-serve-backlog", None, True),
+    ("gpt2m-serve-backlog", "token_altered", False),
     ("gpt2m-train-1k", None, True),
     ("gpt2m-train-1k", "state_unchanged", False),
     ("gpt2m-train-1k", "half_batch", False),
@@ -241,3 +242,26 @@ def test_a_broken_timed_path_ends_with_correct_false(bench, name, fault,
     assert result["correct"] is correct, out[-2000:]
     line = json.loads(out.strip().splitlines()[-1])
     assert line["platform"] == "cpu" and "metrics" not in line
+
+
+@pytest.mark.parametrize("name", ["gpt2m-serve-chat-loaded",
+                                  "gpt2m-serve-backlog"])
+def test_run_py_rehearses_the_cell_from_the_command_line(name):
+    """``run.py --rehearse`` as a process of its own: the cell, its mix
+    and its configuration are found by name, every answer comes, and the
+    line carries counts and nothing under a device metric's name."""
+    import subprocess
+    import sys
+
+    got = subprocess.run(
+        [sys.executable, os.path.join(spec.BENCH_DIR, "run.py"),
+         "--workload", name, "--seed", str(2**31 + 27), "--seconds", "2",
+         "--trace", "0", "--rehearse"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=spec.ROOT,
+        capture_output=True, text=True, timeout=600)
+    assert got.returncode == 0, got.stderr[-2000:]
+    line = json.loads(got.stdout.strip().splitlines()[-1])
+    assert line["rehearsal"] == name and line["correct"] is True
+    counts = line["counts"]
+    assert counts["failed"] == 0 and counts["requests_finished"] > 0
+    assert "metrics" not in line and "device" not in line

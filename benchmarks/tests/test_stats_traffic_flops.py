@@ -12,6 +12,9 @@ from families import gpt2
 from harness import flops, readers, spec, stats, traffic
 from harness.generators import stratified_open_loop as open_loop
 
+SERVE_MIXES = ["chat-loaded", "longprompt-backlog"]
+SEEDS = (1, 2, 2**31 + 12345)
+
 
 def test_percentile_is_nearest_rank_and_exact():
     v = list(range(1, 101))
@@ -43,19 +46,20 @@ def test_iqr_share_is_statistics_quantiles():
     assert stats.iqr_share([1, 2, 3, 4, 5, 6]) == pytest.approx(3.5 / 3.5)
 
 
-@pytest.mark.parametrize("mix_name", ["chat-steady", "longprompt-burst"])
+@pytest.mark.parametrize("mix_name", SERVE_MIXES)
 def test_every_seed_offers_the_same_multiset_in_another_order(mix_name):
     mix = traffic.load_mix(mix_name)
-    runs = [open_loop.requests(mix, 51, seed, 50257)
-            for seed in (1, 2, 2**31 + 12345)]
+    runs = [open_loop.requests(mix, 51, seed, 50257) for seed in SEEDS]
     lens = [sorted(len(r["prompt"]) for r in run) for run in runs]
     outs = [sorted(r["output_len"] for r in run) for run in runs]
     gaps = [np.sort(np.diff([r["arrival_s"] for r in run])) for run in runs]
     assert lens[0] == lens[1] == lens[2]
     assert outs[0] == outs[1] == outs[2]
-    # all gaps but the last (which closes the span unseen) are the same set
+    # the gaps between arrivals: the same multiset but for the one gap
+    # that closes the span unseen, which the seed picks
     for g in gaps[1:]:
         assert len(g) == len(gaps[0])
+        assert len(set(np.round(g, 9)) ^ set(np.round(gaps[0], 9))) <= 2
     assert [len(r["prompt"]) for r in runs[0]] != \
         [len(r["prompt"]) for r in runs[1]]
     assert any((a["prompt"][:4] != b["prompt"][:4]).any()
@@ -72,10 +76,9 @@ def test_block_order_gives_every_seed_the_same_work_in_any_prefix():
     """A backlog's window ends inside the trace: whichever prefix it
     holds has to be the same work for every seed, and a fair sample of
     the whole mix."""
-    mix = traffic.load_mix("longprompt-burst")
+    mix = traffic.load_mix("longprompt-backlog")
     b = mix["order_block"]
-    runs = [open_loop.requests(mix, 51, seed, 50257)
-            for seed in (1, 2, 2**31 + 12345)]
+    runs = [open_loop.requests(mix, 51, seed, 50257) for seed in SEEDS]
     n = len(runs[0])
     for k in range(b, n + 1, b):
         heads = [(sorted(len(r["prompt"]) for r in run[:k]),
@@ -95,6 +98,33 @@ def test_block_order_gives_every_seed_the_same_work_in_any_prefix():
     assert abs(c) < 0.15
 
 
+@pytest.mark.parametrize("mix_name,offered", [
+    ("chat-loaded", 408), ("longprompt-backlog", 612)])
+def test_the_mixes_offer_what_the_cells_say(mix_name, offered):
+    """``run_seconds`` x the mix's rate, whatever the seed; the backlog's
+    all due in the first tenth of the window."""
+    mix = traffic.load_mix(mix_name)
+    seconds = spec.load_benchmark()["run_seconds"]
+    for seed in SEEDS:
+        run = open_loop.requests(mix, seconds, seed, 50257)
+        assert len(run) == offered
+        assert run[-1]["arrival_s"] < seconds * mix["arrival_span_fraction"]
+    assert mix["max_in_flight"] == 16
+
+
+@pytest.mark.parametrize("mix_name", SERVE_MIXES)
+def test_every_request_fits_the_published_positions(mix_name):
+    """Prompt + output inside GPT-2's 1,024 positions for every request
+    of every seed: no operation of the traffic can fail on length."""
+    mix = traffic.load_mix(mix_name)
+    positions = gpt2m()["n_positions"]
+    for seed in SEEDS:
+        run = open_loop.requests(mix, 51, seed, 50257)
+        assert max(len(r["prompt"]) + r["output_len"] for r in run) \
+            <= positions == 1024
+        assert min(r["output_len"] for r in run) >= 2   # a TPOT each
+
+
 def test_gap_quantiles_sum_to_the_span_whatever_the_seed():
     g = open_loop.exponential_gap_quantiles(128, 51.0)
     assert g.sum() == pytest.approx(51.0)
@@ -102,7 +132,7 @@ def test_gap_quantiles_sum_to_the_span_whatever_the_seed():
 
 
 def test_same_seed_same_inputs():
-    mix = traffic.load_mix("chat-steady")
+    mix = traffic.load_mix("chat-loaded")
     a = open_loop.requests(mix, 10, 99, 50257)
     b = open_loop.requests(mix, 10, 99, 50257)
     assert all((x["prompt"] == y["prompt"]).all()
@@ -115,7 +145,7 @@ def test_same_seed_same_inputs():
 
 
 def test_warmup_touches_every_reachable_prefill_bucket():
-    mix = traffic.load_mix("longprompt-burst")
+    mix = traffic.load_mix("longprompt-backlog")
     w = open_loop.warmup(mix, (8, 16, 32, 64, 128, 256, 512, 1024),
                                 16, 50257, 1)
     assert len(w) == 16

@@ -70,14 +70,21 @@ def test_exposed_collective_time(handmade):
         "collective_exposed_s"] == pytest.approx(90 * US)
 
 
-def test_idle_gaps_are_named_by_what_the_host_did_last(handmade):
+def test_idle_gaps_are_split_among_the_spans_open_while_they_last(handmade):
+    """The one gap [520,600) us: ``step_a`` ended at 510, ``step_b``
+    opens at 590, so 70 us lie under no span and 10 under ``step_b``.
+    The hand-made spans carry the prefix a recorded trace of PR 23 has;
+    the program's own are ``hc:`` (the default)."""
     red = xplane.reduce_trace(handmade)
-    spans = xplane.host_spans(handmade)
+    assert xplane.host_spans(handmade) == []
+    spans = xplane.host_spans(handmade, prefix="bench:")
     assert [s[0] for s in spans] == ["step_a", "step_b"]
     gaps = xplane.name_gaps(red["gaps"], spans)
-    assert gaps == [["after_step_a", pytest.approx(80 * US)]]
-    assert xplane.name_gaps([(0.0, 1e-6)], [])[0][0] == \
-        "before_the_first_span"
+    assert gaps == [[xplane.NO_SPAN, pytest.approx(70 * US)],
+                    ["step_b", pytest.approx(10 * US)]]
+    assert xplane.name_gaps([(0.0, 1e-6)], []) == [
+        [xplane.NO_SPAN, pytest.approx(1e-6)]]
+    assert xplane.name_gaps(red["gaps"], spans, top=1) == gaps[:1]
 
 
 def test_names_carry_the_result_shape_and_kernels_are_custom_calls():
@@ -108,7 +115,7 @@ def test_recorded_v5e_trace_reduces_to_what_was_seen_on_the_chip():
     assert red["ops"]["fusion:bf16[1024,1024]"] == pytest.approx(
         37.9e-6, rel=0.02)
     assert red["custom_calls"] == {} and red["collective_s"] == 0
-    spans = xplane.host_spans(prof)
+    spans = xplane.host_spans(prof, prefix="bench:")
     assert [s[0] for s in spans] == ["work_0", "work_1", "work_2"]
     assert len(red["gaps"]) >= 2
     named = dict(map(tuple, xplane.name_gaps(red["gaps"], spans)))

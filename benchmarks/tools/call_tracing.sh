@@ -1,4 +1,5 @@
-# The chip calls of PR 24 (tracing): the serve loop's own account beside the
+# The chip calls of PR 24 (tracing; the serve cells carry PR 27's names): the
+# serve loop's own account beside the
 # device trace, and what the instrumentation costs.  Before a call, the
 # parent commit with THIS benchmark laid over it (what the driver measures
 # the parent with) is unpacked into a directory that .gitignore lists:
@@ -42,16 +43,16 @@ first)
   # benchmark (its line must lack the new metrics and nothing else), one
   # untraced pair a cell, and the train cell (its driver's spans and the
   # kernels' names changed): parent, change, change, parent
-  run chat_t1_parent .chip_archive/parent gpt2m-serve-chat 3000024001 1
-  run chat_t1 . gpt2m-serve-chat 3000024001 1 BENCH_KEEP_TRACE=1
+  run chat_t1_parent .chip_archive/parent gpt2m-serve-chat-loaded 3000024001 1
+  run chat_t1 . gpt2m-serve-chat-loaded 3000024001 1 BENCH_KEEP_TRACE=1
   gaps chat_t1 .
-  run burst_t1 . gpt2m-serve-burst 24002 1 BENCH_KEEP_TRACE=1
-  gaps burst_t1 .
-  run burst_t1_parent .chip_archive/parent gpt2m-serve-burst 24002 1
-  run chat_t0_parent .chip_archive/parent gpt2m-serve-chat 24003 0
-  run chat_t0 . gpt2m-serve-chat 24003 0
-  run burst_t0 . gpt2m-serve-burst 3000024004 0
-  run burst_t0_parent .chip_archive/parent gpt2m-serve-burst 3000024004 0
+  run backlog_t1 . gpt2m-serve-backlog 24002 1 BENCH_KEEP_TRACE=1
+  gaps backlog_t1 .
+  run backlog_t1_parent .chip_archive/parent gpt2m-serve-backlog 24002 1
+  run chat_t0_parent .chip_archive/parent gpt2m-serve-chat-loaded 24003 0
+  run chat_t0 . gpt2m-serve-chat-loaded 24003 0
+  run backlog_t0 . gpt2m-serve-backlog 3000024004 0
+  run backlog_t0_parent .chip_archive/parent gpt2m-serve-backlog 3000024004 0
   run train_t1_parent .chip_archive/parent gpt2m-train-1k 24005 1
   run train_t1 . gpt2m-train-1k 24005 1
   ;;

@@ -6,20 +6,18 @@ into the profiler's trace (the serve loop's phases among them).
     BENCH_KEEP_TRACE=1 python3 benchmarks/run.py --workload W ... --trace 1
     python3 benchmarks/tools/gap_phases.py .bench_work/trace [--chips N]
 
-The trace is reduced by the harness's own ``xplane.reduce_trace``; each
-idle gap of its first chip is then charged twice: whole, to the INNERMOST
-``hc:`` span open when the gap began (spans nest: the one that started
-last among those open), and split, second by second, among the innermost
-spans open while it lasted.  A gap begins while the host still waits for
-the program that just ended, so ``began in`` reads ``decode_wait`` where
-``split`` shows who held the device up afterwards.  Prints a table and,
-last, one JSON line.
+The trace is reduced by the harness's own ``xplane.reduce_trace`` and each
+idle gap of its first chip charged by ``xplane.charge_gaps`` (whole, to
+the innermost ``hc:`` span open when it began; and split among the
+innermost spans open while it lasted: the ``split`` column is what a
+traced run's result line carries as ``idle_gaps``).  Here beside it: how
+many gaps began in each phase, and each phase's spans and seconds inside
+the traced window.  Prints a table and, last, one JSON line.
 """
 
 from __future__ import annotations
 
 import argparse
-import bisect
 import json
 import os
 import sys
@@ -28,68 +26,6 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [BENCH_DIR]
 
 from harness import xplane  # noqa: E402
-
-PREFIX = "hc:"
-NO_SPAN = "no span open"
-
-
-def program_spans(profile) -> list[tuple[str, float, float]]:
-    """``(name, start_s, end_s)`` of every ``hc:`` event of the host
-    planes, prefix stripped, by start."""
-    out = []
-    for plane in profile.planes:
-        if xplane.DEVICE_PLANE.match(plane.name):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith(PREFIX):
-                    out.append((e.name[len(PREFIX):], e.start_ns * 1e-9,
-                                (e.start_ns + e.duration_ns) * 1e-9))
-    return sorted(out, key=lambda s: s[1])
-
-
-def innermost_points(spans) -> list[tuple[float, str]]:
-    """Change points ``(t, label)``: from ``t`` until the next point the
-    innermost open span (the one that started last among those open) is
-    ``label``.  ``spans`` are sorted by start, so the greatest open index
-    is the innermost."""
-    edges = sorted([(s, 0, i) for i, (_, s, _) in enumerate(spans)]
-                   + [(e, 1, i) for i, (_, _, e) in enumerate(spans)])
-    open_now: set[int] = set()
-    points: list[tuple[float, str]] = []
-    for t, closing, i in edges:
-        (open_now.discard if closing else open_now.add)(i)
-        label = spans[max(open_now)][0] if open_now else NO_SPAN
-        if points and points[-1][0] == t:
-            points[-1] = (t, label)
-        elif not points or points[-1][1] != label:
-            points.append((t, label))
-    return points
-
-
-def charge_gaps(gaps, spans) -> dict[str, dict]:
-    """phase -> idle seconds ``began_in_s`` / ``split_s`` and the number
-    of gaps that began in it."""
-    points = innermost_points(spans)
-    times = [t for t, _ in points]
-    out: dict[str, dict] = {}
-
-    def row(k):
-        return out.setdefault(
-            points[k][1] if k >= 0 else NO_SPAN,
-            {"began_in_s": 0.0, "gaps": 0, "split_s": 0.0})
-
-    for g0, g1 in gaps:
-        k = bisect.bisect_right(times, g0) - 1
-        first = row(k)
-        first["began_in_s"] += g1 - g0
-        first["gaps"] += 1
-        t = g0
-        while k + 1 < len(times) and times[k + 1] < g1:
-            row(k)["split_s"] += times[k + 1] - t
-            k, t = k + 1, times[k + 1]
-        row(k)["split_s"] += g1 - t
-    return out
 
 
 def span_totals(spans, t0: float, t1: float) -> dict[str, list]:
@@ -106,15 +42,15 @@ def span_totals(spans, t0: float, t1: float) -> dict[str, list]:
 
 def report(profile, chips: int | None = None) -> dict:
     red = xplane.reduce_trace(profile, chips)
-    spans = program_spans(profile)
+    spans = xplane.host_spans(profile)
     idle = sum(g1 - g0 for g0, g1 in red["gaps"])
-    phases = charge_gaps(red["gaps"], spans)
+    phases = xplane.charge_gaps(red["gaps"], spans)
     totals = span_totals(spans, red["t0"], red["t0"] + red["window_s"])
     for name, (n, secs) in totals.items():
         phases.setdefault(
             name, {"began_in_s": 0.0, "gaps": 0, "split_s": 0.0}
         ).update(spans=n, span_s=secs)
-    named = idle - phases.get(NO_SPAN, {}).get("split_s", 0.0)
+    named = idle - phases.get(xplane.NO_SPAN, {}).get("split_s", 0.0)
     return {"window_s": red["window_s"], "busy_s": red["busy_s"],
             "idle_s": idle, "gaps": len(red["gaps"]),
             "idle_named_share": named / idle if idle else None,

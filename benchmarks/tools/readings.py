@@ -9,7 +9,7 @@ reads — the reference put in the program's place in the arm's
 arm's ``faults`` (the upper readings), each with its own ``correct``,
 which has to come out false.
 
-    python3 benchmarks/tools/readings.py --workload gpt2m-serve-chat \
+    python3 benchmarks/tools/readings.py --workload gpt2m-serve-chat-loaded \
         --seeds 11,12,13 --seconds 15 [--also fp8]
 """
 

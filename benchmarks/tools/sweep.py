@@ -4,8 +4,8 @@ one window per rate, and for each rate whether a backlog grew through the
 window.  The rate chosen (0.8 of the knee) then becomes a number in the
 traffic file; the benchmark never searches.
 
-    python3 benchmarks/tools/sweep.py --workload gpt2m-serve-chat \
-        --rates 1.5,2,2.5,3,3.5,4 --seconds 30 --seed 7
+    python3 benchmarks/tools/sweep.py --workload gpt2m-serve-chat-loaded \
+        --rates 4,6,8,10,12 --seconds 30 --seed 7
 """
 
 from __future__ import annotations
@@ -48,14 +48,13 @@ def main() -> int:
         reqs = gen.requests(m, args.seconds, args.seed, vocab)
         records, others, summary, wall = serve_lane.run_window(
             engine, reqs, args.seconds, None)
-        recs = sorted(records, key=lambda r: r["arrival_s"])
-        third = max(1, len(recs) // 3)
-        q = lambda rs: stats.percentile([r["queue_ms"] for r in rs], 50)  # noqa
+        recs = records
+        first, last = readers.queue_p50_by_thirds(recs)
         print(json.dumps({
             "rate": rate, "offered": len(reqs), "finished": len(records),
             "wall_s": wall, "drain_s": wall - reqs[-1]["arrival_s"],
-            "queue_p50_ms_first_third": q(recs[:third]),
-            "queue_p50_ms_last_third": q(recs[-third:]),
+            "queue_p50_ms_first_third": first,
+            "queue_p50_ms_last_third": last,
             "queue_max_ms": max(r["queue_ms"] for r in recs),
             "ttft_p90_ms": stats.percentile([r["ttft_ms"] for r in recs], 90),
             "tpot_p90_ms": stats.percentile(readers.tpot_values(recs), 90),
