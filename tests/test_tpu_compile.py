@@ -177,3 +177,70 @@ def test_gather_arm_holds_no_second_pool(one_chip, mosaic, program):
     assert not found, [(i.name, i.opcode) for i in found]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 4 * np.prod(leaf), temp
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
+                                                          program):
+    """One period of the hybrid family (a softmax layer over pages, three
+    delta-rule layers over state slots) at the published head sizes, a
+    narrow hidden size and 16 rows: the programs the chip's compiler
+    builds update the page pool AND the recurrent state ``S`` where they
+    rest (no new array of either's shape; the small convolution tails
+    are a row scatter, held to the bound on temporaries), and their
+    temporaries stay under the largest leaf's bytes (the engine's
+    ``kv_pool_temp_ratio``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpu_hc_bench.analysis import hlo
+    from tpu_hc_bench.models import solar_open2
+    from tpu_hc_bench.serve import decode
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = solar_open2.SolarOpen2LM(
+        vocab_size=2048, hidden=512, heads=8, kv_heads=2, kda_heads=8,
+        n_routed=16, experts_held=(0, 2), top_k=4, expert_ffn=256,
+        shared_ffn=256, dtype=jnp.bfloat16)
+    family = decode.build_family(model)
+    params = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+            train=False))["params"])
+    page, width, rows = 16, 40, 16
+    kv = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda: decode.init_kv_state(
+            family, 1 + rows * width, page, jnp.bfloat16, slots=rows + 1)))
+    if program == "decode":
+        fn = decode.build_decode_fn(family, page, width)
+        args = (sd((rows,), jnp.int32), sd((rows, width + 1), jnp.int32),
+                sd((rows,), jnp.int32), sd((rows,), jnp.bool_))
+    else:
+        fn = decode.build_prefill_fn(family, page, width)
+        args = (sd((1, 512), jnp.int32), sd((), jnp.int32),
+                sd((width + 1,), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    leaves = jax.tree.leaves(kv)
+    found = hlo.new_buffers_of_shape(
+        compiled.as_text(),
+        [hlo.shape_text(kv["pages"][0].shape, "bf16"),
+         hlo.shape_text(kv["state"]["S"].shape, "f32")])
+    # a leaf this small is moved whole into the chip's fast memory and
+    # back (a result in memory space ``S(1)``, asynchronous ``copy-start``
+    # / ``copy-done`` between the spaces): no re-layout, and at the
+    # cell's size (GBs) it cannot happen; the bound on temporaries below
+    # still holds it
+    found = [i for i in found
+             if i.opcode not in ("copy-start", "copy-done")
+             and "S(1)}" not in i.text.split(" = ")[1].split(" ")[0]]
+    assert not found, [(i.name, i.opcode) for i in found]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < max(np.prod(x.shape) * x.dtype.itemsize for x in leaves)
+    parts = set(decode.part_of_ops(compiled.as_text()).values())
+    assert parts == set(decode.PARTS)

@@ -299,6 +299,33 @@ def new_buffers_of_shape(module: HloModule | str,
             if ins.shape in shapes and not in_place(ins)]
 
 
+_FIRST_ARRAY = re.compile(r"[a-z0-9]+\[[0-9,]*\]")
+
+
+def ops_by_scope(module: HloModule | str, scopes,
+                 by_name: dict | None = None) -> dict[str, str]:
+    """``{"<instruction>:<result shape>": scope}`` for every instruction,
+    in any computation, whose ``op_name`` path holds one of ``scopes``
+    (``jax.named_scope`` names) as a whole component; the first that
+    appears on the path wins.  ``by_name`` gives the scope of
+    instructions that carry none, by the prefix of their own name (a
+    kernel the compiler names itself).  The key is how a device trace
+    names an operation: the instruction and the shape of its (first)
+    result."""
+    if isinstance(module, str):
+        module = parse_hlo(module)
+    out = {}
+    for ins in _iter_instructions(module):
+        scope = next((c for c in ins.op_name.split("/") if c in scopes),
+                     None) or next(
+            (v for k, v in (by_name or {}).items()
+             if ins.name.startswith(k)), None)
+        shape = _FIRST_ARRAY.search(ins.shape)
+        if scope and shape:
+            out[f"{ins.name}:{shape.group(0)}"] = scope
+    return out
+
+
 def op_attribution(module: HloModule, opcodes: tuple[str, ...] = ("dot",),
                    entry_only: bool = True) -> dict[str, list[str]]:
     """Map each instruction -> ``metadata op_name`` paths of the

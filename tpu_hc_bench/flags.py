@@ -63,13 +63,13 @@ SEQ_SHARDED_IMPLS = ("ring", "ulysses", "ulysses_flash")
 # explicitly set (flag-time, the same loudness contract as every other
 # invalid combination).  Knobs shared by both lanes (model, seed, dtype,
 # data_dir for the prompt corpus, compile_cache, metrics_dir, device,
-# hbm_budget, config) are deliberately absent.
+# hbm_budget, config) are deliberately absent: ``--use_fp16`` serves in
+# bfloat16 (matrices and KV pages held so, a recurrent state in float32;
+# float32 stays the default).
 TRAIN_ONLY_FLAGS = (
     "batch_size", "num_warmup_batches", "num_batches", "num_epochs",
     "display_every", "optimizer", "forward_only", "eval",
     "init_learning_rate", "momentum", "data_format",
-    "use_fp16",  # serving runs f32 reference decode for now (ROADMAP:
-                 # quantized serving arms)
     "variable_update", "overlap_grad_comm", "fusion_threshold_bytes",
     "num_intra_threads", "num_inter_threads", "kmp_blocktime",
     "kmp_affinity", "datasets_num_private_threads",

@@ -61,8 +61,8 @@ class ModelSpec:
 def _registry() -> dict[str, ModelSpec]:
     from tpu_hc_bench.models import (
         alexnet, bert, cifar_resnet, deepspeech, densenet, googlenet, gpt,
-        inception, llama, mobilenet, nasnet, ncf, resnet, small_cnns, vgg,
-        vit,
+        inception, llama, mobilenet, nasnet, ncf, resnet, small_cnns,
+        solar_open2, vgg, vit,
     )
 
     specs = [
@@ -167,6 +167,15 @@ def _registry() -> dict[str, ModelSpec]:
         # ~0.8M params: embed 131k + untied head 131k + 4 layers x ~136k
         ModelSpec("llama_tiny", llama.llama_tiny, (64,), 2 * 0.8e6 * 64,
                   is_text=True, vocab_size=1024, causal_lm=True),
+        # hybrid linear/softmax attention + sigmoid-routed MoE, as one
+        # chip's share of an EP8 deployment (serve lane): ~0.81 B
+        # parameters multiplied per token of the 3.31 B held
+        ModelSpec("solar_open2_250b_ep8", solar_open2.solar_open2_250b_ep8,
+                  (2048,), 2 * 0.81e9 * 2048, is_text=True,
+                  vocab_size=24576, causal_lm=True),
+        ModelSpec("solar_open2_tiny", solar_open2.solar_open2_tiny, (64,),
+                  2 * 0.2e6 * 64, is_text=True,
+                  vocab_size=solar_open2.TINY["vocab_size"], causal_lm=True),
     ]
     return {s.name: s for s in specs}
 

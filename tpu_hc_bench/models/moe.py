@@ -21,6 +21,24 @@ tokens are dropped (their combine weight is zero, the residual connection
 carries them through — standard Switch behavior); the Switch load-balance
 auxiliary loss is sown into the ``"losses"`` collection and picked up by
 ``train.step._loss_and_updates``.
+
+A second routing convention rides the same module (``score="sigmoid"``,
+the DeepSeek-V3 lineage): independent sigmoid scores in float32, the
+top-k chosen on ``score + router_bias`` (a per-expert selection bias that
+never enters the weights), the chosen scores normalized to sum to 1,
+SwiGLU experts (``gated``), and a shared expert every token passes
+through (``shared_ffn``).  ``experts_held=(lo, hi)`` makes the layer one
+chip's share of an expert-parallel deployment: it routes over all
+``num_experts``, holds the tensors of experts ``lo..hi-1`` only, and
+computes THEIR part of the result with the ragged (zero-drop) dispatch;
+what the absent experts would add is left out (on one chip the layer
+runs without its exchange, and nothing stands in for the absent chips).
+At a few rows an expert (a decode step) the grouped matmuls read every
+held expert's tensors anyway and their time follows which experts the
+step happened to leave empty, so up to ``DENSE_ROWS`` rows the share is
+computed as every held expert over every row, weighed by the row's gate
+for it (0 where it was not picked): the same sum, zero-drop too, at a
+cost no routing moves.
 """
 
 from __future__ import annotations
@@ -34,6 +52,13 @@ import jax.numpy as jnp
 # Switch-Transformer convention: aux = E * Σ_e f_e · p̄_e, weighted into the
 # total loss at this coefficient (Fedus et al. use 1e-2).
 AUX_LOSS_COEF = 0.01
+
+
+# The sigmoid share: up to this many rows, once every expert expects a
+# row (rows x top_k >= experts), every held expert multiplies every row
+# (``MoEFFN._dense``).  2 FLOPs a row a weight hide under the weight's
+# 2-byte read up to ~240 rows on a v5e (197 TFLOP/s over 819 GB/s).
+DENSE_ROWS = 256
 
 
 def topk_select(probs: jax.Array, top_k: int):
@@ -63,6 +88,20 @@ def topk_select(probs: jax.Array, top_k: int):
     denom = jnp.maximum(sum(gates), 1e-9)
     gates = [g / denom for g in gates]
     return masks, gates, choices, aux
+
+
+def sigmoid_topk(scores: jax.Array, bias: jax.Array, top_k: int,
+                 normalize: bool = True, scale: float = 1.0):
+    """Sigmoid-score routing: ``scores`` [..., E] float32 in (0, 1);
+    the ``top_k`` largest of ``scores + bias`` are chosen, and a chosen
+    expert's weight is its own score (the bias only moves the choice),
+    over the chosen scores' sum when ``normalize``.  Returns ``(choices
+    [..., k] int32, gates [..., k] float32)``."""
+    _, idx = jax.lax.top_k(scores + bias, top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    if normalize:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-20)
+    return idx.astype(jnp.int32), gates * scale
 
 
 def top_k_routing(probs: jax.Array, top_k: int, capacity: int):
@@ -136,11 +175,25 @@ class MoEFFN(nn.Module):
                                        # crash, not this kernel's VMEM —
                                        # see BASELINE.md MoE); the knob
                                        # stays for exploration
+    score: str = "softmax"             # "sigmoid": see the module docstring
+    gated: bool = False                # SwiGLU experts (``wg`` beside wi/wo)
+    shared_ffn: int = 0                # width of the shared expert, 0 = none
+    experts_held: tuple | None = None  # (lo, hi): this chip's share
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    param_dtype: Any = jnp.float32     # of the expert and shared tensors
 
     @nn.compact
     def __call__(self, x):
         b, s, h = x.shape
         e = self.num_experts
+        if self.score == "sigmoid":
+            return self._sigmoid_share(x)
+        if (self.gated or self.shared_ffn or self.experts_held
+                or self.score != "softmax"):
+            raise ValueError(
+                "gated / shared_ffn / experts_held belong to "
+                f"score='sigmoid' (got score={self.score!r})")
 
         router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                           param_dtype=jnp.float32, name="router")
@@ -151,7 +204,10 @@ class MoEFFN(nn.Module):
         wo = self.param("wo", init, (e, self.ffn, h))
 
         if self.impl == "ragged":
-            y, aux = self._ragged(x, probs, wi, wo)
+            _, gate_list, choices, aux = topk_select(
+                probs.reshape(b * s, e), self.top_k)
+            y = self._ragged(x, jnp.stack(choices, 1),
+                             jnp.stack(gate_list, 1), (wi, wo), e)
         elif self.impl == "einsum":
             y, aux = self._einsum(x, probs, wi, wo, s, e)
         else:
@@ -179,40 +235,114 @@ class MoEFFN(nn.Module):
         y = jnp.einsum("bsec,ebch->bsh", combine, out)
         return y, aux
 
-    def _ragged(self, x, probs, wi, wo):
+    def _sigmoid_share(self, x):
+        """``score="sigmoid"``: route over all ``num_experts``, compute
+        the held experts' part (ragged, zero-drop) plus the shared
+        expert.  Sows ``stats/picks_held`` [b, s]: how many of each
+        token's ``top_k`` picks landed on an expert held here."""
+        if self.impl != "ragged":
+            raise ValueError("score='sigmoid' dispatches ragged "
+                             f"(zero-drop) only, not {self.impl!r}")
         b, s, h = x.shape
-        e, k = self.num_experts, self.top_k
+        e = self.num_experts
+        lo, hi = self.experts_held or (0, e)
+        if not 0 <= lo < hi <= e:
+            raise ValueError(f"experts_held {self.experts_held} outside "
+                             f"0..{e}")
+        router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                          param_dtype=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST,
+                          name="router")
+        bias = self.param("router_bias", nn.initializers.zeros, (e,),
+                          jnp.float32)
+        scores = jax.nn.sigmoid(router(x.astype(jnp.float32)))
+        choices, gates = sigmoid_topk(
+            scores.reshape(b * s, e), bias, self.top_k, self.norm_topk,
+            self.routed_scale)
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        held = hi - lo
+        ws = [self.param(n, init, shape, self.param_dtype)
+              for n, shape in (("wg", (held, h, self.ffn)),
+                               ("wi", (held, h, self.ffn)),
+                               ("wo", (held, self.ffn, h)))
+              if n != "wg" or self.gated]
+        local = choices - lo
+        mine = (local >= 0) & (local < held)
+        self.sow("stats", "picks_held",
+                 mine.sum(-1).astype(jnp.int32).reshape(b, s))
+        # absent experts' pairs sort behind every held group and are
+        # counted in none: the grouped matmuls leave their rows out
+        share = (self._dense if e <= b * s * self.top_k
+                 and b * s <= DENSE_ROWS else self._ragged)
+        y = share(x, jnp.where(mine, local, held),
+                  jnp.where(mine, gates, 0.0), ws, held)
+        if self.shared_ffn:
+            dense = lambda f, name: nn.Dense(        # noqa: E731
+                f, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name)
+            xs = x.astype(self.dtype)
+            y = y + dense(h, "shared_down")(
+                nn.silu(dense(self.shared_ffn, "shared_gate")(xs))
+                * dense(self.shared_ffn, "shared_up")(xs))
+        return y.astype(x.dtype)
+
+    def _dense(self, x, choices, gates, ws, groups):
+        """``_ragged``'s sum without the dispatch: every group's expert
+        over every row, the hidden activations weighed by the row's gate
+        for that group (0 where the row did not pick it), one
+        contraction over groups and width.  Its work depends on the
+        shapes alone."""
+        b, s, h = x.shape
+        n = b * s
+        flat = jnp.broadcast_to(x.reshape(n, h).astype(self.dtype),
+                                (groups, n, h))
+        *w_in, wo_c = (w.astype(self.dtype) for w in ws)
+        # [groups, N]: a picked group's gate, 0 elsewhere (and for the
+        # id ``groups`` = no group, which one_hot leaves out)
+        combine = jnp.einsum(
+            "nkg,nk->gn", jax.nn.one_hot(choices, groups,
+                                         dtype=jnp.float32), gates)
+        up = [jnp.einsum("gnh,ghf->gnf", flat, w) for w in w_in]
+        h1 = nn.silu(up[0]) * up[1] if len(up) == 2 else nn.gelu(up[0])
+        h1 = h1 * combine[..., None].astype(self.dtype)
+        return jnp.einsum("gnf,gfh->nh", h1, wo_c).reshape(b, s, h)
+
+    def _ragged(self, x, choices, gates, ws, groups):
+        """``choices`` / ``gates`` [N, k]: each token's picks as group
+        ids in ``0..groups`` (``groups`` itself = no group: the pair's
+        row is computed by no expert and weighs 0) and their weights;
+        ``ws`` the expert tensors ``(wi, wo)`` or ``(wg, wi, wo)``."""
+        b, s, h = x.shape
+        e, k = groups, self.top_k
         n = b * s
         flat = x.reshape(n, h).astype(self.dtype)
-        p = probs.reshape(n, e)
-        _, gate_list, choices, aux = topk_select(p, k)
-        gates = jnp.stack(gate_list, 1)                   # [N, k]
 
         # token-major (token, choice) pairs sorted by expert -> grouped
         # matmuls over contiguous expert segments
-        pair_expert = jnp.stack(choices, 1).reshape(n * k)
+        pair_expert = choices.reshape(n * k)
         pair_token = jnp.repeat(jnp.arange(n), k)
         order = jnp.argsort(pair_expert)
         xs = flat[pair_token[order]]                      # [N*k, H]
-        wi_c, wo_c = wi.astype(self.dtype), wo.astype(self.dtype)
+        ws_c = tuple(w.astype(self.dtype) for w in ws)
 
         total = n * k
         if total <= self.ragged_chunk:
-            group_sizes = jnp.bincount(pair_expert, length=e).astype(
-                jnp.int32)
-            out = self._grouped_ffn(xs, group_sizes, wi_c, wo_c)
+            group_sizes = jnp.bincount(pair_expert, length=e + 1)[
+                :e].astype(jnp.int32)
+            out = self._grouped_ffn(xs, group_sizes, *ws_c)
         else:
             # chunked grouped matmuls (round 2): big batchxseq blew past
             # Mosaic's scoped-VMEM tiling limit (BASELINE.md r1: 19.4M >
             # 16M at bs=16/seq=1024).  A contiguous slice of the sorted
             # pair array is still expert-sorted, so each chunk is a valid
             # ragged_dot with its own histogram; padding rows are tagged
-            # with the last expert (keeps sortedness) and dropped after.
+            # past the last expert (keeps sortedness, no group) and
+            # dropped after.
             chunk = self.ragged_chunk
             pad = (-total) % chunk
             seg = jnp.concatenate(
                 [pair_expert[order],
-                 jnp.full((pad,), e - 1, pair_expert.dtype)])
+                 jnp.full((pad,), e, pair_expert.dtype)])
             xs_p = jnp.pad(xs, ((0, pad), (0, 0)))
             chunks = (total + pad) // chunk
             seg_c = seg.reshape(chunks, chunk)
@@ -220,18 +350,20 @@ class MoEFFN(nn.Module):
 
             def body(args):
                 xc, sz = args
-                return self._grouped_ffn(xc, sz, wi_c, wo_c)
+                return self._grouped_ffn(xc, sz, *ws_c)
 
             out = jax.lax.map(body, (xs_p.reshape(chunks, chunk, h), sizes))
             out = out.reshape(chunks * chunk, h)[:total]
         # inverse-permute back to token-major pair order; weighted sum
-        # over each token's k picks (pure gathers, no scatter)
+        # over each token's k picks (pure gathers, no scatter); a row no
+        # group computed is left out whatever the kernel wrote there
         inv = jnp.argsort(order)
         out = out[inv].reshape(n, k, h)
-        y = (out * gates[..., None].astype(self.dtype)).sum(axis=1)
-        return y.reshape(b, s, h), aux
+        w = gates[..., None].astype(self.dtype)
+        y = jnp.where(choices[..., None] < e, out * w, 0).sum(axis=1)
+        return y.reshape(b, s, h)
 
-    def _grouped_ffn(self, xs, sizes, wi_c, wo_c):
+    def _grouped_ffn(self, xs, sizes, *ws):
         """Expert FFN over one expert-sorted row block: two grouped
         matmuls, with the FFN dim tiled to ``ragged_f_chunk``.
 
@@ -243,6 +375,15 @@ class MoEFFN(nn.Module):
         second matmul's F-contraction distributes over slices as a sum —
         a lax.scan accumulates it without materializing [rows, F].
         """
+        if len(ws) == 3:
+            if self.ragged_f_chunk:
+                raise ValueError("ragged_f_chunk tiles the two-matrix "
+                                 "(gelu) experts only")
+            wg_c, wi_c, wo_c = ws
+            h1 = (nn.silu(jax.lax.ragged_dot(xs, wg_c, sizes))
+                  * jax.lax.ragged_dot(xs, wi_c, sizes))
+            return jax.lax.ragged_dot(h1, wo_c, sizes)
+        wi_c, wo_c = ws
         f = wi_c.shape[-1]
         fc = self.ragged_f_chunk
         if not fc or f <= fc:

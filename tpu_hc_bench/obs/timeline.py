@@ -100,7 +100,7 @@ KNOWN_SPANS = frozenset((
     "admit",
     # serve admission forensics (round 22): edge-triggered instants the
     # moment the queue blocks on a resource
-    "pool_starved", "batch_full",
+    "pool_starved", "batch_full", "slot_starved",
     # serve degradation (round 23): every load-shed, KV-pressure
     # preemption/requeue, poisoned-request quarantine, and SIGTERM
     # drain leaves an instant — failure forensics read the timeline

@@ -64,9 +64,34 @@ Two compiled shapes per family, both AOT-lowered at engine warmup
 - ``decode``: one token for a batch-bucket of in-flight requests at
   *per-row* cache depths (the continuous-batching shape).
 
+**The cache is a tree** (``init_kv_state``).  A family whose every
+layer attends over keys and values carries the pair ``(k_pages,
+v_pages)`` above.  A family with a per-layer MIXER KIND
+(``_Family.mixers``: ``"attn"`` | ``"kda"``) carries ``{"pages":
+(k_pages, v_pages), "state": {"S", "conv"}}``: pages for its attention
+layers ONLY (the pool's leading axis counts those), and a
+slot-addressed recurrent state for its gated delta-rule layers —
+``S [kda_layers, slots, heads, d_k, d_v]`` float32 and the short
+convolution's tail ``conv [kda_layers, K-1, slots, 3 * heads * d]`` in
+the compute dtype (slots beside channels: the two minor axes tile, so a
+row's taps are read and written where the leaf rests).  A request owns pages AND one slot from admit to
+finish; its slot index rides in one more column of its table (the
+LAST: column 0 stays its first page), so both programs keep their
+positional signatures.  Slot 0 is the trash slot, as page 0 is the
+trash page.  Prefill runs the chunked form of the recurrence over the
+padded bucket with the padding made inert and writes the state from
+zero whatever the slot held; decode runs one recurrence step for every
+slot at once, in place on the donated buffer (a slot no active row
+names keeps its state: decay 1, beta 0).  ``jax.named_scope`` names the
+four parts (``kda``, ``gqa``, ``moe``, ``head``) in both programs, and
+``part_of_ops`` maps a compiled program's operations to them.
+
 Supported families: ``GPTLM`` (gpt2*, moe*: learned positions, dense
-or MoE FFN) and ``LlamaLM`` (llama*: RoPE, GQA, SwiGLU).  Everything
-else that claims ``causal_lm`` fails loudly at engine construction.
+or MoE FFN), ``LlamaLM`` (llama*: RoPE, GQA, SwiGLU) and
+``SolarOpen2LM`` (solar_open2*: gated NoPE GQA every fourth layer,
+gated delta-rule layers between, sigmoid-routed MoE with a shared
+expert as a chip's share; gather arm, unquantized).  Everything else
+that claims ``causal_lm`` fails loudly at engine construction.
 """
 
 from __future__ import annotations
@@ -161,6 +186,24 @@ class _Family:
     ffn_norm_params: Callable   # (p_l) -> (gamma, beta|None)
     quant_paths: Callable       # (l) -> [(param path, contract axes)]
                                 # quantize_weights' int8_w walk
+    mixers: tuple = ()          # per layer "attn" | "kda"; () = all "attn"
+    counters: tuple = ()        # names of the int32 scalars a decode step
+                                # appends to its tokens (``next_tokens
+                                # [b + len(counters)]``: no extra transfer)
+    picks_per_token: int = 0    # routed-expert picks a token makes over
+                                # all layers (0: no share is counted)
+
+    @property
+    def kv_layers(self) -> tuple:
+        """The layers that keep keys and values in pages, in order."""
+        return tuple(l for l in range(self.num_layers)
+                     if not self.mixers or self.mixers[l] == "attn")
+
+    @property
+    def state_layers(self) -> tuple:
+        """The layers that keep a recurrent state in a slot."""
+        return tuple(l for l in range(self.num_layers)
+                     if self.mixers and self.mixers[l] == "kda")
 
     def embed_prefill(self, params, tokens):
         # positions arange(s) — exactly the training forward's layout
@@ -370,10 +413,49 @@ def build_family(model, quant: str = "off") -> _Family:
             quant_paths=quant_paths,
         )
 
+    from tpu_hc_bench.models.solar_open2 import SolarOpen2LM
+
+    if isinstance(model, SolarOpen2LM):
+        if quant != "off":
+            raise ValueError(
+                "--quant has no arm for the solar_open2 family (its "
+                "recurrent state and routed experts stay unquantized)")
+        dt = model.dtype
+        norm = lambda name: (lambda p_l, x: RMSNorm(      # noqa: E731
+            eps=model.eps, dtype=dt).apply({"params": p_l[name]}, x))
+
+        def ffn(p_l, h):
+            """-> (y, held picks per token [b, s])"""
+            y, st = model.moe_module().apply(
+                {"params": p_l["moe"]}, h, mutable=["stats"])
+            return y, st["stats"]["picks_held"][0]
+
+        return _Family(
+            model=model, num_layers=model.num_layers, heads=model.heads,
+            kv_heads=model.kv_heads, head_dim=model.head_dim,
+            norm_kind="rmsnorm",
+            embed_decode=lambda params, tokens, positions: params[
+                "tok_embed"]["embedding"].astype(dt)[tokens][:, None],
+            layer_params=lambda params, l: {
+                k: params[f"layer_{l}_{k}"]
+                for k in ("norm1", "mixer", "norm2", "moe")},
+            attn_norm=norm("norm1"),
+            attn_norm_params=lambda p_l: (p_l["norm1"]["scale"], None),
+            qkv=None, attn_out=None,    # the mixers' own functions
+            ffn=ffn,
+            ffn_norm=norm("norm2"),
+            ffn_norm_params=lambda p_l: (p_l["norm2"]["scale"], None),
+            quant_paths=lambda l: [],
+            mixers=tuple("attn" if model.mixer_kind(l) == "gqa" else "kda"
+                         for l in range(model.num_layers)),
+            counters=("moe_picks_held",),
+            picks_per_token=model.top_k * model.num_layers,
+        )
+
     raise ValueError(
         f"no paged-decode family for {type(model).__name__} (supported: "
-        "GPTLM, LlamaLM); non-causal members serve single-forward "
-        "requests instead")
+        "GPTLM, LlamaLM, SolarOpen2LM); non-causal members serve "
+        "single-forward requests instead")
 
 
 def quantize_weights(family: _Family, params: dict) -> dict:
@@ -394,8 +476,9 @@ def quantize_weights(family: _Family, params: dict) -> dict:
 def init_kv_pages(family: _Family, num_pages: int, page_size: int,
                   dtype) -> tuple[jax.Array, jax.Array]:
     """The zeroed page pool: ``[L, kv_heads, pages, page_size, lanes]``
-    x2, ``lanes`` = head_dim padded up to the 128-lane tile."""
-    shape = (family.num_layers, family.kv_heads, num_pages, page_size,
+    x2, ``L`` the layers that attend over pages, ``lanes`` = head_dim
+    padded up to the 128-lane tile."""
+    shape = (len(family.kv_layers), family.kv_heads, num_pages, page_size,
              _pad_up(family.head_dim, _LANES))
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
@@ -502,10 +585,24 @@ def _attend_rows(q, k_rows, v_rows, k_new, v_new, lengths):
 
 
 def init_kv_state(family: _Family, num_pages: int, page_size: int,
-                  dtype, quant: str = "off") -> tuple:
-    """The engine's KV carry: ``(k_pages, v_pages)`` — int8 pools plus
-    per-(layer, page) f32 scales under ``int8_kv`` (scales start at 1,
-    matching the zeroed pool)."""
+                  dtype, quant: str = "off", slots: int = 0):
+    """The engine's cache carry, a tree: ``(k_pages, v_pages)`` — int8
+    pools plus per-(layer, page) f32 scales under ``int8_kv`` (scales
+    start at 1, matching the zeroed pool) — or, for a family with
+    recurrent-state layers, ``{"pages": (k_pages, v_pages), "state":
+    {"S", "conv"}}`` with ``slots`` state slots (slot 0 the trash
+    slot): ``S`` float32 whatever ``dtype`` is."""
+    if family.state_layers:
+        m = family.model
+        n = m.kda_heads * m.kda_head_dim
+        layers = len(family.state_layers)
+        return {
+            "pages": init_kv_pages(family, num_pages, page_size, dtype),
+            "state": {
+                "S": jnp.zeros((layers, slots, m.kda_heads, m.kda_head_dim,
+                                m.kda_head_dim), jnp.float32),
+                "conv": jnp.zeros((layers, m.conv_kernel - 1, slots, 3 * n),
+                                  dtype)}}
     if quant == "int8_kv":
         kp, vp = init_kv_pages(family, num_pages, page_size, jnp.int8)
         sc = jnp.ones((family.num_layers, num_pages), jnp.float32)
@@ -533,6 +630,8 @@ def build_page_copy_fn():
                 return x.at[:, dst].set(x[:, src])
             return x.at[:, :, dst].set(x[:, :, src])
 
+        if isinstance(kv, dict):        # a state slot is never shared
+            return dict(kv, pages=jax.tree_util.tree_map(copy, kv["pages"]))
         return jax.tree_util.tree_map(copy, kv)
 
     return page_copy
@@ -616,6 +715,9 @@ def build_prefill_fn(family: _Family, page_size: int, table_width: int,
     """
     from tpu_hc_bench.parallel.sequence import dense_attention
 
+    if family.state_layers:
+        return _build_hybrid_prefill_fn(family, table_width)
+
     def prefill(params, kv, tokens, length, table):
         s = tokens.shape[1]
         positions = jnp.arange(s)[None, :]
@@ -689,6 +791,11 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
         raise ValueError("int8_kv scales are consumed inside the paged "
                          "kernel; the gather reference has no "
                          "scale-fused read path")
+    if family.state_layers and (attention != "gather" or quant != "off"):
+        raise ValueError(
+            "a family with recurrent-state layers decodes on the gather "
+            "arm, unquantized (--decode_attention=paged and --quant have "
+            "no kernel that knows its cache tree)")
     ppb = max(1, block_pages)
 
     def scatter_new(kv, tables, lengths, active, kn, vn):
@@ -713,6 +820,9 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
                         page_idx, offset),
             _write_pool(v_pages, _pool_rows(vn, lanes)[:, :, :, None],
                         page_idx, offset))
+
+    if family.state_layers:
+        return _build_hybrid_decode_fn(family, table_width, scatter_new)
 
     def decode_gather(params, kv, tokens, tables, lengths, active):
         k_pages, v_pages = kv
@@ -802,3 +912,191 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
                 scatter_new(kv, tables, lengths, active, kn, vn))
 
     return decode_paged if attention == "paged" else decode_gather
+
+
+# ---------------------------------------------------------------------
+# families with a per-layer mixer kind: pages for the attention layers,
+# a slot-addressed recurrent state for the gated delta-rule layers
+
+
+def _build_hybrid_prefill_fn(family: _Family, table_width: int):
+    """Prefill over a padded bucket for a family with ``kda`` layers.
+
+    ``table`` is ``[table_width + 1]``: the pages, then the request's
+    state slot.  The chunked recurrence runs over the whole bucket with
+    the padded positions made inert (``beta`` = 0, ``g`` = 0); the state
+    entering is zero whatever the slot held, the state leaving and the
+    convolution's tail at ``length`` go to the slot."""
+    from tpu_hc_bench.models import solar_open2 as so
+    from tpu_hc_bench.parallel.sequence import dense_attention
+
+    m = family.model
+    group = family.heads // family.kv_heads
+    n = m.kda_heads * m.kda_head_dim
+    kv_index = {l: i for i, l in enumerate(family.kv_layers)}
+    st_index = {l: i for i, l in enumerate(family.state_layers)}
+
+    def prefill(params, kv, tokens, length, table):
+        k_pages, v_pages = kv["pages"]
+        S, conv = kv["state"]["S"], kv["state"]["conv"]
+        s = tokens.shape[1]
+        slot = table[table_width]
+        valid = jnp.arange(s) < length
+        x = family.embed_prefill(params, tokens)
+        new_k, new_v = {}, {}
+        for l in range(family.num_layers):
+            p_l = family.layer_params(params, l)
+            u = family.attn_norm(p_l, x)
+            if l in kv_index:
+                with jax.named_scope("gqa"):
+                    q, k, v = so.gqa_inputs(p_l["mixer"], u, family.heads,
+                                            family.kv_heads)
+                    new_k[l], new_v[l] = k[0], v[0]
+                    # causal masking alone suffices under right-padding
+                    ctx = dense_attention(
+                        q, jnp.repeat(k, group, axis=2),
+                        jnp.repeat(v, group, axis=2), causal=True)
+                    x = x + so.gqa_output(p_l["mixer"], ctx, u)
+            else:
+                with jax.named_scope("kda"):
+                    li = st_index[l]
+                    tail0 = jnp.zeros((1, m.conv_kernel - 1, 3 * n),
+                                      u.dtype)
+                    q, k, v, g, beta, padded = so.kda_inputs(
+                        p_l["mixer"], u, tail0, m.kda_heads, m.neg_eigval)
+                    g = jnp.where(valid[None, :, None, None], g, 0.0)
+                    beta = jnp.where(valid[None, :, None], beta, 0.0)
+                    o, s_end = so.kda_sequence(
+                        q[0], k[0], v[0], g[0], beta[0],
+                        jnp.zeros(S.shape[2:], jnp.float32))
+                    x = x + so.kda_output(p_l["mixer"], o[None], u, m.eps)
+                    S = jax.lax.dynamic_update_slice(
+                        S, s_end[None, None], (li, slot, 0, 0, 0))
+                    tail = jax.lax.dynamic_slice_in_dim(
+                        padded[0], length, m.conv_kernel - 1, axis=0)
+                    conv = jax.lax.dynamic_update_slice(
+                        conv, tail[None, :, None].astype(conv.dtype),
+                        (li, 0, slot, 0))
+            with jax.named_scope("moe"):
+                y, _ = family.ffn(p_l, family.ffn_norm(p_l, x))
+                x = x + y
+        with jax.named_scope("head"):
+            x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+            logits = family.head(params, x_last)[:, 0]
+            next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("gqa"):
+            kn = jnp.stack([new_k[l] for l in family.kv_layers])
+            vn = jnp.stack([new_v[l] for l in family.kv_layers])
+            pages = (
+                _write_prompt_pages(k_pages, kn, table[:table_width],
+                                    length),
+                _write_prompt_pages(v_pages, vn, table[:table_width],
+                                    length))
+        return next_token, logits, {
+            "pages": pages, "state": {"S": S, "conv": conv}}
+
+    return prefill
+
+
+def _build_hybrid_decode_fn(family: _Family, table_width: int,
+                            scatter_new):
+    """One token a row for a family with ``kda`` layers, gather arm.
+
+    ``tables`` is ``[b, table_width + 1]`` (pages, then the slot).  A
+    ``kda`` layer runs ONE recurrence step over every slot of the state
+    at once, in place: the rows' q, k, v, g, beta are scattered to slot
+    order first (a few KB a row), and a slot that no active row names
+    gets decay 1 and beta 0, which leave it as it was (inactive rows
+    name the trash slot 0).  No row's state is gathered out of the pool
+    or scattered back.  ``scatter_new`` is ``build_decode_fn``'s page
+    write.  Returns ``(next_tokens [b + 1], logits, kv)``:
+    the last entry counts the active rows' picks that landed on an
+    expert held here (``family.counters``)."""
+    from tpu_hc_bench.models import solar_open2 as so
+
+    m = family.model
+    kv_index = {l: i for i, l in enumerate(family.kv_layers)}
+    st_index = {l: i for i, l in enumerate(family.state_layers)}
+
+    def decode(params, kv, tokens, tables, lengths, active):
+        k_pages, v_pages = kv["pages"]
+        S, conv = kv["state"]["S"], kv["state"]["conv"]
+        n_slots = S.shape[1]
+        tabs = tables[:, :table_width]
+        slots = jnp.where(active, tables[:, table_width], 0)
+        x = family.embed_decode(params, tokens, lengths)
+        new_k, new_v = {}, {}
+        held = jnp.zeros((), jnp.int32)
+
+        def to_slots(rows):
+            return jnp.zeros((n_slots,) + rows.shape[1:],
+                             rows.dtype).at[slots].set(rows)
+
+        for l in range(family.num_layers):
+            p_l = family.layer_params(params, l)
+            u = family.attn_norm(p_l, x)
+            if l in kv_index:
+                with jax.named_scope("gqa"):
+                    q, k, v = so.gqa_inputs(p_l["mixer"], u, family.heads,
+                                            family.kv_heads)
+                    new_k[l], new_v[l] = k[:, 0], v[:, 0]
+                    q, tb = jax.lax.optimization_barrier((q, tabs))
+                    ctx = _attend_rows(
+                        q[:, 0], _gather_rows(k_pages, kv_index[l], tb),
+                        _gather_rows(v_pages, kv_index[l], tb),
+                        k[:, 0], v[:, 0], lengths)
+                    x = x + so.gqa_output(p_l["mixer"], ctx[:, None], u)
+            else:
+                with jax.named_scope("kda"):
+                    li = st_index[l]
+                    q, k, v, g, beta, padded = so.kda_inputs(
+                        p_l["mixer"], u,
+                        jnp.swapaxes(conv[li][:, slots], 0, 1),
+                        m.kda_heads, m.neg_eigval)
+                    g = jnp.where(active[:, None, None], g[:, 0], 0.0)
+                    beta = jnp.where(active[:, None], beta[:, 0], 0.0)
+                    s_l = jax.lax.dynamic_index_in_dim(S, li, 0, False)
+                    s_l, o = so.kda_step(
+                        s_l, to_slots(q[:, 0]), to_slots(k[:, 0]),
+                        to_slots(v[:, 0]), to_slots(g), to_slots(beta))
+                    S = jax.lax.dynamic_update_index_in_dim(S, s_l, li, 0)
+                    # a tap at a time: every operand axis but the
+                    # channels is then an index of the scatter, and
+                    # the leaf is written in the layout it rests in
+                    for t in range(m.conv_kernel - 1):
+                        conv = conv.at[li, t, slots].set(
+                            padded[:, 1 + t].astype(conv.dtype))
+                    x = x + so.kda_output(p_l["mixer"], o[slots][:, None],
+                                          u, m.eps)
+            with jax.named_scope("moe"):
+                y, picks = family.ffn(p_l, family.ffn_norm(p_l, x))
+                x = x + y
+                held = held + jnp.sum(jnp.where(active, picks[:, 0], 0))
+        with jax.named_scope("head"):
+            logits = family.head(params, x)[:, 0]
+            next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("gqa"):
+            pages = scatter_new(
+                kv["pages"], tabs, lengths, active,
+                jnp.stack([new_k[l] for l in family.kv_layers]),
+                jnp.stack([new_v[l] for l in family.kv_layers]))
+        return (jnp.concatenate([next_tokens, held[None]]), logits,
+                {"pages": pages, "state": {"S": S, "conv": conv}})
+
+    return decode
+
+
+PARTS = ("kda", "gqa", "moe", "head")
+# the grouped-matmul kernel ``jax.lax.ragged_dot`` lowers to carries its
+# own name and no scope: in these programs only the experts issue it
+_KERNEL_PARTS = {"ragged-dot": "moe"}
+
+
+def part_of_ops(hlo_text: str, parts: tuple = PARTS) -> dict:
+    """``{"<instruction>:<result shape>": part}`` for a compiled
+    program's operations under one of the ``jax.named_scope`` parts
+    (``analysis.hlo.ops_by_scope``): the key is how a device trace names
+    the operation (its events carry the instruction, not the scope)."""
+    from tpu_hc_bench.analysis import hlo
+
+    return hlo.ops_by_scope(hlo_text, parts, _KERNEL_PARTS)

@@ -203,6 +203,63 @@ class PageAllocator:
         table[slot] = page
 
 
+class SlotAllocator:
+    """Free list over the recurrent-state slots of a family whose cache
+    tree has a ``state`` pool (``serve.decode``): a request owns one
+    slot from admit to finish; slot 0 is the reserved trash slot
+    (inactive rows name it) and is never handed out.  A slot is never
+    shared and never scrubbed: the prefill program starts every
+    residency from a zero state whatever the slot held."""
+
+    def __init__(self, num_slots: int):
+        if num_slots < 2:
+            raise ValueError(f"state pool needs >= 2 slots (one is the "
+                             f"trash slot): {num_slots}")
+        self.num_slots = num_slots
+        self._idle = list(range(num_slots - 1, 0, -1))
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._idle)
+
+    def alloc(self) -> int | None:
+        return self._idle.pop() if self._idle else None
+
+    def free(self, slot: int) -> None:
+        assert 0 < slot < self.num_slots and slot not in self._idle, (
+            f"free of unheld slot {slot}")
+        self._idle.append(slot)
+
+
+class CacheManager:
+    """What a resident request holds of the cache tree, behind one seam:
+    pages from the ``PageAllocator`` and, for a family with a state
+    pool, one slot from the ``SlotAllocator``.  Admission binds both,
+    and every exit of the ledger (finish, shed, quarantine, preempt,
+    drain) gives both back through ``release``."""
+
+    def __init__(self, allocator: PageAllocator,
+                 slots: SlotAllocator | None):
+        self.allocator, self.slots = allocator, slots
+
+    def slot_free(self) -> bool:
+        return self.slots is None or self.slots.free_slots > 0
+
+    def bind_slot(self) -> int:
+        """The request's slot (0 where the family keeps no state)."""
+        if self.slots is None:
+            return 0
+        slot = self.slots.alloc()
+        assert slot is not None, "admission checked slot_free"
+        return slot
+
+    def release(self, fl: "_InFlight") -> None:
+        self.allocator.free(fl.pages)
+        if self.slots is not None and fl.slot:
+            self.slots.free(fl.slot)
+            fl.slot = 0
+
+
 class KVLedger:
     """Round 22 (obs.kv): the KV-pool utilization ledger — pages
     reserved by admission vs pages actually written, integrated over
@@ -322,6 +379,8 @@ class _InFlight:
     # shared prefix-cache pages — the footprint record stamps both
     pages_grown: int = 0
     prefix_shared: int = 0
+    # the recurrent-state slot (0 = none: the family keeps no state)
+    slot: int = 0
 
 
 class ServeEngine:
@@ -403,6 +462,15 @@ class ServeEngine:
             example = jnp.zeros((1,) + tuple(self.spec.input_shape),
                                 jnp.float32)
         self.variables = self.model.init(rng, example, train=False)
+        if self.decode_mode and cfg.use_fp16 and not any(
+                x.dtype == dtype
+                for x in jax.tree_util.tree_leaves(self.variables)):
+            # --use_fp16 serves in bfloat16: the matrices are HELD so,
+            # vectors (norm scales, biases) stay float32.  A family that
+            # declares its own parameter types already holds them so
+            self.variables = jax.tree_util.tree_map(
+                lambda x: x.astype(dtype) if x.ndim >= 2 else x,
+                self.variables)
         self.params = self.variables.get("params", self.variables)
 
         # --- bucket ladders + KV pool geometry ---
@@ -434,6 +502,12 @@ class ServeEngine:
                 f"(need {1 + self.table_width}: a trash page + "
                 f"{self.table_width} pages of {self.page_size} tokens "
                 f"for prompt+output {self.max_ctx})")
+
+        # a family with a recurrent-state pool: cap + 1 slots (slot 0
+        # the trash slot), the slot index in one more table column
+        self.state_slots = 0
+        self.state_pool_bytes = 0
+        self.op_parts: dict = {}
 
         # --- warmup: AOT-compile every bucket ---
         self.compiled: dict[tuple[str, int], Any] = {}
@@ -524,7 +598,8 @@ class ServeEngine:
 
     def kv_pool_temp_ratio(self) -> float | None:
         """The largest AOT ``temp`` bytes over the decode and prefill
-        programs, in pool leaves (one of K / V).  A program that holds
+        programs, in pool leaves (the cache tree's largest: one of K /
+        V, or the recurrent state).  A program that holds
         a second copy of a leaf — a re-layout of the pool around its
         gather or its write — reads >= 1; one that reads and writes the
         pool where it rests holds a layer's gathered rows at most.
@@ -539,7 +614,10 @@ class ServeEngine:
                     temps.append(ma["temp_bytes"])
         if not temps:
             return None
-        return round(max(temps) / self._kv[0].nbytes, 4)
+        import jax
+
+        return round(max(temps) / max(
+            x.nbytes for x in jax.tree_util.tree_leaves(self._kv)), 4)
 
     def _check_hbm_budget(self, print_fn) -> None:
         """``--hbm_budget`` in the serving lane: the warmed ladder's
@@ -586,6 +664,22 @@ class ServeEngine:
         jnp = self._jnp
         self.family = decode_mod.build_family(self.model,
                                               quant=self.quant)
+        stateful = bool(self.family.state_layers)
+        if stateful:
+            # the file's own rule: an unsupported combination fails at
+            # construction, never mid-traffic
+            if self.decode_attention != "gather":
+                raise ValueError(
+                    f"--model {self.cfg.model} keeps a recurrent state "
+                    "beside its KV pages; --decode_attention=paged has "
+                    "no kernel that knows that cache tree")
+            if self.cfg.prefix_cache != "off":
+                raise ValueError(
+                    f"--model {self.cfg.model}: --prefix_cache=on would "
+                    "share K/V pages without the recurrent state that "
+                    "belongs to the same prefix (no state snapshots "
+                    "yet); sharing is refused for this family")
+            self.state_slots = self.cap + 1
         # int8_w: the decode programs read the quantized tree; the
         # original f32 params stay on self.params (parity tests read
         # them for the full-forward reference)
@@ -594,30 +688,39 @@ class ServeEngine:
             if self.quant == "int8_w" else self.params)
         self._kv = decode_mod.init_kv_state(
             self.family, self.num_pages, self.page_size,
-            jnp.dtype(self.cfg.compute_dtype), quant=self.quant)
+            jnp.dtype(self.cfg.compute_dtype), quant=self.quant,
+            slots=self.state_slots)
         import jax
 
         leaves = jax.tree_util.tree_leaves(self._kv)
         self.kv_pool_bytes = int(sum(x.nbytes for x in leaves))
+        if stateful:
+            self.state_pool_bytes = int(sum(
+                x.nbytes
+                for x in jax.tree_util.tree_leaves(self._kv["state"])))
+            self.kv_pool_bytes -= self.state_pool_bytes
         if self.quant == "int8_kv":
             # the per-(layer, page) f32 scale planes ride the pool
             # bytes — int8 pages without their scales would undercount
             self.kv_scale_bytes = int(sum(
                 x.nbytes for x in leaves if x.dtype == jnp.float32))
         w = self.table_width
+        # the table handed to the programs: the pages, then the slot
+        self.table_cols = cols = w + (1 if stateful else 0)
         for s in self.prefill_buckets:
             fn = decode_mod.build_prefill_fn(
                 self.family, self.page_size, w, quant=self.quant)
             self._aot(("prefill", s), fn, self.exec_params, self._kv,
                       np.zeros((1, s), np.int32), np.int32(1),
-                      np.zeros((w,), np.int32), donate=(1,))
+                      np.zeros((cols,), np.int32), donate=(1,))
         for b in self.batch_buckets:
             fn = decode_mod.build_decode_fn(
                 self.family, self.page_size, w,
                 attention=self.decode_attention, quant=self.quant,
                 block_pages=self.block_pages)
             self._aot(("decode", b), fn, self.exec_params, self._kv,
-                      np.zeros((b,), np.int32), np.zeros((b, w), np.int32),
+                      np.zeros((b,), np.int32),
+                      np.zeros((b, cols), np.int32),
                       np.zeros((b,), np.int32), np.zeros((b,), bool),
                       donate=(1,))
         # round 25: the one COW program — page-count-shaped, not
@@ -625,6 +728,14 @@ class ServeEngine:
         # prefix cache can ever trigger (zero lowering after warmup)
         self._aot(("page_copy", 0), decode_mod.build_page_copy_fn(),
                   self._kv, np.int32(0), np.int32(0), donate=(0,))
+        if stateful:
+            # which named part (kda / gqa / moe / head) each operation
+            # of each program belongs to, keyed as a device trace names
+            # it: the trace's events carry the instruction, not the scope
+            self.op_parts = {
+                f"{kind}@{n}": decode_mod.part_of_ops(c.as_text())
+                for (kind, n), c in self.compiled.items()
+                if kind in ("prefill", "decode")}
 
     def _warm_classify(self) -> None:
         model = self.model
@@ -719,6 +830,11 @@ class ServeEngine:
                 "prefix_cache=on requires kv_reserve=lazy (sharing "
                 "only saves pages when admission stops reserving the "
                 "worst case)")
+        if prefix_cache == "on" and self.state_slots:
+            raise ValueError(
+                f"--model {self.cfg.model}: prefix_cache=on would share "
+                "K/V pages without the recurrent state of the same "
+                "prefix; refused for this family")
         headroom = self.cfg.kv_growth_headroom
         deadline_ms = (deadline_ms if deadline_ms is not None
                        else (self.cfg.deadline_ms or self.cfg.slo_e2e_ms))
@@ -755,6 +871,16 @@ class ServeEngine:
         allocator = PageAllocator(self.num_pages) if self.decode_mode \
             else None
         ledger = KVLedger(self.page_size) if self.decode_mode else None
+        cache_mgr = (CacheManager(
+            allocator, SlotAllocator(self.state_slots)
+            if self.state_slots else None) if self.decode_mode else None)
+        # program counters of a family with a state pool / routed
+        # experts held as a share (summed from what each decode step
+        # returns with its tokens: no extra transfer)
+        counters = dict.fromkeys(
+            self.family.counters + (("moe_picks",) * bool(
+                self.family.picks_per_token)) if self.decode_mode else (), 0)
+        state_slot_steps = [0, 0]       # in use, slots x steps
         # round 25: the shared-prefix cache lives per run (it holds
         # references into THIS run's allocator) and its counters feed
         # prefix_hit_frac on the kv_pool record cadence
@@ -1027,8 +1153,8 @@ class ServeEngine:
                 writer.event("quarantine", **rec)
                 timeline_mod.instant("quarantine", rid=fl.req.rid,
                                      cause=cause)
-            if allocator is not None:
-                allocator.free(fl.pages)
+            if cache_mgr is not None:
+                cache_mgr.release(fl)
             phases.enter(back)
 
         def shed_queued(req: Request, cause: str, t: float) -> None:
@@ -1090,7 +1216,9 @@ class ServeEngine:
                          / max(1, fl.produced))
             active.remove(victim)
             ledger.retire(len(victim.pages), victim.length)
-            allocator.free(victim.pages)
+            # pages AND slot: the re-prefill starts from a zero state in
+            # whatever slot it is given then
+            cache_mgr.release(victim)
             carry[victim.req.rid] = {
                 "prefix": list(victim.out_tokens),
                 "t_admit": victim.t_admit, "t_first": victim.t_first,
@@ -1122,8 +1250,8 @@ class ServeEngine:
                     preempts=fl.preempts + 1))
                 if ledger is not None:
                     ledger.retire(len(fl.pages), fl.length)
-                if allocator is not None:
-                    allocator.free(fl.pages)
+                if cache_mgr is not None:
+                    cache_mgr.release(fl)
             active.clear()
             for req in queue:
                 c = carry.pop(req.rid, None)
@@ -1214,8 +1342,12 @@ class ServeEngine:
             fresh = allocator.alloc(max(0, slots - len(shared)))
             assert fresh is not None, "admission checked free_pages"
             pages = shared + fresh
+            slot = cache_mgr.bind_slot()
             table = np.pad(np.asarray(pages, np.int32),
                            (0, self.table_width - len(pages)))
+            if cache_mgr.slots is not None:
+                # the slot rides in one more column, after the pages
+                table = np.append(table, np.int32(slot))
             ledger.admit(len(pages), plen)
             s = pick_bucket(self.prefill_buckets, plen)
             toks = np.zeros((1, s), np.int32)
@@ -1230,7 +1362,7 @@ class ServeEngine:
                 # dense pass itself still runs: next_token attends
                 # over every prompt position either way.
                 wtable = np.where(
-                    np.arange(self.table_width) < len(shared),
+                    np.arange(self.table_cols) < len(shared),
                     0, table).astype(np.int32)
             (next_tok, logits, kv), dt = self._timed(
                 clock, "prefill",
@@ -1261,7 +1393,7 @@ class ServeEngine:
                 t_last=(c["t_last"] if c else None),
                 preempts=(c["preempts"] if c else 0),
                 produced_res=(0 if c else 1),
-                prefix_shared=len(shared))
+                prefix_shared=len(shared), slot=slot)
             if guard:
                 row = np.asarray(logits)
                 if faults is not None and faults.poison_rids([req.rid]):
@@ -1361,7 +1493,7 @@ class ServeEngine:
                     return False
             b = pick_bucket(self.batch_buckets, len(sched))
             toks = np.zeros((b,), np.int32)
-            tables = np.zeros((b, self.table_width), np.int32)
+            tables = np.zeros((b, self.table_cols), np.int32)
             lengths = np.zeros((b,), np.int32)
             mask = np.zeros((b,), bool)
             for i, fl in enumerate(sched):
@@ -1380,6 +1512,14 @@ class ServeEngine:
             bucket_acct("decode", b, len(sched), dt)
             ledger.charge(dt)
             next_toks = np.asarray(next_toks)
+            for j, name in enumerate(self.family.counters):
+                counters[name] += int(next_toks[b + j])
+            if self.family.picks_per_token:
+                counters["moe_picks"] += (
+                    len(sched) * self.family.picks_per_token)
+            if cache_mgr.slots is not None:
+                state_slot_steps[0] += len(sched)
+                state_slot_steps[1] += self.cap
             bad: set[int] = set()
             if guard:
                 # per-request quarantine: ONE host read of the step's
@@ -1551,11 +1691,16 @@ class ServeEngine:
                                         "deadline_predicted", now())
                             progressed = True
                             continue
-                        if allocator is None \
-                                or free_now() >= need_pages(head):
+                        if allocator is None or (
+                                free_now() >= need_pages(head)
+                                and cache_mgr.slot_free()):
                             admit(queue.popleft())
                             progressed = True
                             continue
+                        if not cache_mgr.slot_free():
+                            # every slot is held (paused rows keep
+                            # theirs): only a retirement frees one
+                            break
                         # starved: reclaim cold cache pages first (they
                         # are free capacity the trie is merely keeping
                         # warm), then the r23 preemption machinery
@@ -1597,6 +1742,9 @@ class ServeEngine:
                         blocked_cause = "batch_full"
                     elif len(active) >= self.cap:
                         blocked_cause = "batch_full"
+                    elif cache_mgr is not None and \
+                            not cache_mgr.slot_free():
+                        blocked_cause = "slot_starved"
                     elif allocator is not None and \
                             free_now() < need_pages(queue[0]):
                         blocked_cause = "pool_starved"
@@ -1606,6 +1754,9 @@ class ServeEngine:
                     # resource — bounded by transitions, not steps
                     if blocked_cause == "pool_starved":
                         timeline_mod.instant("pool_starved",
+                                             queued=len(queue))
+                    elif blocked_cause == "slot_starved":
+                        timeline_mod.instant("slot_starved",
                                              queued=len(queue))
                     elif blocked_cause == "batch_full":
                         timeline_mod.instant("batch_full",
@@ -1651,7 +1802,10 @@ class ServeEngine:
                     # it (they rejoin admission at the next loop top)
                     dt_blk = now() - t_blocked
                     if dt_blk > 0:
-                        ci = 0 if blocked_cause == "pool_starved" else 1
+                        # a starved slot is cache capacity, as a
+                        # starved page is
+                        ci = 0 if blocked_cause in (
+                            "pool_starved", "slot_starved") else 1
                         # the KV_PRESSURE measure: wall seconds this
                         # window spent blocked, split by binding cause
                         win_stats["blocked"][ci] += dt_blk
@@ -1758,7 +1912,7 @@ class ServeEngine:
             "kv_page_size": self.page_size,
             "kv_pages": self.num_pages,
             # round 22 (obs.kv): pool geometry + the utilization ledger
-            "kv_layers": (self.family.num_layers
+            "kv_layers": (len(self.family.kv_layers)
                           if self.decode_mode else None),
             "kv_pool_bytes": self.kv_pool_bytes,
             "kv_scale_bytes": self.kv_scale_bytes,
@@ -1778,6 +1932,14 @@ class ServeEngine:
                 "aot_decode_temp_bytes"),
             "kv_pool_temp_ratio": self.compile_record.get(
                 "kv_pool_temp_ratio"),
+            # a family with a recurrent-state pool: its bytes, the slots
+            # in use at each decode step summed beside slots x steps,
+            # the decode steps' expert picks and those that landed on an
+            # expert held here
+            "state_pool_bytes": self.state_pool_bytes,
+            "state_slots": state_slot_steps[0],
+            "state_slot_steps": state_slot_steps[1],
+            **counters,
             "post_warmup_compiles": entries_final
                                     - self.entries_after_warmup,
             # round 20 (obs.requests): the tail-attribution fold, its
@@ -1831,4 +1993,8 @@ class ServeEngine:
                      entries_final=entries_final,
                      post_warmup_compiles=summary["post_warmup_compiles"])
         timeline_mod.detach()   # flush the serve spans, close the file
+        # each program's operations by named part (what a device trace
+        # calls them -> kda / gqa / moe / head): for the caller that
+        # reduces a trace, too large for the stream's summary record
+        summary["op_parts"] = self.op_parts
         return summary
