@@ -39,3 +39,18 @@ def iqr_share(values) -> float:
     contract sets bounds from."""
     q1, _, q3 = statistics.quantiles(values, n=4)
     return (q3 - q1) / statistics.median(values)
+
+
+def without_farthest(values) -> list[float]:
+    """The set less the run farthest from its median: what the check
+    reads a set's spread from when it asks whether a bound is too tight
+    ("a spread leaves out the run farthest from its median")."""
+    mid = statistics.median(values)
+    return sorted(sorted(values, key=lambda v: abs(v - mid))[:-1])
+
+
+def range_share(values) -> float:
+    """Largest less smallest, as a share of the median: of a set without
+    its farthest run, never under the quartiles' distance, so the
+    stricter reading of the check's rule (ISSUE 32 sizes cells by it)."""
+    return (max(values) - min(values)) / statistics.median(values)

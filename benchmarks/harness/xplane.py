@@ -11,14 +11,24 @@ Host planes hold the PROGRAM's own spans on the same clock (``hc:<name>``:
 serve loop into an open trace), which is how a gap gets its name.  The
 spans nest (``decode`` holds ``decode_dispatch`` and ``decode_wait``), so a
 moment belongs to the INNERMOST span open then.
+
+A 6 s trace of a fast serve lane holds millions of events, so nothing here
+walks them in Python but the one pass that reads them: the events of a
+chip become arrays (start, end, an integer id a distinct raw name), names
+are cleaned and classified once a DISTINCT name, intervals are merged by
+a running maximum over sorted arrays, per-name seconds are a ``bincount``
+and the idle gaps are charged by ``searchsorted``.
 """
 
 from __future__ import annotations
 
-import bisect
+import array
 import glob
 import os
 import re
+from typing import NamedTuple
+
+import numpy as np
 
 OPS_LINE = "XLA Ops"
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -40,30 +50,56 @@ def find_xplane(trace_dir: str) -> str:
     return paths[-1]
 
 
+def parse(serialized: bytes):
+    """The profile of a serialized trace (what a profiler session's
+    ``stop()`` returns, and what an ``.xplane.pb`` file holds)."""
+    from jax.profiler import ProfileData
+
+    return ProfileData.from_serialized_xspace(serialized)
+
+
 def load(path: str):
     from jax.profiler import ProfileData
 
     if path.endswith(".txt"):
         with open(path) as f:
-            return ProfileData.from_serialized_xspace(
-                ProfileData.text_proto_to_serialized_xspace(f.read()))
+            return parse(ProfileData.text_proto_to_serialized_xspace(f.read()))
     return ProfileData.from_file(path)
 
 
-def device_ops(profile) -> dict[int, list[tuple[str, float, float]]]:
-    """chip id -> ``[(name, start_s, end_s)]`` of its ``XLA Ops`` line."""
-    out: dict[int, list] = {}
+class Ops(NamedTuple):
+    """One chip's ``XLA Ops`` events: the distinct raw names in order of
+    first appearance, and per event the index of its name and its start
+    and end in seconds."""
+    names: list
+    ids: np.ndarray
+    start: np.ndarray
+    end: np.ndarray
+
+
+def device_ops(profile) -> dict[int, Ops]:
+    """chip id -> the events of its ``XLA Ops`` line, as arrays."""
+    raw: dict[int, tuple] = {}
     for plane in profile.planes:
         m = DEVICE_PLANE.match(plane.name)
         if not m:
             continue
+        index, ids, start, dur = raw.setdefault(
+            int(m.group(1)),
+            ({}, array.array("q"), array.array("d"), array.array("d")))
         for line in plane.lines:
             if line.name != OPS_LINE:
                 continue
-            evs = [(e.name, e.start_ns * 1e-9,
-                    (e.start_ns + e.duration_ns) * 1e-9)
-                   for e in line.events]
-            out.setdefault(int(m.group(1)), []).extend(evs)
+            for e in line.events:
+                ids.append(index.setdefault(e.name, len(index)))
+                start.append(e.start_ns)
+                dur.append(e.duration_ns)
+    out = {}
+    for chip, (index, ids, start, dur) in raw.items():
+        start_ns = np.frombuffer(start, dtype=np.float64)
+        out[chip] = Ops(list(index), np.frombuffer(ids, dtype=np.int64),
+                        start_ns * 1e-9,
+                        (start_ns + np.frombuffer(dur, np.float64)) * 1e-9)
     return out
 
 
@@ -84,36 +120,48 @@ def host_spans(profile, prefix: str = SPAN_PREFIX
     return sorted(out, key=lambda s: s[1])
 
 
-def union(intervals) -> list[tuple[float, float]]:
-    merged: list[list[float]] = []
-    for s, e in sorted(intervals):
-        if merged and s <= merged[-1][1]:
-            merged[-1][1] = max(merged[-1][1], e)
-        else:
-            merged.append([s, e])
-    return [(s, e) for s, e in merged]
+def _pairs(intervals) -> np.ndarray:
+    if not isinstance(intervals, np.ndarray):
+        intervals = list(intervals)
+    return np.asarray(intervals, dtype=np.float64).reshape(-1, 2)
+
+
+def union(intervals) -> np.ndarray:
+    """The ``(start, end)`` pairs merged: an ``[n, 2]`` array, by start,
+    of intervals that neither overlap nor touch."""
+    iv = _pairs(intervals)
+    if not len(iv):
+        return iv
+    iv = iv[np.argsort(iv[:, 0], kind="stable")]
+    # sorted by start, the running maximum of the ends is the end of the
+    # merged interval so far: a new one begins where a start lies past it
+    reach = np.maximum.accumulate(iv[:, 1])
+    first = np.concatenate([[True], iv[1:, 0] > reach[:-1]])
+    last = np.concatenate([first[1:], [True]])
+    return np.column_stack([iv[first, 0], reach[last]])
 
 
 def total(intervals) -> float:
-    return sum(e - s for s, e in intervals)
+    iv = _pairs(intervals)
+    return float((iv[:, 1] - iv[:, 0]).sum())
 
 
-def subtract(a, b) -> list[tuple[float, float]]:
+def subtract(a, b) -> np.ndarray:
     """Parts of the merged intervals ``a`` not covered by merged ``b``."""
-    out, j = [], 0
-    for s, e in a:
-        cur = s
-        while j < len(b) and b[j][1] <= cur:
-            j += 1
-        k = j
-        while k < len(b) and b[k][0] < e:
-            if b[k][0] > cur:
-                out.append((cur, b[k][0]))
-            cur = max(cur, b[k][1])
-            k += 1
-        if cur < e:
-            out.append((cur, e))
-    return out
+    a, b = _pairs(a), _pairs(b)
+    if not len(a) or not len(b):
+        return a
+    # between two neighbouring boundaries of either set nothing changes:
+    # a stretch is kept where its start lies inside ``a`` and outside ``b``
+    cuts = np.unique(np.concatenate([a.ravel(), b.ravel()]))
+    lo, hi = cuts[:-1], cuts[1:]
+
+    def inside(iv):
+        k = np.searchsorted(iv[:, 0], lo, side="right") - 1
+        return (k >= 0) & (lo < iv[np.maximum(k, 0), 1])
+
+    keep = inside(a) & ~inside(b)
+    return np.column_stack([lo[keep], hi[keep]])
 
 
 SHAPE = re.compile(r"= \(?([a-z0-9]+\[[0-9,]*\])")
@@ -141,52 +189,67 @@ def _kind(name: str) -> str:
     return "wrapper" if WRAPPERS.match(head) else "compute"
 
 
+def _spans_of(ops: Ops, mask) -> np.ndarray:
+    return np.column_stack([ops.start[mask], ops.end[mask]])
+
+
+def _add_by_key(acc: np.ndarray, key, seconds, n: int) -> np.ndarray:
+    """``acc`` grown to ``n`` keys, plus the seconds summed a key."""
+    return (np.pad(acc, (0, n - len(acc)))
+            + np.bincount(key, seconds, minlength=n))
+
+
 def reduce_trace(profile, chips: int | None = None) -> dict:
     """Everything the per-layer readers and the result line take from a
     trace.  Times are seconds; per-chip figures are averaged over the
-    chips that ran anything."""
+    chips that ran anything.  ``gaps`` is an ``[n, 2]`` array: the idle
+    intervals of the first chip inside the traced window, by start."""
     ops = device_ops(profile)
     if chips:
-        ops = {k: v for k, v in sorted(ops.items())[:chips]}
-    ops = {k: v for k, v in ops.items() if v}
+        ops = dict(sorted(ops.items())[:chips])
+    ops = {k: v for k, v in sorted(ops.items()) if len(v.ids)}
     if not ops:
         raise ValueError("the trace holds no device operation")
-    t0 = min(s for evs in ops.values() for _, s, _ in evs)
-    t1 = max(e for evs in ops.values() for _, _, e in evs)
-    window = t1 - t0
+    t0 = min(float(o.start.min()) for o in ops.values())
+    t1 = max(float(o.end.max()) for o in ops.values())
     busy_s, exposed_s, coll_s = [], [], []
-    by_name: dict[str, float] = {}
-    kernels: dict[str, float] = {}
-    gaps: list[tuple[float, float]] = []
-    for chip, evs in sorted(ops.items()):
-        busy = union((s, e) for _, s, e in evs)
+    keys: dict[str, int] = {}       # cleaned name -> place, by appearance
+    by_key, kernel_by_key = np.zeros(0), np.zeros(0)
+    gaps = np.zeros((0, 2))
+    for chip, o in ops.items():
+        busy = union(_spans_of(o, slice(None)))
         busy_s.append(total(busy))
-        kinds = [_kind(n) for n, _, _ in evs]
-        coll = union((s, e) for (_, s, e), k in zip(evs, kinds)
-                     if k == "collective")
-        comp = union((s, e) for (_, s, e), k in zip(evs, kinds)
-                     if k == "compute")
+        # a name is classified and cleaned once, not once an event;
+        # wrappers get no key (their bodies are listed)
+        kinds = [_kind(n) for n in o.names]
+        kind = np.array(kinds)[o.ids]
+        coll = union(_spans_of(o, kind == "collective"))
+        comp = union(_spans_of(o, kind == "compute"))
         coll_s.append(total(coll))
         exposed_s.append(total(subtract(coll, comp)))
-        for (n, s, e), k in zip(evs, kinds):
-            if k == "wrapper":
-                continue
-            key = clean_name(n)
-            by_name[key] = by_name.get(key, 0.0) + (e - s) / len(ops)
-            if is_custom_call(n):
-                kernels[key] = kernels.get(key, 0.0) + (e - s) / len(ops)
+        key = np.array([-1 if k == "wrapper"
+                        else keys.setdefault(clean_name(n), len(keys))
+                        for n, k in zip(o.names, kinds)])[o.ids]
+        kernel = np.array([is_custom_call(n) for n in o.names])[o.ids]
+        took = o.end - o.start
+        listed = key >= 0
+        by_key = _add_by_key(by_key, key[listed], took[listed], len(keys))
+        kernel &= listed
+        kernel_by_key = _add_by_key(kernel_by_key, key[kernel],
+                                    took[kernel], len(keys))
         if chip == min(ops):
-            edges = [(t0, t0)] + busy + [(t1, t1)]
-            gaps = [(a[1], b[0]) for a, b in zip(edges, edges[1:])
-                    if b[0] > a[1]]
+            a = np.concatenate([[t0], busy[:, 1]])
+            b = np.concatenate([busy[:, 0], [t1]])
+            gaps = np.column_stack([a, b])[b > a]
     n = len(ops)
     return {
-        "window_s": window, "t0": t0, "chips": n,
+        "window_s": t1 - t0, "t0": t0, "chips": n,
         "busy_s": sum(busy_s) / n,
         "collective_s": sum(coll_s) / n,
         "collective_exposed_s": sum(exposed_s) / n,
-        "ops": by_name,
-        "custom_calls": kernels,
+        "ops": {k: float(by_key[i]) / n for k, i in keys.items()},
+        "custom_calls": {k: float(kernel_by_key[i]) / n
+                         for k, i in keys.items() if kernel_by_key[i] > 0},
         "gaps": gaps,
     }
 
@@ -217,26 +280,43 @@ def charge_gaps(gaps, spans) -> dict[str, dict]:
     among the innermost spans open while it lasted.  A gap begins while
     the host still waits for the program that just ended, so ``began in``
     reads ``decode_wait`` where ``split`` shows who held the device up
-    afterwards."""
+    afterwards.  ``gaps`` do not overlap and come by start."""
     points = innermost_points(spans)
-    times = [t for t, _ in points]
+    times = np.array([t for t, _ in points], dtype=np.float64)
+    # stretch j of the clock: before the first change point (0, under no
+    # span), then from point j - 1 to point j, the last one without end
+    names = [NO_SPAN] + [label for _, label in points]
+    g = _pairs(gaps)
+    g0, g1 = g[:, 0], g[:, 1]
+    first = np.searchsorted(times, g0, side="right")
+    last = np.maximum(np.searchsorted(times, g1, side="left"), first)
+    n = len(names)
+
+    def sum_at(where, seconds):
+        return np.bincount(where, seconds, minlength=n).astype(np.float64)
+
+    began = sum_at(first, g1 - g0)
+    count = np.bincount(first, minlength=n)
+    # a gap inside one stretch is that stretch's; another leaves its head
+    # to the first, its tail to the last, and holds those between whole
+    one = first == last
+    split = sum_at(first[one], (g1 - g0)[one])
+    f, l = first[~one], last[~one]
+    split += sum_at(f, times[f] - g0[~one]) if len(f) else 0.0
+    split += sum_at(l, g1[~one] - times[l - 1]) if len(l) else 0.0
+    whole = np.cumsum(np.bincount(f + 1, minlength=n + 1)
+                      - np.bincount(l, minlength=n + 1))[:n] > 0
+    inner = slice(1, max(1, len(times)))
+    split[inner] += np.where(whole[inner], np.diff(times), 0.0)
+    touched = whole | (count > 0)
+    touched[last] = True
     out: dict[str, dict] = {}
-
-    def row(k):
-        return out.setdefault(
-            points[k][1] if k >= 0 else NO_SPAN,
-            {"began_in_s": 0.0, "gaps": 0, "split_s": 0.0})
-
-    for g0, g1 in gaps:
-        k = bisect.bisect_right(times, g0) - 1
-        first = row(k)
-        first["began_in_s"] += g1 - g0
-        first["gaps"] += 1
-        t = g0
-        while k + 1 < len(times) and times[k + 1] < g1:
-            row(k)["split_s"] += times[k + 1] - t
-            k, t = k + 1, times[k + 1]
-        row(k)["split_s"] += g1 - t
+    for j in np.flatnonzero(touched):
+        row = out.setdefault(
+            names[j], {"began_in_s": 0.0, "gaps": 0, "split_s": 0.0})
+        row["began_in_s"] += float(began[j])
+        row["gaps"] += int(count[j])
+        row["split_s"] += float(split[j])
     return out
 
 

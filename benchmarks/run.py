@@ -92,8 +92,15 @@ def main() -> int:
         dev_rec["window_s"] = tr["window_s"]
         result["breakdown"] = {
             "device_ops": xplane.top_ops(tr["ops"]),
-            "idle_gaps": xplane.name_gaps(tr["gaps"], tr["spans"])}
+            "idle_gaps": tr["idle_gaps"]}
+        print(f"[bench] trace of {len(tr['gaps'])} idle gaps: closing the "
+              f"profiler took {tr['stop_s']:.1f} s, reading and reducing "
+              f"the trace {tr['reduce_s']:.1f} s", flush=True)
     result["also"] = out.get("also", {})
+    if args.trace:
+        # what the trace cost this run: both, since the stop is the larger
+        result["also"]["trace_stop_s"] = tr["stop_s"]
+        result["also"]["trace_reduce_s"] = tr["reduce_s"]
     result["wall_s"] = time.monotonic() - T_PROC
     result["compared"] = checks.as_json(out["numbers"])
     sys.stdout.flush()

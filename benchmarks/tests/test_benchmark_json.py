@@ -68,21 +68,46 @@ def test_the_check_sees_a_retired_cell_left_in_a_list():
     assert len(dangling(bench)) == 2
 
 
-def test_pr_27s_cells_are_there_and_the_retired_ones_gone():
-    """Later PRs add cells beside these three; none brings a retired
-    name back (the ledger's ``level`` of it is of other traffic)."""
-    mine = {"gpt2m-serve-backlog", "gpt2m-serve-chat-loaded",
-            "gpt2m-train-1k"}
-    assert mine <= set(CELLS)
-    assert not {"gpt2m-serve-chat", "gpt2m-serve-burst"} & set(CELLS)
-    assert all(w["chips"] == 1 and w["config"] == "gpt2_medium"
-               for w in BENCH["workloads"] if w["name"] in mine)
+# every cell a benchmark PR brought or retired, by name: later PRs add
+# cells beside those present; none brings a retired name back (the
+# ledger's ``level`` / ``best`` of it are of other traffic)
+PRESENT = ["gpt2m-serve-backlog-r2", "gpt2m-train-1k",
+           "solar-open2-ep8-serve-reason", "gpt2m-train-1k-dp4"]
+RETIRED = ["gpt2m-serve-chat", "gpt2m-serve-burst",             # PR 27
+           "gpt2m-serve-chat-loaded", "gpt2m-serve-backlog",    # PR 32
+           # read on the chip in PR 32 and left out for its noise (PERF.md
+           # section 7): the chat cell that comes back takes another name
+           "gpt2m-serve-chat-loaded-r2"]
+
+
+@pytest.mark.parametrize("name,present", [(n, True) for n in PRESENT]
+                         + [(n, False) for n in RETIRED])
+def test_the_cells_of_the_benchmark_prs_are_there_and_the_retired_gone(
+        name, present):
+    assert (name in CELLS) is present
+    named = [m["name"] for _, m in METRICS if name in m.get("workloads", ())]
+    if not present:
+        assert named == []
+        assert name not in {w["traffic"] for w in BENCH["workloads"]}
+        return
+    cell = spec.cell_of(BENCH, name)
+    assert cell["chips"] == (4 if name.endswith("-dp4") else 1)
+    if name.startswith("gpt2m-"):
+        assert cell["config"] == "gpt2_medium"
+    assert named, "no metric lists the cell"
+
+
+def test_the_benchmarks_frame_stands():
     pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
     assert len(set(pairs)) == len(pairs)
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
     assert BENCH["run_seconds"] == 51
     bounds = {m["name"]: m["bound"] for m in BENCH["end_to_end"]}
-    assert bounds["serve_tpot_p90_ms"] <= 0.06
-    assert bounds["serve_tokens_per_s"] <= 0.02
+    # ceilings: PR 27's, but for the backlog's rate, which PR 32 widened
+    # from 0.02 to what the check's own runs of the faster lane spread by
+    # (5.0% / 1.76% of their median: PERF.md section 2)
+    assert bounds["serve_tpot_p90_ms"] <= 0.05
+    assert bounds["serve_tokens_per_s"] <= 0.08
     assert bounds["train_examples_per_s"] <= 0.01
     assert bounds["setup_s"] <= 0.1
 

@@ -225,10 +225,8 @@ def rehearse_cell(bench, name, seed=31):
 
 
 @pytest.mark.parametrize("name,fault,correct", [
-    ("gpt2m-serve-chat-loaded", None, True),
-    ("gpt2m-serve-chat-loaded", "token_altered", False),
-    ("gpt2m-serve-backlog", None, True),
-    ("gpt2m-serve-backlog", "token_altered", False),
+    ("gpt2m-serve-backlog-r2", None, True),
+    ("gpt2m-serve-backlog-r2", "token_altered", False),
     ("gpt2m-train-1k", None, True),
     ("gpt2m-train-1k", "state_unchanged", False),
     ("gpt2m-train-1k", "half_batch", False),
@@ -244,8 +242,7 @@ def test_a_broken_timed_path_ends_with_correct_false(bench, name, fault,
     assert line["platform"] == "cpu" and "metrics" not in line
 
 
-@pytest.mark.parametrize("name", ["gpt2m-serve-chat-loaded",
-                                  "gpt2m-serve-backlog"])
+@pytest.mark.parametrize("name", ["gpt2m-serve-backlog-r2"])
 def test_run_py_rehearses_the_cell_from_the_command_line(name):
     """``run.py --rehearse`` as a process of its own: the cell, its mix
     and its configuration are found by name, every answer comes, and the

@@ -1,6 +1,6 @@
 # The count of a backlog mix, read on the chip: one untraced full run of
 # the cell from the unpacked archive (the mix's present count has only to
-# outlast the window), what the lane began in it, and the count = 2x that,
+# outlast the window), what the lane began in it, and the count = 3x that,
 # rounded up to a multiple of 4, with the `requests_per_s` that gives it.
 # That rate is written into the ARCHIVE's copy of the mix (the machine's
 # copy: write it into the repo's file by hand, with the date, from what
@@ -23,7 +23,7 @@ res = json.loads(open(sys.argv[1]).read().splitlines()[-1])
 if not res["correct"]:
     sys.exit(1)
 begun = res["also"]["window"]["begun"]
-count = -(-2 * begun // 4) * 4
+count = -(-3 * begun // 4) * 4
 rate = round(count / 51, 3)
 assert round(rate * 51) == count, (rate, count)
 bench = json.load(open("BENCHMARK.json"))

@@ -1,4 +1,4 @@
-# The chip calls of PR 24 (tracing; the serve cells carry PR 27's names): the
+# The chip calls of PR 24 (tracing; the cells are arguments since PR 32): the
 # serve loop's own account beside the
 # device trace, and what the instrumentation costs.  Before a call, the
 # parent commit with THIS benchmark laid over it (what the driver measures
@@ -7,7 +7,7 @@
 #     && git archive <parent> | tar -x -C .chip_archive/parent \
 #     && cp BENCHMARK.json .chip_archive/parent/ \
 #     && cp -r benchmarks/. .chip_archive/parent/benchmarks/
-#   chiprun --timeout 3000 -- bash benchmarks/tools/call_tracing.sh first
+#   chiprun --timeout 3000 -- bash benchmarks/tools/call_tracing.sh first [<chat cell> <backlog cell> <train cell>]
 #   chiprun --timeout 3500 -- bash benchmarks/tools/call_tracing.sh cost <cell> <seeds...>
 #   (PROBE_TREE=.chip_archive/final: the same from an unpacked archive of the final tree)
 # Every run is benchmarks/run.py itself through tools/probe_run.py, which
@@ -37,24 +37,28 @@ gaps() { # tag tree: the kept trace of the run just made, by phase
   head -n 16 $out/$1.gaps
 }
 
+# (no chat cell since PR 32 left gpt2m-serve-chat-loaded-r2 out: the tail
+# cell that is there stands in until one comes back)
+chat=${2:-solar-open2-ep8-serve-reason}; backlog=${3:-gpt2m-serve-backlog-r2}
+train=${4:-gpt2m-train-1k}
 case $1 in
 first)
   # both serve cells traced with the trace kept, the parent under this
   # benchmark (its line must lack the new metrics and nothing else), one
   # untraced pair a cell, and the train cell (its driver's spans and the
   # kernels' names changed): parent, change, change, parent
-  run chat_t1_parent .chip_archive/parent gpt2m-serve-chat-loaded 3000024001 1
-  run chat_t1 . gpt2m-serve-chat-loaded 3000024001 1 BENCH_KEEP_TRACE=1
+  run chat_t1_parent .chip_archive/parent $chat 3000024001 1
+  run chat_t1 . $chat 3000024001 1 BENCH_KEEP_TRACE=1
   gaps chat_t1 .
-  run backlog_t1 . gpt2m-serve-backlog 24002 1 BENCH_KEEP_TRACE=1
+  run backlog_t1 . $backlog 24002 1 BENCH_KEEP_TRACE=1
   gaps backlog_t1 .
-  run backlog_t1_parent .chip_archive/parent gpt2m-serve-backlog 24002 1
-  run chat_t0_parent .chip_archive/parent gpt2m-serve-chat-loaded 24003 0
-  run chat_t0 . gpt2m-serve-chat-loaded 24003 0
-  run backlog_t0 . gpt2m-serve-backlog 3000024004 0
-  run backlog_t0_parent .chip_archive/parent gpt2m-serve-backlog 3000024004 0
-  run train_t1_parent .chip_archive/parent gpt2m-train-1k 24005 1
-  run train_t1 . gpt2m-train-1k 24005 1
+  run backlog_t1_parent .chip_archive/parent $backlog 24002 1
+  run chat_t0_parent .chip_archive/parent $chat 24003 0
+  run chat_t0 . $chat 24003 0
+  run backlog_t0 . $backlog 3000024004 0
+  run backlog_t0_parent .chip_archive/parent $backlog 3000024004 0
+  run train_t1_parent .chip_archive/parent $train 24005 1
+  run train_t1 . $train 24005 1
   ;;
 cost)
   # per seed: untraced with the recorder on, off, and traced, in an order

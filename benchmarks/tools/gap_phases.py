@@ -43,7 +43,7 @@ def span_totals(spans, t0: float, t1: float) -> dict[str, list]:
 def report(profile, chips: int | None = None) -> dict:
     red = xplane.reduce_trace(profile, chips)
     spans = xplane.host_spans(profile)
-    idle = sum(g1 - g0 for g0, g1 in red["gaps"])
+    idle = xplane.total(red["gaps"])
     phases = xplane.charge_gaps(red["gaps"], spans)
     totals = span_totals(spans, red["t0"], red["t0"] + red["window_s"])
     for name, (n, secs) in totals.items():

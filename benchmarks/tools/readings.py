@@ -9,8 +9,8 @@ reads — the reference put in the program's place in the arm's
 arm's ``faults`` (the upper readings), each with its own ``correct``,
 which has to come out false.
 
-    python3 benchmarks/tools/readings.py --workload gpt2m-serve-chat-loaded \
-        --seeds 11,12,13 --seconds 15 [--also fp8]
+    python3 benchmarks/tools/readings.py --workload <cell> \
+        --seeds 11,12,13 --seconds 51 [--also fp8]
 """
 
 from __future__ import annotations

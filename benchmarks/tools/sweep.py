@@ -2,10 +2,14 @@
 """Finds the knee of a serve mix once, on the chip: one warmed engine,
 one window per rate, and for each rate whether a backlog grew through the
 window.  The rate chosen (0.8 of the knee) then becomes a number in the
-traffic file; the benchmark never searches.
+traffic file; the benchmark never searches.  A queue GROWS at a rate
+(``grows``) where the last third's median queue wait is over 1.5x the
+first third's and above one step of the lane's largest decode bucket, or
+where a request queued for over a second; the knee is the lowest rate at
+which any seed's does.
 
-    python3 benchmarks/tools/sweep.py --workload gpt2m-serve-chat-loaded \
-        --rates 4,6,8,10,12 --seconds 30 --seed 7
+    python3 benchmarks/tools/sweep.py --workload <serve cell> \
+        --rates 8,10,12,14,16,18,20,24 --seconds 30 --seed 7
 """
 
 from __future__ import annotations
@@ -50,12 +54,20 @@ def main() -> int:
             engine, reqs, args.seconds, None)
         recs = records
         first, last = readers.queue_p50_by_thirds(recs)
+        util = summary["bucket_util"]
+        top = util[max((k for k in util if k.startswith("decode@")),
+                       key=lambda k: int(k.split("@")[1]))]
+        top_step_ms = 1e3 * top["wall_s"] / top["steps"]
+        queue_max = max(r["queue_ms"] for r in recs)
         print(json.dumps({
             "rate": rate, "offered": len(reqs), "finished": len(records),
             "wall_s": wall, "drain_s": wall - reqs[-1]["arrival_s"],
             "queue_p50_ms_first_third": first,
             "queue_p50_ms_last_third": last,
-            "queue_max_ms": max(r["queue_ms"] for r in recs),
+            "queue_max_ms": queue_max,
+            "top_bucket_step_ms": top_step_ms,
+            "grows": bool((last > 1.5 * first and last > top_step_ms)
+                          or queue_max > 1e3),
             "ttft_p90_ms": stats.percentile([r["ttft_ms"] for r in recs], 90),
             "tpot_p90_ms": stats.percentile(readers.tpot_values(recs), 90),
             "tokens_per_s": sum(r["output_len"] for r in recs) / wall,
