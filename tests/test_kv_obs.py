@@ -36,6 +36,7 @@ from tpu_hc_bench.obs import kv
 from tpu_hc_bench.obs import metrics as obs_metrics
 from tpu_hc_bench.obs import regress
 from tpu_hc_bench.obs import timeline as timeline_mod
+from tpu_hc_bench.serve import cache as cache_mod
 from tpu_hc_bench.serve import engine as engine_mod
 from tpu_hc_bench.serve import slo
 
@@ -147,7 +148,7 @@ def test_offline_fold_matches_engine_summary(moe_ab):
 
 
 def test_allocator_counts_peak_and_recycling():
-    a = engine_mod.PageAllocator(7)
+    a = cache_mod.PageAllocator(7)
     p1 = a.alloc(3)
     assert a.pages_peak == 3 and a.recycled == 0
     a.free(p1)
@@ -402,7 +403,7 @@ def test_ledger_stamp_overhead_bounded():
     must cost well under the round-17 1%-of-step guard — it runs every
     decode step on the hot path."""
     step_s = SERVE_VCOSTS["decode"]
-    ledger = engine_mod.KVLedger(4)
+    ledger = cache_mod.KVLedger(4)
     ledger.admit(3, 5)
     n = 2000
     t0 = time.perf_counter()

@@ -27,6 +27,7 @@ from tpu_hc_bench import flags
 from tpu_hc_bench.analysis import lints
 from tpu_hc_bench.obs import metrics as obs_metrics
 from tpu_hc_bench.serve import arrivals
+from tpu_hc_bench.serve import cache as cache_mod
 from tpu_hc_bench.serve import engine as engine_mod
 from tpu_hc_bench.serve import prefix_cache as pc
 
@@ -39,7 +40,7 @@ VCOSTS = dict(SERVE_VCOSTS, page_copy=0.001)
 
 
 def test_allocator_share_free_refcount():
-    a = engine_mod.PageAllocator(6)
+    a = cache_mod.PageAllocator(6)
     pages = a.alloc(2)
     assert pages and all(p != 0 for p in pages)
     assert all(a.refcount(p) == 1 for p in pages)
@@ -55,7 +56,7 @@ def test_allocator_share_free_refcount():
 
 
 def test_allocator_cow_counted_apart_from_recycled():
-    a = engine_mod.PageAllocator(4)
+    a = cache_mod.PageAllocator(4)
     first = a.alloc(3)
     a.free(first)
     assert a.recycled == 0              # first hand-out is not a recycle
@@ -69,7 +70,7 @@ def test_allocator_cow_counted_apart_from_recycled():
 
 
 def test_allocator_bind_refuses_dead_page():
-    a = engine_mod.PageAllocator(4)
+    a = cache_mod.PageAllocator(4)
     table = np.zeros(3, np.int32)
     (p,) = a.alloc(1)
     a.bind(table, 1, p)
@@ -85,7 +86,7 @@ def test_allocator_bind_refuses_dead_page():
 
 
 def _cache(num_pages=16, ps=4):
-    a = engine_mod.PageAllocator(num_pages)
+    a = cache_mod.PageAllocator(num_pages)
     return a, pc.PrefixCache(a, page_size=ps)
 
 

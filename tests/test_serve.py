@@ -44,6 +44,7 @@ from tpu_hc_bench.analysis import lints
 from tpu_hc_bench.data.tokens import PromptSampler
 from tpu_hc_bench.obs import metrics as obs_metrics
 from tpu_hc_bench.serve import arrivals, slo
+from tpu_hc_bench.serve import cache as cache_mod
 from tpu_hc_bench.serve import engine as engine_mod
 from tpu_hc_bench.tune import prune, registry, space
 
@@ -185,7 +186,7 @@ def test_serve_resolve_validations_loud():
 
 
 def test_page_allocator_reserves_trash_page():
-    alloc = engine_mod.PageAllocator(5)
+    alloc = cache_mod.PageAllocator(5)
     assert alloc.free_pages == 4
     pages = alloc.alloc(4)
     assert 0 not in pages and sorted(pages) == [1, 2, 3, 4]
@@ -193,7 +194,7 @@ def test_page_allocator_reserves_trash_page():
     alloc.free(pages)
     assert alloc.free_pages == 4
     with pytest.raises(ValueError, match="trash"):
-        engine_mod.PageAllocator(1)
+        cache_mod.PageAllocator(1)
 
 
 def test_pick_bucket_off_ladder_raises():

@@ -339,8 +339,9 @@ def test_bounded_retention_long_trace(moe_engine, monkeypatch):
     from tpu_hc_bench import flags
     from tpu_hc_bench.serve import arrivals
     from tpu_hc_bench.serve import engine as engine_mod
+    from tpu_hc_bench.serve import loop as loop_mod
 
-    monkeypatch.setattr(engine_mod, "_DONE_SAMPLE_CAP", 6)
+    monkeypatch.setattr(loop_mod, "_DONE_SAMPLE_CAP", 6)
     cfg = flags.BenchmarkConfig(
         model="moe_tiny", workload="serve", arrival_rate=200.0,
         num_requests=24, max_prompt_len=8, max_output_len=4,

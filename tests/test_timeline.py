@@ -776,9 +776,9 @@ def test_serve_engine_records_spans(tmp_path):
     test_serve_phases' job)."""
     import inspect
 
-    from tpu_hc_bench.serve import engine as engine_mod
+    from tpu_hc_bench.serve import loop as loop_mod
 
-    src = inspect.getsource(engine_mod.ServeEngine)
+    src = inspect.getsource(loop_mod.ServeLoop)
     assert 'phases.enter(kind + "_dispatch", parent=kind)' in src
     assert 'phases.enter("retire")' in src
     assert 'timeline_mod.instant("admit"' in src

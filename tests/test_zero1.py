@@ -61,16 +61,24 @@ def arm_states(mesh8):
             "param_bytes": param_bytes}
 
 
-def test_zero1_matches_psum_bitwise(arm_states):
+def test_zero1_matches_psum_to_the_last_bits(arm_states):
     """Acceptance: the zero1 arm proves numerical equivalence to psum —
-    bitwise-identical f32 params after K steps (the scatter/shard-
-    update/gather pipeline is elementwise-identical math; only the
-    cross-device summation differs, and psum and psum_scatter reduce in
-    the same order)."""
+    the three steps' losses are EQUAL, and the f32 params after them
+    agree leaf by leaf to the last bits.  Not bitwise: the scatter /
+    shard-update / gather pipeline is elementwise-identical math, but
+    ``psum`` and ``psum_scatter`` do not reduce across devices in one
+    order on this JAX (0.9.0, 8 CPU devices).  Measured on this tree
+    (PR 33): 220 of the kernel's 1,920 entries and 2 of the bias's 10
+    differ, by at most 1.49e-8 absolute (one f32 ulp at the kernel's
+    largest entry, 0.21) and 1.75e-5 relative (on entries near zero,
+    which is why the limit is absolute); the limit is ~7x the former."""
     assert arm_states["losses_p"] == arm_states["losses_z"]
-    fp_p = ckpt.fingerprint(arm_states["state_p"].params)
-    fp_z = ckpt.fingerprint(arm_states["state_z"].params)
-    assert fp_p == fp_z
+    leaves_p = jax.tree.leaves(arm_states["state_p"].params)
+    leaves_z = jax.tree.leaves(arm_states["state_z"].params)
+    assert len(leaves_p) == len(leaves_z)
+    for p, z in zip(leaves_p, leaves_z):
+        np.testing.assert_allclose(np.asarray(z), np.asarray(p),
+                                   rtol=0.0, atol=1e-7)
 
 
 def test_zero1_opt_state_bytes_one_over_n(arm_states):

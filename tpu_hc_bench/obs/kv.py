@@ -1,6 +1,6 @@
 """KV-pool utilization ledger: allocation honesty for the serving lane.
 
-Admission is conservative by design (``serve/engine.py`` reserves every
+Admission is conservative by design (``serve/cache.py`` reserves every
 request's worst-case page count, so mid-generation eviction never
 happens) — which means the pool underutilizes whenever outputs run
 short, and before this ledger the waste was a guess, not a number.
@@ -18,7 +18,7 @@ gated the p99 (pool-starved ⇒ grow the pool / evict; batch-full ⇒
 scale out — the disaggregated-serving scaling-policy input).
 
 Record shapes (round 22, all host counters the engine already holds —
-no device round-trips; see ``serve.engine.KVLedger``):
+no device round-trips; see ``serve.cache.KVLedger``):
 
 - ``kv_pool`` records: periodic pool snapshots with cumulative
   ``reserved_page_s``/``written_page_s`` integrals, free-list depth,

@@ -9,11 +9,12 @@ the two techniques the related work canonized:
 - **Continuous batching** (Orca): requests are admitted into and
   retired from the running decode batch *per decode step*, instead of
   batches running to completion while arrivals queue
-  (``serve.engine``; ``--batching=static`` keeps the classic arm as
-  the A/B control).
+  (``serve.loop``, over ``serve.engine``'s programs;
+  ``--batching=static`` keeps the classic arm as the A/B control).
 - **Paged KV cache** (vLLM): decode members allocate KV cache in fixed
   pages from a shared pool, so memory scales with tokens actually held
-  rather than worst-case sequence slabs (``serve.decode``).
+  rather than worst-case sequence slabs (``serve.decode``'s programs,
+  ``serve.cache``'s allocation).
 
 Everything runs over a small ladder of AOT-compiled ``(batch, seqlen)``
 bucket shapes, warmed at startup through the shared persistent compile
