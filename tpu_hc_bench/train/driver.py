@@ -85,6 +85,10 @@ class BenchmarkResult:
     # compiled.cost_analysis() of the actual step program, "analytic" =
     # the hand-maintained spec.flops_per_example table (obs.efficiency)
     mfu_source: str = "analytic"
+    # share of the score rectangle's sub-tiles the flash kernel computes
+    # at this run's attention shape (ops.flash_attention.tile_plan, the
+    # plan its loops are built from); None where no flash kernel runs
+    flash_tile_share: float | None = None
     # resume identity when this run restored a checkpoint (None for a
     # fresh run): restored_step, saved_world -> live_world, arm, and
     # whether the elastic reshard ran — so `obs diff`/BENCH json can
@@ -110,6 +114,21 @@ def log_name(
     """Log naming convention, after the reference's
     ``tfmn-<n>n-<b>b-<data>-<fabric>-r<run>.log`` (run-tf-sing-ucx-openmpi.sh:9-12)."""
     return f"tpubench-{num_hosts}n-{batch}b-{data}-{fabric}-r{run}.log"
+
+
+def _flash_tile_plan(cfg: BenchmarkConfig, model, spec):
+    """The flash kernel's tile plan at this run's attention shape, or
+    None where the attention implementation runs no flash kernel (or the
+    model does not say its sequence and head widths)."""
+    heads, hidden = getattr(model, "heads", 0), getattr(model, "hidden", 0)
+    if (cfg.attention_impl not in ("flash", "ulysses_flash")
+            or not spec.is_text or not heads):
+        return None
+    from tpu_hc_bench.ops.flash_attention import tile_plan
+
+    seq = spec.input_shape[0]
+    return tile_plan(seq, seq, causal=spec.causal_lm,
+                     head_dim=hidden // heads)
 
 
 def _example_units(cfg: BenchmarkConfig, spec) -> str:
@@ -979,6 +998,12 @@ def run_benchmark(
             f"multislice: {num_slices} slices x {per_slice} — data axis = "
             f"dcn({num_slices}) x data({layout.total_workers // num_slices})")
     print_fn(f"device_kind={hw.device_kind()} global_batch={global_batch}")
+    flash_plan = _flash_tile_plan(cfg, model, spec)
+    if flash_plan is not None:
+        print_fn(f"flash tiles: {flash_plan.fwd[0]}/{flash_plan.rect} "
+                 f"computed, {flash_plan.fwd[1]} masked "
+                 f"({flash_plan.sub_q}x{flash_plan.sub_k} in blocks of "
+                 f"{flash_plan.block_q}x{flash_plan.block_k})")
     if compile_cache_dir:
         print_fn(f"compile cache: {compile_cache_dir} "
                  f"({cache_entries_before} entries at start)")
@@ -2241,6 +2266,8 @@ def run_benchmark(
                        if cfg.data_dir is not None and not spec.is_text
                        else None),
         mfu_source=mfu_rep["mfu_source"],
+        flash_tile_share=(flash_plan.computed_share
+                          if flash_plan is not None else None),
         resume=resume_rec,
     )
     tsum = trace_window.post_summary()
