@@ -298,6 +298,42 @@ def test_summarize_is_a_function_of_the_closed_loop(moe_engine,
         == {k: v for k, v in one.items() if k not in wall}
 
 
+def test_a_run_counts_its_own_compiles_not_the_cache_dirs_entries(
+        moe_engine, moe_requests):
+    """``post_warmup_compiles`` is what THIS process compiled during the
+    run: entries that another process (another pytest worker, another
+    engine) writes into the shared cache dir meanwhile are not the run's
+    — what failed eight serve tests in the driver's six-worker run on a
+    cold cache (PR 35)."""
+    import os
+
+    if moe_engine.cache_dir:
+        stranger = os.path.join(moe_engine.cache_dir, "another-process")
+        with open(stranger, "w") as f:
+            f.write("x")
+    try:
+        summary = moe_engine.run(
+            moe_requests, clock=engine_mod.VirtualClock(SERVE_VCOSTS))
+    finally:
+        if moe_engine.cache_dir:
+            os.remove(stranger)
+    assert summary["post_warmup_compiles"] == 0
+    assert summary["completed"] == len(moe_requests)
+
+
+def test_process_compiles_counts_a_compile_of_this_process():
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_hc_bench.utils import compile_cache
+
+    x = jnp.zeros((7, 13, 3), jnp.int8)
+    before = compile_cache.process_compiles()
+    # a shape no other test compiles: one fresh program
+    jax.jit(lambda x: x * 3 + 1)(x)
+    assert compile_cache.process_compiles() == before + 1
+
+
 # --- the sizes and the one door, pinned at the source ------------------
 
 

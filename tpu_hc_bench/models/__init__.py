@@ -61,8 +61,8 @@ class ModelSpec:
 def _registry() -> dict[str, ModelSpec]:
     from tpu_hc_bench.models import (
         alexnet, bert, cifar_resnet, deepspeech, densenet, googlenet, gpt,
-        inception, llama, mobilenet, nasnet, ncf, resnet, small_cnns,
-        solar_open2, vgg, vit,
+        granite4h, inception, llama, mobilenet, nasnet, ncf, resnet,
+        small_cnns, solar_open2, vgg, vit,
     )
 
     specs = [
@@ -176,6 +176,15 @@ def _registry() -> dict[str, ModelSpec]:
         ModelSpec("solar_open2_tiny", solar_open2.solar_open2_tiny, (64,),
                   2 * 0.2e6 * 64, is_text=True,
                   vocab_size=solar_open2.TINY["vocab_size"], causal_lm=True),
+        # Mamba-2 (SSD) layers beside NoPE GQA one layer in ten, a dense
+        # SwiGLU FFN, muP multipliers, a tied head: 3.19 B parameters, all
+        # multiplied per token (serve lane)
+        ModelSpec("granite_4_0_h_micro", granite4h.granite_4_0_h_micro,
+                  (2048,), 2 * 3.19e9 * 2048, is_text=True,
+                  vocab_size=100352, causal_lm=True),
+        ModelSpec("granite4h_tiny", granite4h.granite4h_tiny, (64,),
+                  2 * 0.2e6 * 64, is_text=True,
+                  vocab_size=granite4h.TINY["vocab_size"], causal_lm=True),
     ]
     return {s.name: s for s in specs}
 

@@ -59,8 +59,9 @@ def _l2norm(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
 
 
-def causal_conv(x, tail, w):
-    """Depthwise causal convolution over time, then SiLU, in float32.
+def causal_conv(x, tail, w, bias=None):
+    """Depthwise causal convolution over time (plus ``bias`` [c] where
+    given), then SiLU, in float32.
 
     ``x`` [b, s, c] the new inputs, ``tail`` [b, K-1, c] the inputs just
     before them (zeros at a sequence's start), ``w`` [K, c].  Returns
@@ -71,6 +72,8 @@ def causal_conv(x, tail, w):
     s = x.shape[1]
     y = sum(padded[:, i:i + s].astype(jnp.float32)
             * w[i].astype(jnp.float32) for i in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return nn.silu(y), padded
 
 

@@ -73,14 +73,20 @@ Two compiled shapes per family, both AOT-lowered at engine warmup
 **The cache is a tree** (``init_kv_state``).  A family whose every
 layer attends over keys and values carries the pair ``(k_pages,
 v_pages)`` above.  A family with a per-layer MIXER KIND
-(``_Family.mixers``: ``"attn"`` | ``"kda"``) carries ``{"pages":
-(k_pages, v_pages), "state": {"S", "conv"}}``: pages for its attention
-layers ONLY (the pool's leading axis counts those), and a
-slot-addressed recurrent state for its gated delta-rule layers —
-``S [kda_layers, slots, heads, d_k, d_v]`` float32 and the short
-convolution's tail ``conv [kda_layers, K-1, slots, 3 * heads * d]`` in
-the compute dtype (slots beside channels: the two minor axes tile, so a
-row's taps are read and written where the leaf rests).  A request owns pages AND one slot from admit to
+(``_Family.mixers``: ``"attn"`` | ``"kda"`` | ``"mamba2"``) carries
+``{"pages": (k_pages, v_pages), "state": {<leaf>, "conv"}}``: pages for
+its attention layers ONLY (the pool's leading axis counts those), and a
+slot-addressed recurrent state for its recurrent layers, of the one
+recurrent kind the family has (``_Recurrent``, which supplies its
+leaf's shape, its prefill and its decode step):
+
+- ``kda`` (gated delta rule): ``S [layers, slots, heads, d_k, d_v]``;
+- ``mamba2`` (SSD): ``h [layers, slots, heads, d_head, d_state]``;
+
+float32 both, and the short convolution's tail ``conv [layers, K-1,
+slots, channels]`` in the compute dtype (slots beside channels: the two
+minor axes tile, so a row's taps are read and written where the leaf
+rests).  A request owns pages AND one slot from admit to
 finish; its slot index rides in one more column of its table (the
 LAST: column 0 stays its first page), so both programs keep their
 positional signatures.  Slot 0 is the trash slot, as page 0 is the
@@ -88,22 +94,26 @@ trash page.  Prefill runs the chunked form of the recurrence over the
 padded bucket with the padding made inert and writes the state from
 zero whatever the slot held; decode runs one recurrence step for every
 slot at once, in place on the donated buffer (a slot no active row
-names keeps its state: decay 1, beta 0).  ``jax.named_scope`` names the
-four parts (``kda``, ``gqa``, ``moe``, ``head``) in both programs, and
+names keeps its state: decay 1 and no input).  ``jax.named_scope`` names
+the parts (``PARTS``: the recurrent kind's ``kda`` or ``ssm``, ``gqa``,
+the FFN's ``moe`` or ``mlp``, ``head``) in both programs, and
 ``part_of_ops`` maps a compiled program's operations to them.
 
 Supported families: ``GPTLM`` (gpt2*, moe*: learned positions, dense
-or MoE FFN), ``LlamaLM`` (llama*: RoPE, GQA, SwiGLU) and
-``SolarOpen2LM`` (solar_open2*: gated NoPE GQA every fourth layer,
-gated delta-rule layers between, sigmoid-routed MoE with a shared
-expert as a chip's share; gather arm, unquantized).  Everything else
-that claims ``causal_lm`` fails loudly at engine construction.
+or MoE FFN), ``LlamaLM`` (llama*: RoPE, GQA, SwiGLU), ``SolarOpen2LM``
+(solar_open2*: gated NoPE GQA every fourth layer, gated delta-rule
+layers between, sigmoid-routed MoE with a shared expert as a chip's
+share) and ``GraniteHybridLM`` (granite4h / granite_4_0_h_micro: Mamba-2
+layers with NoPE GQA one in ten, dense SwiGLU, muP multipliers, a tied
+head); the hybrids on the gather arm, unquantized.  Everything else that
+claims ``causal_lm`` fails loudly at engine construction.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import operator
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -196,13 +206,21 @@ class _Family:
     qkv: Callable               # (p_l, x, positions [b,s]) -> q, k, v
                                 # ([b,s,heads,d], [b,s,kvh,d] x2; RoPE
                                 # families rotate inside)
-    attn_out: Callable          # (p_l, ctx [b,s,heads,d]) -> [b,s,H]
-    ffn: Callable               # (p_l, x normed) -> [b,s,H]
+    attn_out: Callable          # (p_l, ctx [b,s,heads,d]) -> [b,s,H]; a
+                                # hybrid family's also takes the normed
+                                # input its gate may read: (p_l, ctx, u)
+    ffn: Callable               # (p_l, x normed) -> [b,s,H] (a routed one
+                                # of a hybrid: (y, held picks [b, s]))
     ffn_norm: Callable          # (p_l, x) -> normed
     ffn_norm_params: Callable   # (p_l) -> (gamma, beta|None)
     quant_paths: Callable       # (l) -> [(param path, contract axes)]
                                 # quantize_weights' int8_w walk
-    mixers: tuple = ()          # per layer "attn" | "kda"; () = all "attn"
+    mixers: tuple = ()          # per layer "attn" | the recurrent kind's
+                                # name; () = all "attn"
+    recurrent: Any = None       # the ``_Recurrent`` of the other layers
+    attn_scale: float | None = None     # scores' scale; None: 1/sqrt(d)
+    residual: Callable = operator.add   # (x, mixer or FFN output) -> x
+    ffn_scope: str = "moe"      # the FFN's named part in hybrid programs
     counters: tuple = ()        # names of the int32 scalars a decode step
                                 # appends to its tokens (``next_tokens
                                 # [b + len(counters)]``: no extra transfer)
@@ -219,7 +237,7 @@ class _Family:
     def state_layers(self) -> tuple:
         """The layers that keep a recurrent state in a slot."""
         return tuple(l for l in range(self.num_layers)
-                     if self.mixers and self.mixers[l] == "kda")
+                     if self.mixers and self.mixers[l] != "attn")
 
     def embed_prefill(self, params, tokens):
         # positions arange(s) — exactly the training forward's layout
@@ -429,9 +447,9 @@ def build_family(model, quant: str = "off") -> _Family:
             quant_paths=quant_paths,
         )
 
-    from tpu_hc_bench.models.solar_open2 import SolarOpen2LM
+    from tpu_hc_bench.models import solar_open2 as so
 
-    if isinstance(model, SolarOpen2LM):
+    if isinstance(model, so.SolarOpen2LM):
         if quant != "off":
             raise ValueError(
                 "--quant has no arm for the solar_open2 family (its "
@@ -457,21 +475,60 @@ def build_family(model, quant: str = "off") -> _Family:
                 for k in ("norm1", "mixer", "norm2", "moe")},
             attn_norm=norm("norm1"),
             attn_norm_params=lambda p_l: (p_l["norm1"]["scale"], None),
-            qkv=None, attn_out=None,    # the mixers' own functions
+            qkv=lambda p_l, u, positions: so.gqa_inputs(
+                p_l["mixer"], u, model.heads, model.kv_heads),
+            attn_out=lambda p_l, ctx, u: so.gqa_output(p_l["mixer"], ctx, u),
             ffn=ffn,
             ffn_norm=norm("norm2"),
             ffn_norm_params=lambda p_l: (p_l["norm2"]["scale"], None),
             quant_paths=lambda l: [],
             mixers=tuple("attn" if model.mixer_kind(l) == "gqa" else "kda"
                          for l in range(model.num_layers)),
+            recurrent=_kda(model),
             counters=("moe_picks_held",),
             picks_per_token=model.top_k * model.num_layers,
         )
 
+    from tpu_hc_bench.models import granite4h as gh
+
+    if isinstance(model, gh.GraniteHybridLM):
+        if quant != "off":
+            raise ValueError(
+                "--quant has no arm for the granite4h family (its "
+                "recurrent state stays unquantized)")
+        dt = model.dtype
+        norm = lambda name: (lambda p_l, x: RMSNorm(      # noqa: E731
+            eps=model.eps, dtype=dt).apply({"params": p_l[name]}, x))
+        return _Family(
+            model=model, num_layers=model.num_layers, heads=model.heads,
+            kv_heads=model.kv_heads, head_dim=model.head_dim,
+            norm_kind="rmsnorm",
+            embed_decode=lambda params, tokens, positions: model.pp_embed(
+                params, tokens[:, None], None)[0],
+            layer_params=lambda params, l: {
+                k: params[f"layer_{l}_{k}"]
+                for k in ("norm1", "mixer", "norm2", "mlp")},
+            attn_norm=norm("norm1"),
+            attn_norm_params=lambda p_l: (p_l["norm1"]["scale"], None),
+            qkv=lambda p_l, u, positions: gh.attn_inputs(
+                p_l["mixer"], u, model.heads, model.kv_heads),
+            attn_out=lambda p_l, ctx, u: gh.attn_output(p_l["mixer"], ctx),
+            ffn=lambda p_l, h: gh.mlp(p_l["mlp"], h),
+            ffn_norm=norm("norm2"),
+            ffn_norm_params=lambda p_l: (p_l["norm2"]["scale"], None),
+            quant_paths=lambda l: [],
+            mixers=tuple(model.mixer_kind(l)
+                         for l in range(model.num_layers)),
+            recurrent=_mamba2(model),
+            attn_scale=model.attn_scale,
+            residual=lambda x, y: gh.residual(x, y, model.residual_mult),
+            ffn_scope="mlp",
+        )
+
     raise ValueError(
         f"no paged-decode family for {type(model).__name__} (supported: "
-        "GPTLM, LlamaLM, SolarOpen2LM); non-causal members serve "
-        "single-forward requests instead")
+        "GPTLM, LlamaLM, SolarOpen2LM, GraniteHybridLM); non-causal "
+        "members serve single-forward requests instead")
 
 
 def quantize_weights(family: _Family, params: dict) -> dict:
@@ -617,9 +674,9 @@ def _pack_pairs(tables, lengths, active, page_size: int, chunk: int):
 # traced only inside the decode programs, which the engine lowers at
 # warm-up: no call of its own ever reaches it
 @functools.partial(  # tpu-hc: disable=serve-bucket-recompile
-    jax.jit, static_argnames="chunk")
+    jax.jit, static_argnames=("chunk", "scale"))
 def _attend_packed(q, k_pool, v_pool, layer, pairs, k_new, v_new,
-                   chunk: int):
+                   chunk: int, scale: float | None = None):
     """Single-query attention over the pages the rows HOLD plus the
     fresh token: ``_softmax_attend``'s sums, re-associated.
 
@@ -637,8 +694,8 @@ def _attend_packed(q, k_pool, v_pool, layer, pairs, k_new, v_new,
     against float32; the default would round the partial sums to
     bfloat16).  GQA is a reshape of ``q`` to [b, kvh, group, d]: head
     ``h`` reads kv head ``h // group`` and no page is repeated.  Lanes
-    past ``d`` are zero and stay in the contraction.  Returns
-    [b, heads, d].
+    past ``d`` are zero and stay in the contraction.  The scores' scale
+    is ``scale``, 1/sqrt(d) where None.  Returns [b, heads, d].
 
     Jitted, with the layer an operand (it is one entry of the gather's
     index either way): the layers of a program share one trace and one
@@ -647,7 +704,7 @@ def _attend_packed(q, k_pool, v_pool, layer, pairs, k_new, v_new,
     page, owner, below, n = pairs
     b, heads, d = q.shape
     _, kvh, _, page_size, lanes = k_pool.shape
-    scale = 1.0 / d ** 0.5
+    scale = 1.0 / d ** 0.5 if scale is None else scale
     qg = q.reshape(b, kvh, heads // kvh, d)
     q_pad = jnp.pad(qg, ((0, 0),) * 3 + ((0, lanes - d),))
     rows = jnp.arange(b, dtype=owner.dtype)[:, None]
@@ -699,19 +756,17 @@ def init_kv_state(family: _Family, num_pages: int, page_size: int,
     pools plus per-(layer, page) f32 scales under ``int8_kv`` (scales
     start at 1, matching the zeroed pool) — or, for a family with
     recurrent-state layers, ``{"pages": (k_pages, v_pages), "state":
-    {"S", "conv"}}`` with ``slots`` state slots (slot 0 the trash
-    slot): ``S`` float32 whatever ``dtype`` is."""
+    {<the kind's leaf>, "conv"}}`` with ``slots`` state slots (slot 0
+    the trash slot): the state float32 whatever ``dtype`` is."""
     if family.state_layers:
-        m = family.model
-        n = m.kda_heads * m.kda_head_dim
+        r = family.recurrent
         layers = len(family.state_layers)
         return {
             "pages": init_kv_pages(family, num_pages, page_size, dtype),
             "state": {
-                "S": jnp.zeros((layers, slots, m.kda_heads, m.kda_head_dim,
-                                m.kda_head_dim), jnp.float32),
-                "conv": jnp.zeros((layers, m.conv_kernel - 1, slots, 3 * n),
-                                  dtype)}}
+                r.leaf: jnp.zeros((layers, slots) + r.shape, jnp.float32),
+                "conv": jnp.zeros((layers, r.conv_kernel - 1, slots,
+                                   r.conv_channels), dtype)}}
     if quant == "int8_kv":
         kp, vp = init_kv_pages(family, num_pages, page_size, jnp.int8)
         sc = jnp.ones((family.num_layers, num_pages), jnp.float32)
@@ -1027,29 +1082,119 @@ def build_decode_fn(family: _Family, page_size: int, table_width: int,
 
 # ---------------------------------------------------------------------
 # families with a per-layer mixer kind: pages for the attention layers,
-# a slot-addressed recurrent state for the gated delta-rule layers
+# a slot-addressed recurrent state for the others
+
+
+@dataclasses.dataclass(frozen=True)
+class _Recurrent:
+    """A recurrent mixer kind, as ``init_kv_state`` and the hybrid
+    programs see it: one state leaf a slot holds beside the short
+    convolution's tail, a prefill from a zero state, and one step for
+    every slot at once.  The programs do the cache's reads and writes;
+    a kind computes."""
+
+    scope: str          # its named part in the programs (``PARTS``)
+    leaf: str           # its state leaf's key in the cache tree
+    shape: tuple        # one slot's state, float32
+    conv_kernel: int
+    conv_channels: int
+    prefill: Callable   # (p, u [1, s, H], valid [s]) -> (out [1, s, H],
+                        #  state [*shape], padded [1, K-1+s, channels]):
+                        #  positions past ``valid`` inert
+    step: Callable      # (p, u [b, 1, H], tails [b, K-1, channels],
+                        #  state [slots, *shape], slots [b], active [b])
+                        #  -> (out [b, 1, H], state, padded [b, K, ...]):
+                        #  a slot no active row names keeps its state
+    fence: bool = False     # decode: a layer's state write completes
+                            # before the next layer starts (see
+                            # ``_build_hybrid_decode_fn``)
+
+
+def _to_slots(rows, slots, n_slots: int):
+    """``rows [b, ...]`` scattered to slot order ``[n_slots, ...]``,
+    zeros where no row's slot is (the inactive rows name slot 0)."""
+    return jnp.zeros((n_slots,) + rows.shape[1:],
+                     rows.dtype).at[slots].set(rows)
+
+
+def _kda(m) -> _Recurrent:
+    """The gated delta rule (``models/solar_open2``): inert where ``g``
+    = 0 and ``beta`` = 0."""
+    from tpu_hc_bench.models import solar_open2 as so
+
+    n = m.kda_heads * m.kda_head_dim
+    shape = (m.kda_heads, m.kda_head_dim, m.kda_head_dim)
+
+    def prefill(p, u, valid):
+        tail0 = jnp.zeros((1, m.conv_kernel - 1, 3 * n), u.dtype)
+        q, k, v, g, beta, padded = so.kda_inputs(p, u, tail0, m.kda_heads,
+                                                 m.neg_eigval)
+        g = jnp.where(valid[None, :, None, None], g, 0.0)
+        beta = jnp.where(valid[None, :, None], beta, 0.0)
+        o, s_end = so.kda_sequence(q[0], k[0], v[0], g[0], beta[0],
+                                   jnp.zeros(shape, jnp.float32))
+        return so.kda_output(p, o[None], u, m.eps), s_end, padded
+
+    def step(p, u, tails, S, slots, active):
+        q, k, v, g, beta, padded = so.kda_inputs(p, u, tails, m.kda_heads,
+                                                 m.neg_eigval)
+        g = jnp.where(active[:, None, None], g[:, 0], 0.0)
+        beta = jnp.where(active[:, None], beta[:, 0], 0.0)
+        at = lambda rows: _to_slots(rows, slots, S.shape[0])  # noqa: E731
+        S, o = so.kda_step(S, at(q[:, 0]), at(k[:, 0]), at(v[:, 0]), at(g),
+                           at(beta))
+        return so.kda_output(p, o[slots][:, None], u, m.eps), S, padded
+
+    return _Recurrent("kda", "S", shape, m.conv_kernel, 3 * n, prefill, step)
+
+
+def _mamba2(m) -> _Recurrent:
+    """Mamba-2's SSD layer (``models/granite4h``): inert where the step
+    ``dt`` = 0 (decay 1, no input)."""
+    from tpu_hc_bench.models import granite4h as gh
+
+    shape = (m.mamba_heads, m.mamba_head_dim, m.d_state)
+
+    def prefill(p, u, valid):
+        tail0 = jnp.zeros((1, m.conv_kernel - 1, m.conv_channels), u.dtype)
+        x, B, C, dt, z, padded = gh.ssd_inputs(p, u, tail0, m.mamba_heads,
+                                               m.d_state)
+        dt = jnp.where(valid[None, :, None], dt, 0.0)
+        y, h_end = gh.ssd_sequence(x[0], B[0], C[0], dt[0], gh.ssd_decay(p),
+                                   jnp.zeros(shape, jnp.float32), m.chunk)
+        return gh.ssd_output(p, y[None], x, z, m.eps), h_end, padded
+
+    def step(p, u, tails, h, slots, active):
+        x, B, C, dt, z, padded = gh.ssd_inputs(p, u, tails, m.mamba_heads,
+                                               m.d_state)
+        dt = jnp.where(active[:, None], dt[:, 0], 0.0)
+        at = lambda rows: _to_slots(rows, slots, h.shape[0])  # noqa: E731
+        h, y = gh.ssd_step(h, at(x[:, 0]), at(B[:, 0]), at(C[:, 0]), at(dt),
+                           gh.ssd_decay(p))
+        return gh.ssd_output(p, y[slots][:, None], x, z, m.eps), h, padded
+
+    return _Recurrent("ssm", "h", shape, m.conv_kernel, m.conv_channels,
+                      prefill, step, fence=True)
 
 
 def _build_hybrid_prefill_fn(family: _Family, table_width: int):
-    """Prefill over a padded bucket for a family with ``kda`` layers.
+    """Prefill over a padded bucket for a family with recurrent layers.
 
     ``table`` is ``[table_width + 1]``: the pages, then the request's
     state slot.  The chunked recurrence runs over the whole bucket with
-    the padded positions made inert (``beta`` = 0, ``g`` = 0); the state
-    entering is zero whatever the slot held, the state leaving and the
-    convolution's tail at ``length`` go to the slot."""
-    from tpu_hc_bench.models import solar_open2 as so
+    the padded positions made inert; the state entering is zero whatever
+    the slot held, the state leaving and the convolution's tail at
+    ``length`` go to the slot."""
     from tpu_hc_bench.parallel.sequence import dense_attention
 
-    m = family.model
+    r = family.recurrent
     group = family.heads // family.kv_heads
-    n = m.kda_heads * m.kda_head_dim
     kv_index = {l: i for i, l in enumerate(family.kv_layers)}
     st_index = {l: i for i, l in enumerate(family.state_layers)}
 
     def prefill(params, kv, tokens, length, table):
         k_pages, v_pages = kv["pages"]
-        S, conv = kv["state"]["S"], kv["state"]["conv"]
+        S, conv = kv["state"][r.leaf], kv["state"]["conv"]
         s = tokens.shape[1]
         slot = table[table_width]
         valid = jnp.arange(s) < length
@@ -1060,37 +1205,31 @@ def _build_hybrid_prefill_fn(family: _Family, table_width: int):
             u = family.attn_norm(p_l, x)
             if l in kv_index:
                 with jax.named_scope("gqa"):
-                    q, k, v = so.gqa_inputs(p_l["mixer"], u, family.heads,
-                                            family.kv_heads)
+                    q, k, v = family.qkv(p_l, u, None)
                     new_k[l], new_v[l] = k[0], v[0]
                     # causal masking alone suffices under right-padding
                     ctx = dense_attention(
                         q, jnp.repeat(k, group, axis=2),
-                        jnp.repeat(v, group, axis=2), causal=True)
-                    x = x + so.gqa_output(p_l["mixer"], ctx, u)
+                        jnp.repeat(v, group, axis=2), causal=True,
+                        scale=family.attn_scale)
+                    x = family.residual(x, family.attn_out(p_l, ctx, u))
             else:
-                with jax.named_scope("kda"):
+                with jax.named_scope(r.scope):
                     li = st_index[l]
-                    tail0 = jnp.zeros((1, m.conv_kernel - 1, 3 * n),
-                                      u.dtype)
-                    q, k, v, g, beta, padded = so.kda_inputs(
-                        p_l["mixer"], u, tail0, m.kda_heads, m.neg_eigval)
-                    g = jnp.where(valid[None, :, None, None], g, 0.0)
-                    beta = jnp.where(valid[None, :, None], beta, 0.0)
-                    o, s_end = so.kda_sequence(
-                        q[0], k[0], v[0], g[0], beta[0],
-                        jnp.zeros(S.shape[2:], jnp.float32))
-                    x = x + so.kda_output(p_l["mixer"], o[None], u, m.eps)
+                    out, s_end, padded = r.prefill(p_l["mixer"], u, valid)
+                    x = family.residual(x, out)
                     S = jax.lax.dynamic_update_slice(
-                        S, s_end[None, None], (li, slot, 0, 0, 0))
+                        S, s_end[None, None], (li, slot) + (0,) * len(r.shape))
                     tail = jax.lax.dynamic_slice_in_dim(
-                        padded[0], length, m.conv_kernel - 1, axis=0)
+                        padded[0], length, r.conv_kernel - 1, axis=0)
                     conv = jax.lax.dynamic_update_slice(
                         conv, tail[None, :, None].astype(conv.dtype),
                         (li, 0, slot, 0))
-            with jax.named_scope("moe"):
-                y, _ = family.ffn(p_l, family.ffn_norm(p_l, x))
-                x = x + y
+            with jax.named_scope(family.ffn_scope):
+                y = family.ffn(p_l, family.ffn_norm(p_l, x))
+                if family.picks_per_token:
+                    y = y[0]
+                x = family.residual(x, y)
         with jax.named_scope("head"):
             x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
             logits = family.head(params, x_last)[:, 0]
@@ -1104,35 +1243,32 @@ def _build_hybrid_prefill_fn(family: _Family, table_width: int):
                 _write_prompt_pages(v_pages, vn, table[:table_width],
                                     length))
         return next_token, logits, {
-            "pages": pages, "state": {"S": S, "conv": conv}}
+            "pages": pages, "state": {r.leaf: S, "conv": conv}}
 
     return prefill
 
 
 def _build_hybrid_decode_fn(family: _Family, table_width: int,
                             scatter_new):
-    """One token a row for a family with ``kda`` layers, gather arm.
+    """One token a row for a family with recurrent layers, gather arm.
 
     ``tables`` is ``[b, table_width + 1]`` (pages, then the slot).  A
-    ``kda`` layer runs ONE recurrence step over every slot of the state
-    at once, in place: the rows' q, k, v, g, beta are scattered to slot
-    order first (a few KB a row), and a slot that no active row names
-    gets decay 1 and beta 0, which leave it as it was (inactive rows
-    name the trash slot 0).  No row's state is gathered out of the pool
-    or scattered back.  ``scatter_new`` is ``build_decode_fn``'s page
-    write.  Returns ``(next_tokens [b + 1], logits, kv)``:
-    the last entry counts the active rows' picks that landed on an
-    expert held here (``family.counters``)."""
-    from tpu_hc_bench.models import solar_open2 as so
-
-    m = family.model
+    recurrent layer runs ONE step over every slot of the state at once,
+    in place: the rows' inputs are scattered to slot order first (a few
+    KB a row), and a slot that no active row names gets inert inputs,
+    which leave it as it was (inactive rows name the trash slot 0).  No
+    row's state is gathered out of the pool or scattered back.
+    ``scatter_new`` is ``build_decode_fn``'s page write.  Returns
+    ``(next_tokens [b + len(family.counters)], logits, kv)``: for a
+    routed FFN the last entry counts the active rows' picks that landed
+    on an expert held here."""
+    r = family.recurrent
     kv_index = {l: i for i, l in enumerate(family.kv_layers)}
     st_index = {l: i for i, l in enumerate(family.state_layers)}
 
     def decode(params, kv, tokens, tables, lengths, active):
         k_pages, v_pages = kv["pages"]
-        S, conv = kv["state"]["S"], kv["state"]["conv"]
-        n_slots = S.shape[1]
+        S, conv = kv["state"][r.leaf], kv["state"]["conv"]
         tabs = tables[:, :table_width]
         chunk = chunk_pages(k_pages, *tabs.shape)
         pairs = _pack_pairs(tabs, lengths, active, k_pages.shape[3], chunk)
@@ -1141,49 +1277,53 @@ def _build_hybrid_decode_fn(family: _Family, table_width: int,
         new_k, new_v = {}, {}
         held = jnp.zeros((), jnp.int32)
 
-        def to_slots(rows):
-            return jnp.zeros((n_slots,) + rows.shape[1:],
-                             rows.dtype).at[slots].set(rows)
-
         for l in range(family.num_layers):
             p_l = family.layer_params(params, l)
             u = family.attn_norm(p_l, x)
             if l in kv_index:
                 with jax.named_scope("gqa"):
-                    q, k, v = so.gqa_inputs(p_l["mixer"], u, family.heads,
-                                            family.kv_heads)
+                    q, k, v = family.qkv(p_l, u, None)
                     new_k[l], new_v[l] = k[:, 0], v[:, 0]
                     q, pairs = jax.lax.optimization_barrier((q, pairs))
                     ctx = _attend_packed(
                         q[:, 0], k_pages, v_pages, kv_index[l], pairs,
-                        k[:, 0], v[:, 0], chunk)
-                    x = x + so.gqa_output(p_l["mixer"], ctx[:, None], u)
+                        k[:, 0], v[:, 0], chunk, family.attn_scale)
+                    x = family.residual(
+                        x, family.attn_out(p_l, ctx[:, None], u))
             else:
-                with jax.named_scope("kda"):
+                with jax.named_scope(r.scope):
                     li = st_index[l]
-                    q, k, v, g, beta, padded = so.kda_inputs(
+                    out, s_l, padded = r.step(
                         p_l["mixer"], u,
                         jnp.swapaxes(conv[li][:, slots], 0, 1),
-                        m.kda_heads, m.neg_eigval)
-                    g = jnp.where(active[:, None, None], g[:, 0], 0.0)
-                    beta = jnp.where(active[:, None], beta[:, 0], 0.0)
-                    s_l = jax.lax.dynamic_index_in_dim(S, li, 0, False)
-                    s_l, o = so.kda_step(
-                        s_l, to_slots(q[:, 0]), to_slots(k[:, 0]),
-                        to_slots(v[:, 0]), to_slots(g), to_slots(beta))
+                        jax.lax.dynamic_index_in_dim(S, li, 0, False),
+                        slots, active)
                     S = jax.lax.dynamic_update_index_in_dim(S, s_l, li, 0)
                     # a tap at a time: every operand axis but the
                     # channels is then an index of the scatter, and
                     # the leaf is written in the layout it rests in
-                    for t in range(m.conv_kernel - 1):
+                    for t in range(r.conv_kernel - 1):
                         conv = conv.at[li, t, slots].set(
                             padded[:, 1 + t].astype(conv.dtype))
-                    x = x + so.kda_output(p_l["mixer"], o[slots][:, None],
-                                          u, m.eps)
-            with jax.named_scope("moe"):
-                y, picks = family.ffn(p_l, family.ffn_norm(p_l, x))
-                x = x + y
-                held = held + jnp.sum(jnp.where(active, picks[:, 0], 0))
+                    x = family.residual(x, out)
+                    if r.fence:
+                        # Left free, the chip's compiler scheduled the
+                        # first layer's in-place state write late, kept
+                        # that version for the next layer's read-out and
+                        # then RECOMPUTED the write from the donated
+                        # buffer it had already updated: the first
+                        # Mamba-2 layer's state took two steps a step
+                        # (Granite at 64 rows, 13 GB of arguments on 16;
+                        # PERF.md, PR 35).  Tying the state to the
+                        # layer's output orders the write before the
+                        # next layer and leaves nothing to recompute.
+                        x, S = jax.lax.optimization_barrier((x, S))
+            with jax.named_scope(family.ffn_scope):
+                y = family.ffn(p_l, family.ffn_norm(p_l, x))
+                if family.picks_per_token:
+                    y, picks = y
+                    held = held + jnp.sum(jnp.where(active, picks[:, 0], 0))
+                x = family.residual(x, y)
         with jax.named_scope("head"):
             logits = family.head(params, x)[:, 0]
             next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1192,13 +1332,15 @@ def _build_hybrid_decode_fn(family: _Family, table_width: int,
                 kv["pages"], tabs, lengths, active,
                 jnp.stack([new_k[l] for l in family.kv_layers]),
                 jnp.stack([new_v[l] for l in family.kv_layers]))
-        return (jnp.concatenate([next_tokens, held[None]]), logits,
-                {"pages": pages, "state": {"S": S, "conv": conv}})
+        if family.counters:
+            next_tokens = jnp.concatenate([next_tokens, held[None]])
+        return (next_tokens, logits,
+                {"pages": pages, "state": {r.leaf: S, "conv": conv}})
 
     return decode
 
 
-PARTS = ("kda", "gqa", "moe", "head")
+PARTS = ("kda", "ssm", "gqa", "moe", "mlp", "head")
 # the grouped-matmul kernel ``jax.lax.ragged_dot`` lowers to carries its
 # own name and no scope: in these programs only the experts issue it
 _KERNEL_PARTS = {"ragged-dot": "moe"}

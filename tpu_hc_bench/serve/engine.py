@@ -18,8 +18,10 @@ summarizes it.  The lane's design constraints, in order:
    executables: an off-ladder shape *raises* instead of recompiling,
    and the ``serve-bucket-recompile`` analysis lint guards the source
    so no jit/lower call site creeps into the traffic path.  Measured
-   the same way as the round-10 hit/miss banner: compile-cache entry
-   deltas, re-counted after traffic (``post_warmup_compiles``).
+   over each ``run`` as the programs THIS process compiled meanwhile
+   (``post_warmup_compiles``: ``compile_cache.process_compiles``; the
+   cache dir's entries, which other processes and other engines of this
+   process write too, stay the warm-up's hit/miss banner).
 2. **Continuous batching** (Orca): admission and retirement happen per
    decode step.  A newly arrived request is prefilled as soon as a
    slot and pages are free, joins the running batch at the next step,
@@ -64,6 +66,7 @@ from tpu_hc_bench.obs import metrics as obs_metrics
 from tpu_hc_bench.obs import timeline as timeline_mod
 from tpu_hc_bench.serve import faults as faults_mod
 from tpu_hc_bench.serve.arrivals import Request
+from tpu_hc_bench.utils import compile_cache
 
 
 def ceil_pow2(n: int) -> int:
@@ -547,12 +550,13 @@ class ServeEngine:
             self, requests, policy, kv=self._kv, writer=writer,
             clock=clock or MonotonicClock(), fleet=fleet, faults=faults,
             journal_path=journal_path)
+        compiles_before = compile_cache.process_compiles()
         loop.play(drain_handler, step_timeout_s, on_watchdog)
         self._kv = loop.kv
         loop.close()
         entries_final = self._count_cache()
         summary = loop_mod.summarize(
-            loop, entries_final - self.entries_after_warmup)
+            loop, compile_cache.process_compiles() - compiles_before)
         writer.event("serve_summary", **summary)
         writer.event("serve_compile", **self.compile_record,
                      entries_final=entries_final,

@@ -21,6 +21,7 @@ manifest record); it is the only value the flag takes.
 from __future__ import annotations
 
 import os
+import threading
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
@@ -65,3 +66,32 @@ def entry_count(cache_dir: str) -> int:
     that appear between run start and end-of-warmup are the compiles
     this run paid for."""
     return sum(len(files) for _, _, files in os.walk(cache_dir))
+
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_compiles = {"n": 0, "listening": False}
+_compiles_lock = threading.Lock()
+
+
+def _on_compile_event(event: str, duration: float, **kw) -> None:
+    if event == _BACKEND_COMPILE:
+        with _compiles_lock:
+            _compiles["n"] += 1
+
+
+def process_compiles() -> int:
+    """Programs THIS process has compiled or fetched from the persistent
+    cache, by JAX's own backend-compile events, counted from the first
+    call (which starts the one listener a process keeps): a difference of
+    two calls is the compiles between them.  Unlike ``entry_count`` it
+    sees no other process's entries in a shared cache dir, counts
+    compiles under ``--compile_cache=off`` too, and counts nothing that
+    only reached the cache earlier."""
+    with _compiles_lock:
+        if not _compiles["listening"]:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event)
+            _compiles["listening"] = True
+        return _compiles["n"]
