@@ -453,6 +453,11 @@ def test_engine_serves_the_family_in_bfloat16_and_counts_its_pools():
     assert "moe_picks" not in summary
     assert {"ssm", "gqa", "mlp", "head"} == set(
         summary["op_parts"][f"decode@{eng.cap}"].values())
+    # every decode program is counted; interpreted here, the kernel is no
+    # custom call (what the chip's compiler makes of it:
+    # tests/test_tpu_compile.py)
+    assert summary["ssd_kernel_calls"] == {
+        f"decode@{b}": 0 for b in eng.batch_buckets}
 
 
 @pytest.mark.parametrize("flag,value,match", [

@@ -36,7 +36,8 @@ _SUMMARY_KV = ["kv_pool_util", "kv_req_gap_frac", "pages_grown_total"]
 _SUMMARY_ARMS = [
     "kv_reserve", "prefix_cache", "decode_attention", "quant",
     "decode_block_pages", "aot_decode_temp_bytes", "kv_pool_temp_ratio",
-    "state_pool_bytes", "kv_read", "state_slots", "state_slot_steps"]
+    "state_pool_bytes", "kv_read", "state_slots", "state_slot_steps",
+    "ssd_kernel_calls"]
 _SUMMARY_TAIL = [
     "post_warmup_compiles", "attribution", "tail_queue_wait_frac",
     "tail_decode_stall_frac", "bucket_util", "loop_phases", "loop_wall_s",

@@ -54,7 +54,7 @@ def mosaic(monkeypatch):
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    for mod in ("flash_attention", "paged_attention"):
+    for mod in ("flash_attention", "paged_attention", "ssd_decode"):
         monkeypatch.setattr(
             importlib.import_module(f"tpu_hc_bench.ops.{mod}"),
             "_interpret", lambda: False)
@@ -209,37 +209,35 @@ def _hybrid_model(kind: str):
                                                     "head"}
 
 
-@pytest.mark.parametrize("kind", ["solar_open2", "granite4h"])
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
-                                                          program, kind):
-    """One period of each hybrid family (a softmax layer over pages, three
-    recurrent layers over state slots: the gated delta rule's ``S``, or
-    Mamba-2's ``h`` of 64 heads x 64 x 128) at the published head and
-    state sizes, a narrow hidden size and 16 rows: the programs the
-    chip's compiler builds update the page pool AND the recurrent state
-    where they rest (no new array of either's shape; the small
-    convolution tails are a row scatter, held to the bound on
-    temporaries), and their temporaries stay under the largest leaf's
-    bytes (the engine's ``kv_pool_temp_ratio``)."""
+# one period of each hybrid family, compiled once for the tests below
+# that read it: (kind, program) -> (compiled, kv, family)
+_HYBRID: dict = {}
+_HYBRID_PAGE, _HYBRID_WIDTH, _HYBRID_ROWS = 16, 40, 16
+
+
+def _hybrid_program(one_chip, kind: str, program: str):
+    """The decode or prefill program of ``_hybrid_model(kind)`` over a
+    cache of ``_HYBRID_ROWS`` rows, as the chip's compiler builds it
+    (call it with the ``mosaic`` fixture active)."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    from tpu_hc_bench.analysis import hlo
     from tpu_hc_bench.serve import decode
+
+    if (kind, program) in _HYBRID:
+        return _HYBRID[kind, program]
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    model, parts_named = _hybrid_model(kind)
+    model, _ = _hybrid_model(kind)
     family = decode.build_family(model)
     params = jax.tree.map(
         lambda x: sd(x.shape, x.dtype),
         jax.eval_shape(lambda: model.init(
             jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
             train=False))["params"])
-    page, width, rows = 16, 40, 16
+    page, width, rows = _HYBRID_PAGE, _HYBRID_WIDTH, _HYBRID_ROWS
     kv = jax.tree.map(
         lambda x: sd(x.shape, x.dtype),
         jax.eval_shape(lambda: decode.init_kv_state(
@@ -252,14 +250,47 @@ def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
         fn = decode.build_prefill_fn(family, page, width)
         args = (sd((1, 512), jnp.int32), sd((), jnp.int32),
                 sd((width + 1,), jnp.int32))
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, kv, *args).compile()
+    _HYBRID[kind, program] = (
+        jax.jit(fn, donate_argnums=(1,)).lower(params, kv, *args).compile(),
+        kv, family)
+    return _HYBRID[kind, program]
+
+
+@pytest.mark.parametrize("kind", ["solar_open2", "granite4h"])
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
+                                                          program, kind):
+    """One period of each hybrid family (a softmax layer over pages, three
+    recurrent layers over state slots: the gated delta rule's ``S``, or
+    Mamba-2's ``h`` of 64 heads x 64 x 128) at the published head and
+    state sizes, a narrow hidden size and 16 rows: the programs the
+    chip's compiler builds update the page pool AND the recurrent state
+    where they rest (no new array of either's shape; the small
+    convolution tails are a row scatter, held to the bound on
+    temporaries), and their temporaries stay under the largest leaf's
+    bytes (the engine's ``kv_pool_temp_ratio``).  Mamba-2's decode steps
+    the state through ``ops.ssd_decode``, one call a layer, its result
+    the leaf it was handed (aliased), under the ``ssm`` part; no other
+    program calls it, and no array of a layer's slice of ``h`` is made."""
+    import jax
+    import numpy as np
+
+    from tpu_hc_bench.analysis import hlo
+    from tpu_hc_bench.ops import ssd_decode
+    from tpu_hc_bench.serve import decode
+
+    _, parts_named = _hybrid_model(kind)
+    compiled, kv, family = _hybrid_program(one_chip, kind, program)
+    rows, width = _HYBRID_ROWS, _HYBRID_WIDTH
+    text = compiled.as_text()
     leaves = jax.tree.leaves(kv)
     state = kv["state"][family.recurrent.leaf]
+    kernel = (kind, program) == ("granite4h", "decode")
     found = hlo.new_buffers_of_shape(
-        compiled.as_text(),
+        text,
         [hlo.shape_text(kv["pages"][0].shape, "bf16"),
-         hlo.shape_text(state.shape, "f32")])
+         hlo.shape_text(state.shape, "f32")]
+        + ([hlo.shape_text(state.shape[1:], "f32")] if kernel else []))
     # a leaf this small is moved whole into the chip's fast memory and
     # back (a result in memory space ``S(1)``, asynchronous ``copy-start``
     # / ``copy-done`` between the spaces): no re-layout, and at the
@@ -279,34 +310,75 @@ def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
         # 8 kv heads in bfloat16, as the cells: 512 pages a chunk
         assert decode.chunk_pages(
             kv["pages"][0], rows, width) == 512 < rows * width
-    parts = set(decode.part_of_ops(compiled.as_text()).values())
-    assert parts == parts_named
+    parts = decode.part_of_ops(text)
+    assert set(parts.values()) == parts_named
+    calls = [ln for ln in text.splitlines()
+             if re.match(rf"\s*%{ssd_decode.NAME}(\.\d+)? = ", ln)]
+    assert ssd_decode.kernel_calls(text) == len(calls) == (
+        len(family.state_layers) if kernel else 0)
+    for ln in calls:
+        # the leaf is operand 6 (after the layer, the slots and the
+        # rows' four vectors) and comes back as result 0
+        assert "output_to_operand_aliasing={{0}: (6, {})}" in ln
+        key = ln.split(" = ")[0].strip().lstrip("%") + ":" + hlo.shape_text(
+            state.shape, "f32")
+        assert parts[key] == "ssm"
+
+
+# the instruction lines (metadata left out) of Solar's programs above, as
+# they compiled before the Mamba-2 decode kernel: the kernel's seam
+# (``_Recurrent.step`` takes the whole leaf) moves no instruction
+_SOLAR_BEFORE = {
+    "decode":
+        "23dd16ba1fe8153e794fc54f85c684aaa6f246fcf07804cb73db7377a548c812",
+    "prefill":
+        "d93163f8441b51c589663ed80fc05c789de3c90c9e8dbc213f7734e460d485a8",
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_solar_programs_are_as_before_instruction_for_instruction(
+        one_chip, mosaic, program):
+    import hashlib
+
+    compiled, _, _ = _hybrid_program(one_chip, "solar_open2", program)
+    lines = [re.sub(r", metadata=\{[^}]*\}", "", ln)
+             for ln in compiled.as_text().splitlines() if " = " in ln]
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == (
+        _SOLAR_BEFORE[program])
 
 
 @pytest.mark.parametrize("fence", [True, False])
 def test_granite_decode_recomputes_no_state_write(one_chip, mosaic, fence):
-    """The decode program of a hybrid family whose state fills most of
-    the chip (9 Mamba-2 layers x 640 slots = 12.1 GB of float32 ``h``
-    beside the pages; a narrow hidden size, nothing allocated): without
-    the fence (``_Recurrent.fence``) the chip's compiler rematerializes
-    the first layer's in-place state write from the donated buffer that
-    write has already updated, so that layer's state takes two steps a
-    step (what read ``logit_error_excess`` +4 to +9.5 in the Granite
-    cell, PR 35); with it no instruction of the state's shape is
-    recomputed."""
+    """The decode program of the published 40 layers at the cell's 64
+    rows, its state filling most of the chip (36 Mamba-2 layers x 160
+    slots = 12.1 GB of float32 ``h`` beside the pages; a narrow hidden
+    size, nothing allocated), with the fence (``_Recurrent.fence``) and
+    without it: each Mamba-2 layer steps the state through one call of
+    ``ops.ssd_decode`` (36: what the engine's ``ssd_kernel_calls`` reads
+    of the cell's programs on the chip), and no instruction of the
+    state's shape is recomputed.  Before the kernel the state write was
+    an XLA fusion, and without the fence the chip's compiler
+    rematerialized the first layer's write from the donated buffer it
+    had already updated, so that layer's state took two steps a step
+    (what read ``logit_error_excess`` +4 to +9.5 in the Granite cell);
+    the kernel's aliased call leaves nothing of the state's shape to
+    recompute, and the fence stays to order each layer's write before
+    the next layer's work."""
     import dataclasses
 
     import jax
     import jax.numpy as jnp
 
     from tpu_hc_bench.models import granite4h
+    from tpu_hc_bench.ops import ssd_decode
     from tpu_hc_bench.serve import decode
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     model = granite4h.GraniteHybridLM(
-        vocab_size=2048, hidden=512, layer_types=granite4h.LAYER_TYPES[:10],
+        vocab_size=2048, hidden=512, layer_types=granite4h.LAYER_TYPES,
         heads=8, kv_heads=8, ffn=512, dtype=jnp.bfloat16)
     family = decode.build_family(model)
     assert family.recurrent.fence
@@ -317,7 +389,7 @@ def test_granite_decode_recomputes_no_state_write(one_chip, mosaic, fence):
         jax.eval_shape(lambda: model.init(
             jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
             train=False))["params"])
-    page, width, rows, slots = 16, 96, 64, 640
+    page, width, rows, slots = 16, 96, 64, 160
     kv = jax.tree.map(
         lambda x: sd(x.shape, x.dtype),
         jax.eval_shape(lambda: decode.init_kv_state(
@@ -331,4 +403,5 @@ def test_granite_decode_recomputes_no_state_write(one_chip, mosaic, fence):
     remat = [line.split(" = ")[0].strip() for line in text.splitlines()
              if " = " in line and ".remat" in line.split(" = ")[0]
              and hlo_shape in line.split(" = ")[1][:60]]
-    assert bool(remat) is not fence, remat
+    assert not remat, remat
+    assert ssd_decode.kernel_calls(text) == 36

@@ -92,9 +92,10 @@ LAST: column 0 stays its first page), so both programs keep their
 positional signatures.  Slot 0 is the trash slot, as page 0 is the
 trash page.  Prefill runs the chunked form of the recurrence over the
 padded bucket with the padding made inert and writes the state from
-zero whatever the slot held; decode runs one recurrence step for every
-slot at once, in place on the donated buffer (a slot no active row
-names keeps its state: decay 1 and no input).  ``jax.named_scope`` names
+zero whatever the slot held; decode steps the state in place on the
+donated buffer (a slot no active row names keeps its state: decay 1 and
+no input) — ``kda`` every slot of a layer at once, ``mamba2`` the rows'
+slots alone through ``ops.ssd_decode_step``.  ``jax.named_scope`` names
 the parts (``PARTS``: the recurrent kind's ``kda`` or ``ssm``, ``gqa``,
 the FFN's ``moe`` or ``mlp``, ``head``) in both programs, and
 ``part_of_ops`` maps a compiled program's operations to them.
@@ -1102,9 +1103,11 @@ class _Recurrent:
                         #  state [*shape], padded [1, K-1+s, channels]):
                         #  positions past ``valid`` inert
     step: Callable      # (p, u [b, 1, H], tails [b, K-1, channels],
-                        #  state [slots, *shape], slots [b], active [b])
-                        #  -> (out [b, 1, H], state, padded [b, K, ...]):
-                        #  a slot no active row names keeps its state
+                        #  leaf [layers, slots, *shape], layer, slots [b],
+                        #  active [b]) -> (out [b, 1, H], leaf, padded
+                        #  [b, K, ...]): the layer's state stepped where
+                        #  the whole leaf rests; a slot no active row
+                        #  names keeps its state
     fence: bool = False     # decode: a layer's state write completes
                             # before the next layer starts (see
                             # ``_build_hybrid_decode_fn``)
@@ -1135,7 +1138,8 @@ def _kda(m) -> _Recurrent:
                                    jnp.zeros(shape, jnp.float32))
         return so.kda_output(p, o[None], u, m.eps), s_end, padded
 
-    def step(p, u, tails, S, slots, active):
+    def step(p, u, tails, leaf, li, slots, active):
+        S = jax.lax.dynamic_index_in_dim(leaf, li, 0, False)
         q, k, v, g, beta, padded = so.kda_inputs(p, u, tails, m.kda_heads,
                                                  m.neg_eigval)
         g = jnp.where(active[:, None, None], g[:, 0], 0.0)
@@ -1143,15 +1147,20 @@ def _kda(m) -> _Recurrent:
         at = lambda rows: _to_slots(rows, slots, S.shape[0])  # noqa: E731
         S, o = so.kda_step(S, at(q[:, 0]), at(k[:, 0]), at(v[:, 0]), at(g),
                            at(beta))
-        return so.kda_output(p, o[slots][:, None], u, m.eps), S, padded
+        out = so.kda_output(p, o[slots][:, None], u, m.eps)
+        leaf = jax.lax.dynamic_update_index_in_dim(leaf, S, li, 0)
+        return out, leaf, padded
 
     return _Recurrent("kda", "S", shape, m.conv_kernel, 3 * n, prefill, step)
 
 
 def _mamba2(m) -> _Recurrent:
     """Mamba-2's SSD layer (``models/granite4h``): inert where the step
-    ``dt`` = 0 (decay 1, no input)."""
+    ``dt`` = 0 (decay 1, no input).  Its decode step is
+    ``ops.ssd_decode_step``: each row's state read once from its slot,
+    updated, read out and written back where it rests."""
     from tpu_hc_bench.models import granite4h as gh
+    from tpu_hc_bench.ops.ssd_decode import ssd_decode_step
 
     shape = (m.mamba_heads, m.mamba_head_dim, m.d_state)
 
@@ -1164,14 +1173,14 @@ def _mamba2(m) -> _Recurrent:
                                    jnp.zeros(shape, jnp.float32), m.chunk)
         return gh.ssd_output(p, y[None], x, z, m.eps), h_end, padded
 
-    def step(p, u, tails, h, slots, active):
+    def step(p, u, tails, h, li, slots, active):
         x, B, C, dt, z, padded = gh.ssd_inputs(p, u, tails, m.mamba_heads,
                                                m.d_state)
         dt = jnp.where(active[:, None], dt[:, 0], 0.0)
-        at = lambda rows: _to_slots(rows, slots, h.shape[0])  # noqa: E731
-        h, y = gh.ssd_step(h, at(x[:, 0]), at(B[:, 0]), at(C[:, 0]), at(dt),
-                           gh.ssd_decay(p))
-        return gh.ssd_output(p, y[slots][:, None], x, z, m.eps), h, padded
+        # ``ssd_step``'s operands, row by row: decay, then ``dt x``
+        h, y = ssd_decode_step(h, li, slots, jnp.exp(dt * gh.ssd_decay(p)),
+                               dt[..., None] * x[:, 0], B[:, 0], C[:, 0])
+        return gh.ssd_output(p, y[:, None], x, z, m.eps), h, padded
 
     return _Recurrent("ssm", "h", shape, m.conv_kernel, m.conv_channels,
                       prefill, step, fence=True)
@@ -1253,11 +1262,14 @@ def _build_hybrid_decode_fn(family: _Family, table_width: int,
     """One token a row for a family with recurrent layers, gather arm.
 
     ``tables`` is ``[b, table_width + 1]`` (pages, then the slot).  A
-    recurrent layer runs ONE step over every slot of the state at once,
-    in place: the rows' inputs are scattered to slot order first (a few
-    KB a row), and a slot that no active row names gets inert inputs,
-    which leave it as it was (inactive rows name the trash slot 0).  No
-    row's state is gathered out of the pool or scattered back.
+    recurrent layer hands its kind's step the whole state leaf and the
+    layer's index and gets the leaf back, stepped in place: ``kda`` runs
+    ONE step over every slot of the layer at once, the rows' inputs
+    scattered to slot order first (a few KB a row) and a slot that no
+    active row names given inert inputs, which leave it as it was;
+    ``mamba2``'s kernel visits the rows' slots alone.  Inactive rows name
+    the trash slot 0 and step it inertly.  No row's state is gathered
+    out of the pool or scattered back.
     ``scatter_new`` is ``build_decode_fn``'s page write.  Returns
     ``(next_tokens [b + len(family.counters)], logits, kv)``: for a
     routed FFN the last entry counts the active rows' picks that landed
@@ -1293,12 +1305,10 @@ def _build_hybrid_decode_fn(family: _Family, table_width: int,
             else:
                 with jax.named_scope(r.scope):
                     li = st_index[l]
-                    out, s_l, padded = r.step(
+                    out, S, padded = r.step(
                         p_l["mixer"], u,
-                        jnp.swapaxes(conv[li][:, slots], 0, 1),
-                        jax.lax.dynamic_index_in_dim(S, li, 0, False),
+                        jnp.swapaxes(conv[li][:, slots], 0, 1), S, li,
                         slots, active)
-                    S = jax.lax.dynamic_update_index_in_dim(S, s_l, li, 0)
                     # a tap at a time: every operand axis but the
                     # channels is then an index of the scatter, and
                     # the leaf is written in the layout it rests in
