@@ -321,3 +321,91 @@ def test_train_driver_reads_the_plan_of_the_shape_it_runs(model, impl, want):
     else:
         assert (plan.fwd[0], plan.rect, plan.fwd[1]) == want
         assert plan.computed_share == want[0] / want[1]
+
+
+# ---------------------------------------------------------------------------
+# the sliding window (forward only: the serve lane's prefill)
+
+
+@pytest.mark.parametrize("s,window,block,sub", [
+    (37, 8, 16, 8),       # window under a block, several blocks
+    (70, 20, 32, 8),      # window crosses one block edge
+    (70, 5, 16, 8),       # window under a sub-tile
+    (100, 64, 32, 16),    # window over a block: a band of three
+    (50, 100, 16, 8),     # window past the sequence: the causal square
+    (33, 1, 16, 8),       # a query sees itself alone
+    (300, 40, 1024, None),  # the default blocks: one block, 256 sub-tiles
+])
+def test_window_forward_matches_masked_dense(s, window, block, sub):
+    q, k, v = _qkv(b=2, s=s, h=3, d=16, seed=s)
+    got = flash_attention(q, k, v, causal=True, window=window,
+                          block_q=block, block_k=block, sub_tile=sub)
+    np.testing.assert_allclose(
+        got, seq.dense_attention(q, k, v, causal=True, window=window),
+        atol=2e-6, rtol=2e-6)
+
+
+def _count_band(plan):
+    """(computed, masked) sub-tiles of the band, element by element."""
+    computed = masked = 0
+    for q0 in range(0, plan.sq_p, plan.sub_q):
+        for k0 in range(0, plan.sk_p, plan.sub_k):
+            qs = np.arange(q0, q0 + plan.sub_q)[:, None]
+            ks = np.arange(k0, k0 + plan.sub_k)[None]
+            vis = (qs >= ks) & (ks > qs - plan.window)
+            computed += bool(vis.any())
+            masked += bool(vis.any() and not vis.all())
+    return computed, masked
+
+
+@pytest.mark.parametrize("s,window,block,sub", [
+    (37, 8, 16, 8), (70, 20, 32, 8), (100, 64, 32, 16), (50, 100, 16, 8),
+    (8192, 1024, 1024, 256), (4096, 1024, 1024, 256), (512, 1024, 1024, 256),
+])
+def test_window_plan_computes_only_the_band(s, window, block, sub):
+    """The windowed forward's count against the mask itself: no sub-tile
+    wholly below the band is computed, and the grid is the band."""
+    plan = tile_plan(s, s, block, block, True, sub_tile=sub, window=window)
+    assert plan.fwd == _count_band(plan)
+    n = plan.sq_p // plan.block_q
+    assert plan.band == min(n, -(-(window - 1) // plan.block_k) + 1)
+    with pytest.raises(ValueError, match="forward only"):
+        plan.bwd_dkv
+
+
+def test_window_none_leaves_the_train_cells_plan_as_it_was():
+    """``tile_plan`` as the parent computed it at the train cells' shape
+    and at longer ones (read off the tree before the window argument)."""
+    for (s, d), fields, counts, offsets in [
+            ((1024, 64), (1024, 1024, 256, 256, 1024, 1024, True),
+             (10, 4), [0]),
+            ((4096, 64), (1024, 1024, 256, 256, 4096, 4096, True),
+             (136, 16), [0, 1023]),
+            ((8192, 128), (1024, 1024, 256, 256, 8192, 8192, True),
+             (528, 32), [0, 1023])]:
+        plan = tile_plan(s, s, causal=True, head_dim=d)
+        assert tuple(plan)[:7] == fields and plan.window is None
+        assert plan.fwd == plan.bwd_dq == plan.bwd_dkv == counts
+        assert sorted(set(plan.block_offsets())) == offsets
+
+
+def test_window_kernel_has_a_name_of_its_own():
+    """The benchmark's ``kernel.flash_window_roofline`` finds the windowed
+    forward's events by name; the causal kernels keep theirs."""
+    import re
+
+    q, k, v = _qkv(b=1, s=32, h=1, d=8)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=8, block_q=16, block_k=16,
+        sub_tile=8))(q, k, v)
+    names = set(re.findall(r"\bname=(\w+)", str(jaxpr)))
+    assert "flash_window_fwd" in names
+    assert not names & {"flash_attention_fwd", "flash_attention_bwd_dq"}
+
+
+@pytest.mark.parametrize("kw", [dict(causal=False, window=4),
+                                dict(causal=True, window=0)])
+def test_window_refuses_what_it_cannot_mask(kw):
+    q, k, v = _qkv(b=1, s=16, h=1, d=8)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, **kw)

@@ -405,3 +405,78 @@ def test_granite_decode_recomputes_no_state_write(one_chip, mosaic, fence):
              and hlo_shape in line.split(" = ")[1][:60]]
     assert not remat, remat
     assert ssd_decode.kernel_calls(text) == 36
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_window_family_programs_hold_no_second_pool(one_chip, mosaic,
+                                                    program):
+    """One period of the mellum2 family (three sliding-window layers, one
+    full-attention layer) at the published head size and window, a narrow
+    hidden size, 16 rows: both programs write the full layers' pages and
+    the window layers' ring where they rest (no new array of either
+    pool's shape), and the prefill of 2,048 tokens attends through the
+    flash kernels under their own names — the windowed forward once a
+    window layer, the causal one once a full layer; the decode program
+    runs none."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_hc_bench.analysis import hlo
+    from tpu_hc_bench.models import mellum2
+    from tpu_hc_bench.serve import decode
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = mellum2.Mellum2LM(
+        vocab_size=2048, hidden=512, layer_types=mellum2.LAYER_TYPES[:4],
+        heads=4, kv_heads=2, n_experts=8, top_k=2, expert_ffn=256,
+        dtype=jnp.bfloat16)
+    family = decode.build_family(model)
+    params = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+            train=False))["params"])
+    page, width, rows = 16, 160, 16
+    ring = decode.ring_width(family, page, width)
+    assert ring == 65
+    kv = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda: decode.init_kv_state(
+            family, 1 + rows * width, page, jnp.bfloat16,
+            window_pages=1 + rows * ring)))
+    cols = width + ring
+    if program == "decode":
+        fn = decode.build_decode_fn(family, page, width)
+        args = (sd((rows,), jnp.int32), sd((rows, cols), jnp.int32),
+                sd((rows,), jnp.int32), sd((rows,), jnp.bool_))
+    else:
+        fn = decode.build_prefill_fn(family, page, width)
+        args = (sd((1, 2048), jnp.int32), sd((), jnp.int32),
+                sd((cols,), jnp.int32))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, kv, *args).compile()
+    text = compiled.as_text()
+    found = hlo.new_buffers_of_shape(
+        text, [hlo.shape_text(kv["pages"][0].shape, "bf16"),
+               hlo.shape_text(kv["window"][0].shape, "bf16")])
+    # a pool this small (tens of MB) may be moved whole into the chip's
+    # fast memory and back (a result in memory space ``S(1)``), as the
+    # hybrid test above says; at the cell's size (GBs) it cannot, and the
+    # bound on temporaries holds it
+    found = [i for i in found
+             if "S(1)}" not in i.text.split(" = ")[1].split(" ")[0]]
+    assert not found, [(i.name, i.opcode) for i in found]
+    if program == "decode":      # a prefill's activations outgrow a pool
+        leaves = jax.tree.leaves(kv)    # this narrow; the cell's do not
+        assert compiled.memory_analysis().temp_size_in_bytes < max(
+            x.size * x.dtype.itemsize for x in leaves)
+    calls = [re.sub(r"\.\d+$", "", c) for c in _custom_calls(text)]
+    flash = sorted(c for c in calls if c.startswith("flash"))
+    if program == "decode":
+        assert flash == []
+    else:
+        assert flash == ["flash_attention_fwd"] + ["flash_window_fwd"] * 3
+    assert {"swa", "gqa", "moe", "head"} <= set(
+        decode.part_of_ops(text).values())

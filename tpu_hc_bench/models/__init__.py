@@ -61,8 +61,8 @@ class ModelSpec:
 def _registry() -> dict[str, ModelSpec]:
     from tpu_hc_bench.models import (
         alexnet, bert, cifar_resnet, deepspeech, densenet, googlenet, gpt,
-        granite4h, inception, llama, mobilenet, nasnet, ncf, resnet,
-        small_cnns, solar_open2, vgg, vit,
+        granite4h, inception, llama, mellum2, mobilenet, nasnet, ncf,
+        resnet, small_cnns, solar_open2, vgg, vit,
     )
 
     specs = [
@@ -185,6 +185,16 @@ def _registry() -> dict[str, ModelSpec]:
         ModelSpec("granite4h_tiny", granite4h.granite4h_tiny, (64,),
                   2 * 0.2e6 * 64, is_text=True,
                   vocab_size=granite4h.TINY["vocab_size"], causal_lm=True),
+        # sliding-window and YaRN full attention, 3 : 1, over top-8 of 64
+        # softmax-routed SwiGLU experts: a serving stage of two periods
+        # (8 of 28 layers), ~0.79 B parameters multiplied per token of the
+        # 3.79 B held (serve lane)
+        ModelSpec("mellum2_12b_a2_5b_8l", mellum2.mellum2_12b_a2_5b_8l,
+                  (2048,), 2 * 0.79e9 * 2048, is_text=True,
+                  vocab_size=98304, causal_lm=True),
+        ModelSpec("mellum2_tiny", mellum2.mellum2_tiny, (64,),
+                  2 * 0.1e6 * 64, is_text=True,
+                  vocab_size=mellum2.TINY["vocab_size"], causal_lm=True),
     ]
     return {s.name: s for s in specs}
 

@@ -44,7 +44,8 @@ class RMSNorm(nn.Module):
         return (y * scale.astype(jnp.float32)).astype(self.dtype)
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
+def apply_rope(x, positions, theta: float = 10000.0, inv_freq=None,
+               scale: float = 1.0):
     """Rotary position embedding over the trailing head_dim.
 
     ``x``: [batch, seq, heads, head_dim]; ``positions``: [seq] global
@@ -52,11 +53,16 @@ def apply_rope(x, positions, theta: float = 10000.0):
     pass their offset range), or [batch, seq] per-row positions (the
     serving lane's decode step, where every in-flight request sits at
     its own cache depth).  Split-half convention (rotate_half), f32
-    trig, output in x's dtype.
+    trig, output in x's dtype.  ``inv_freq`` [head_dim / 2] replaces
+    ``theta``'s frequencies (a scaled rotary such as YaRN), and ``scale``
+    multiplies cos and sin (YaRN's attention factor).
     """
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * freqs
     if angles.ndim == 2:                             # [S, half]
         cos = jnp.cos(angles)[None, :, None, :]      # [1, S, 1, half]
@@ -64,6 +70,8 @@ def apply_rope(x, positions, theta: float = 10000.0):
     else:                                            # [B, S, half]
         cos = jnp.cos(angles)[:, :, None, :]         # [B, S, 1, half]
         sin = jnp.sin(angles)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate(

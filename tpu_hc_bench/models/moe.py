@@ -27,7 +27,10 @@ the DeepSeek-V3 lineage): independent sigmoid scores in float32, the
 top-k chosen on ``score + router_bias`` (a per-expert selection bias that
 never enters the weights), the chosen scores normalized to sum to 1,
 SwiGLU experts (``gated``), and a shared expert every token passes
-through (``shared_ffn``).  ``experts_held=(lo, hi)`` makes the layer one
+through (``shared_ffn``).  Softmax routing takes the same path (the
+share path, ``MoEFFN._share``) once any of its fields is set: the top-k
+of a softmax over all experts, their probabilities renormalised to sum
+to 1, gated experts.  ``experts_held=(lo, hi)`` makes the layer one
 chip's share of an expert-parallel deployment: it routes over all
 ``num_experts``, holds the tensors of experts ``lo..hi-1`` only, and
 computes THEIR part of the result with the ragged (zero-drop) dispatch;
@@ -96,7 +99,9 @@ def sigmoid_topk(scores: jax.Array, bias: jax.Array, top_k: int,
     the ``top_k`` largest of ``scores + bias`` are chosen, and a chosen
     expert's weight is its own score (the bias only moves the choice),
     over the chosen scores' sum when ``normalize``.  Returns ``(choices
-    [..., k] int32, gates [..., k] float32)``."""
+    [..., k] int32, gates [..., k] float32)``.  Softmax routing with
+    top-k renormalisation is the same selection over softmax scores
+    with no bias."""
     _, idx = jax.lax.top_k(scores + bias, top_k)
     gates = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
@@ -187,13 +192,11 @@ class MoEFFN(nn.Module):
     def __call__(self, x):
         b, s, h = x.shape
         e = self.num_experts
-        if self.score == "sigmoid":
-            return self._sigmoid_share(x)
-        if (self.gated or self.shared_ffn or self.experts_held
-                or self.score != "softmax"):
-            raise ValueError(
-                "gated / shared_ffn / experts_held belong to "
-                f"score='sigmoid' (got score={self.score!r})")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router score {self.score!r}")
+        if (self.score == "sigmoid" or self.gated or self.shared_ffn
+                or self.experts_held):
+            return self._share(x)
 
         router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                           param_dtype=jnp.float32, name="router")
@@ -235,13 +238,18 @@ class MoEFFN(nn.Module):
         y = jnp.einsum("bsec,ebch->bsh", combine, out)
         return y, aux
 
-    def _sigmoid_share(self, x):
-        """``score="sigmoid"``: route over all ``num_experts``, compute
-        the held experts' part (ragged, zero-drop) plus the shared
-        expert.  Sows ``stats/picks_held`` [b, s]: how many of each
-        token's ``top_k`` picks landed on an expert held here."""
+    def _share(self, x):
+        """The share path: route over all ``num_experts``, compute the
+        held experts' part (ragged, zero-drop) plus the shared expert.
+        ``score="sigmoid"``: sigmoid scores, the top-k chosen on ``score
+        + router_bias`` (``sigmoid_topk``); ``"softmax"``: a softmax over
+        all experts, the top-k chosen on it and their probabilities
+        renormalised to sum to 1 (``norm_topk``); the scores float32
+        either way.  Sows ``stats/picks_held`` [b, s]: how many of each
+        token's ``top_k`` picks landed on an expert held here, and
+        ``stats/choices`` [b * s, k]: the picks."""
         if self.impl != "ragged":
-            raise ValueError("score='sigmoid' dispatches ragged "
+            raise ValueError("the share path dispatches ragged "
                              f"(zero-drop) only, not {self.impl!r}")
         b, s, h = x.shape
         e = self.num_experts
@@ -253,12 +261,18 @@ class MoEFFN(nn.Module):
                           param_dtype=jnp.float32,
                           precision=jax.lax.Precision.HIGHEST,
                           name="router")
-        bias = self.param("router_bias", nn.initializers.zeros, (e,),
-                          jnp.float32)
-        scores = jax.nn.sigmoid(router(x.astype(jnp.float32)))
-        choices, gates = sigmoid_topk(
-            scores.reshape(b * s, e), bias, self.top_k, self.norm_topk,
-            self.routed_scale)
+        logits = router(x.astype(jnp.float32))
+        if self.score == "sigmoid":
+            bias = self.param("router_bias", nn.initializers.zeros, (e,),
+                              jnp.float32)
+            choices, gates = sigmoid_topk(
+                jax.nn.sigmoid(logits).reshape(b * s, e), bias, self.top_k,
+                self.norm_topk, self.routed_scale)
+        else:
+            choices, gates = sigmoid_topk(
+                jax.nn.softmax(logits, axis=-1).reshape(b * s, e), 0.0,
+                self.top_k, self.norm_topk, self.routed_scale)
+        self.sow("stats", "choices", choices)
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         held = hi - lo
         ws = [self.param(n, init, shape, self.param_dtype)

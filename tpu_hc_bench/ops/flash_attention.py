@@ -14,6 +14,14 @@ A causal call computes only the sub-tiles at or below the diagonal
 (``tile_plan``; at seq >= 2048 the grid also skips whole blocks above it,
 whose K/V block DMAs still run: the grid is a rectangle).
 
+A causal call with a ``window`` (sliding-window layers: query ``i`` sees
+keys ``i - window < j <= i``) is forward only, under a kernel of its own
+(``flash_window_fwd``).  Its grid walks each query block's BAND: the key
+blocks from the one the window's lower edge reaches to the diagonal
+block, so a long sequence costs its band and not its square, and inside
+a block only the sub-tiles that meet the band are computed; those the
+band's lower edge or the diagonal crosses are masked.
+
 The backward pass (custom VJP) recomputes probabilities blockwise from the
 saved per-row logsumexp — the standard flash-attention backward:
 
@@ -107,6 +115,14 @@ class TilePlan(typing.NamedTuple):
     sq_p: int
     sk_p: int
     causal: bool
+    window: int | None = None
+
+    @property
+    def band(self) -> int:
+        """Key blocks a query block visits under the window: its own and
+        those behind it that the window reaches (the grid's last axis)."""
+        nq = self.sq_p // self.block_q
+        return min(nq, -(-(self.window - 1) // self.block_k) + 1)
 
     @property
     def one_block(self) -> bool:
@@ -140,15 +156,38 @@ class TilePlan(typing.NamedTuple):
                 _div_clip(first_k + self.sub_k - 1 + self.sub_q - 1,
                           self.sub_q, n))
 
+    def window_span(self, delta: int, a: int) -> tuple:
+        """``(lo, edge, full, live)`` for q sub-tile ``a`` under the
+        window: key sub-tiles ``[lo, edge)`` cross the band's lower edge,
+        ``[edge, full)`` lie wholly inside the band for every query of
+        the sub-tile, ``[full, live)`` cross the diagonal (a sub-tile
+        that crosses both is counted with the lower edge); the rest hold
+        no visible key.  The windowed forward's walk."""
+        n = self.block_k // self.sub_k
+        first_q = delta + a * self.sub_q
+        last_q = first_q + self.sub_q - 1
+        full = _div_clip(first_q + 1, self.sub_k, n)
+        live = _div_clip(last_q + self.sub_k, self.sub_k, n)
+        lo = _div_clip(first_q - self.window + 1, self.sub_k, n)
+        edge = min(max(-(-(last_q - self.window + 1) // self.sub_k), lo),
+                   full)
+        return lo, edge, full, live
+
     def block_offsets(self) -> list:
         """``delta = i * block_q - j * block_k`` of every block of the
         grid that is computed, i.e. holds a visible element.  Every block
         wholly at or below the diagonal (``delta >= block_k - 1``: its
         spans are all the same) reads ``block_k - 1``; a block the
         diagonal crosses keeps its own.  The kernels build one body a
-        DISTINCT offset; the counts below sum over all of them."""
+        DISTINCT offset; the counts below sum over all of them.  Under a
+        window the grid is the band (``band``: key block ``i - band + 1
+        + t`` at step ``t``), whose offsets are few and kept as they are."""
+        nq = self.sq_p // self.block_q
+        if self.window is not None:
+            return [(self.band - 1 - t) * self.block_k for i in range(nq)
+                    for t in range(self.band) if i >= self.band - 1 - t]
         deltas = [i * self.block_q - j * self.block_k
-                  for i in range(self.sq_p // self.block_q)
+                  for i in range(nq)
                   for j in range(self.sk_p // self.block_k)]
         if not self.causal:
             return [0] * len(deltas)
@@ -161,8 +200,15 @@ class TilePlan(typing.NamedTuple):
 
     @property
     def fwd(self) -> tuple:
+        rows = range(self.block_q // self.sub_q)
+        if self.window is not None:
+            spans = [self.window_span(d, a) for d in self.block_offsets()
+                     for a in rows]
+            return (sum(live - lo for lo, _, _, live in spans),
+                    sum(edge - lo + live - full
+                        for lo, edge, full, live in spans))
         spans = [self.key_span(d, a) for d in self.block_offsets()
-                 for a in range(self.block_q // self.sub_q)]
+                 for a in rows]
         return (sum(live for _, live in spans),
                 sum(live - full for full, live in spans))
 
@@ -170,6 +216,8 @@ class TilePlan(typing.NamedTuple):
 
     @property
     def bwd_dkv(self) -> tuple:
+        if self.window is not None:
+            raise ValueError("the windowed kernel is forward only")
         n_q = self.block_q // self.sub_q
         spans = [self.query_span(d, b) for d in self.block_offsets()
                  for b in range(self.block_k // self.sub_k)]
@@ -194,20 +242,29 @@ def _fit(block, seq, sub):
 
 def tile_plan(sq: int, sk: int, block_q: int = _BLOCK_Q,
               block_k: int = _BLOCK_K, causal: bool = False,
-              head_dim: int = 64, sub_tile: int | None = None) -> TilePlan:
+              head_dim: int = 64, sub_tile: int | None = None,
+              window: int | None = None) -> TilePlan:
     """What a call of these lengths runs, from static shapes alone.
 
     A non-causal call computes every block as one tile.  A causal call
     cuts each block into ``sub_tile`` x ``sub_tile`` sub-tiles (default
     ``_SUB_TILE``; the block itself where that does not divide it) and
-    computes those that hold a visible element."""
+    computes those that hold a visible element.  A ``window`` (causal
+    self-attention only: ``sq == sk``, square blocks) also leaves out
+    the blocks and sub-tiles wholly below the band."""
+    if window is not None and not (causal and sq == sk and window >= 1):
+        raise ValueError(f"a window needs causal self-attention and a width "
+                         f">= 1 (sq {sq}, sk {sk}, window {window})")
     if head_dim > 128:           # keep the VMEM working set bounded
         block_k = min(block_k, 512)
     sub = (_SUB_TILE if sub_tile is None else sub_tile) if causal else None
     block_q, sub_q = _fit(block_q, sq, sub)
     block_k, sub_k = _fit(block_k, sk, sub)
+    if window is not None and (block_q, sub_q) != (block_k, sub_k):
+        raise ValueError(f"a window walks square blocks: {block_q} x "
+                         f"{block_k}")
     return TilePlan(block_q, block_k, sub_q, sub_k, _pad_up(sq, block_q),
-                    _pad_up(sk, block_k), causal)
+                    _pad_up(sk, block_k), causal, window)
 
 
 def _when(cond, body):
@@ -282,17 +339,50 @@ def _scores(q, k, scale):
 # ---------------------------------------------------------------------------
 
 
+def _writer(o_ref, lse_ref):
+    def write(rows, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[0, rows, :] = m + jnp.log(l)
+
+    return write
+
+
+def _fold_rows(rows, scores, v_ref, state, write):
+    """One q sub-tile's pieces ``scores`` (``(s, visible, cols)``, masked
+    scores in float32) folded into its rows' online softmax: into the
+    state a row keeps between grid steps, or written where it ends."""
+    m = functools.reduce(jnp.maximum, [
+        jnp.max(s, axis=1, keepdims=True) for s, _, _ in scores])
+    l, acc = 0.0, 0.0
+    if state:
+        m_ref, l_ref, acc_ref = state
+        m_old = m_ref[rows, :]                     # [SQ, 1]
+        m = jnp.maximum(m_old, m)
+        corr = jnp.exp(m_old - m)
+        l, acc = l_ref[rows, :] * corr, acc_ref[rows, :] * corr
+    for s, visible, cols in scores:
+        p = jnp.exp(s - m)
+        if visible is not None:
+            # fully-masked rows keep m == _NEG_INF; exp(s-m)=1
+            # there, so re-mask
+            p = jnp.where(visible, p, 0.0)
+        l = l + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc + jnp.dot(p.astype(v_ref.dtype), v_ref[0, cols, :],
+                            preferred_element_type=jnp.float32)
+    if state:
+        m_ref[rows, :], l_ref[rows, :], acc_ref[rows, :] = m, l, acc
+    else:
+        write(rows, m, l, acc)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
                 scale, seq_k, plan):
     i, j = _block_ids(plan, 1, 2)
     pad = plan.sk_p != seq_k
     if state:      # several blocks a head: a row's state rests here between
         m_ref, l_ref, acc_ref = state
-
-    def write(rows, m, l, acc):
-        l = jnp.maximum(l, 1e-30)
-        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
-        lse_ref[0, rows, :] = m + jnp.log(l)
+    write = _writer(o_ref, lse_ref)
 
     def block(delta):
         for a in range(plan.block_q // plan.sub_q):
@@ -310,27 +400,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
                 if visible is not None:
                     s = jnp.where(visible, s, _NEG_INF)
                 scores.append((s, visible, cols))
-            m = functools.reduce(jnp.maximum, [
-                jnp.max(s, axis=1, keepdims=True) for s, _, _ in scores])
-            l, acc = 0.0, 0.0
-            if state:
-                m_old = m_ref[rows, :]                     # [SQ, 1]
-                m = jnp.maximum(m_old, m)
-                corr = jnp.exp(m_old - m)
-                l, acc = l_ref[rows, :] * corr, acc_ref[rows, :] * corr
-            for s, visible, cols in scores:
-                p = jnp.exp(s - m)
-                if visible is not None:
-                    # fully-masked rows keep m == _NEG_INF; exp(s-m)=1
-                    # there, so re-mask
-                    p = jnp.where(visible, p, 0.0)
-                l = l + jnp.sum(p, axis=1, keepdims=True)
-                acc = acc + jnp.dot(p.astype(v_ref.dtype), v_ref[0, cols, :],
-                                    preferred_element_type=jnp.float32)
-            if state:
-                m_ref[rows, :], l_ref[rows, :], acc_ref[rows, :] = m, l, acc
-            else:
-                write(rows, m, l, acc)
+            _fold_rows(rows, scores, v_ref, state, write)
 
     if not state:
         # one block a head: every row ends here (key 0 is visible to all)
@@ -390,6 +460,110 @@ def _fwd_call(q, k, v, scale, seq_k, plan):
         interpret=_interpret(),
         compiler_params=_PARAMS,
         name="flash_attention_fwd",
+    )(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# windowed forward: grid (batch*heads, q_blocks, band), the band innermost
+# ---------------------------------------------------------------------------
+
+
+def _band_visible(q0, k0, shape, window):
+    """[rows, keys] bool: the key at or before the query and less than
+    ``window`` positions behind it."""
+    qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return jnp.logical_and(kpos <= qpos, kpos > qpos - window)
+
+
+def _fwd_window_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state,
+                       scale, plan):
+    """Step ``t`` of query block ``i`` visits key block ``i - band + 1 +
+    t`` (none where that is below 0): its offset ``(band - 1 - t) x
+    block`` is static a step, so every span inside is too.  Padded keys
+    lie past every real query and are never visible to one; padded
+    queries are sliced off by the caller."""
+    write = _writer(o_ref, lse_ref)
+
+    def block(delta):
+        for a in range(plan.block_q // plan.sub_q):
+            lo, edge, full, live = plan.window_span(delta, a)
+            if live <= lo:
+                continue
+            rows = pl.ds(a * plan.sub_q, plan.sub_q)
+            q = q_ref[0, rows, :]
+            pieces = ([(x, 1, True) for x in range(lo, edge)]
+                      + ([(edge, full - edge, False)] if full > edge else [])
+                      + [(x, 1, True) for x in range(full, live)])
+            scores = []
+            for x, count, crossed in pieces:
+                cols = pl.ds(x * plan.sub_k, count * plan.sub_k)
+                s = _scores(q, k_ref[0, cols, :], scale)   # [SQ, keys] f32
+                visible = None
+                if crossed:     # positions from the block's first key
+                    visible = _band_visible(delta + a * plan.sub_q,
+                                            x * plan.sub_k, s.shape,
+                                            plan.window)
+                    s = jnp.where(visible, s, _NEG_INF)
+                scores.append((s, visible, cols))
+            _fold_rows(rows, scores, v_ref, state, write)
+
+    if not state:
+        # a band of one block: the diagonal's, where every row ends
+        return block(0)
+    m_ref, l_ref, acc_ref = state
+    i, t = pl.program_id(1), pl.program_id(2)
+
+    def init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    _when(t == 0, init)
+    for t0 in range(plan.band):
+        behind = plan.band - 1 - t0
+        cond = t == t0
+        if behind:
+            cond = jnp.logical_and(cond, i >= behind)
+        _when(cond, functools.partial(block, behind * plan.block_k))
+    _when(t == plan.band - 1,
+          lambda: write(slice(None), m_ref[:], l_ref[:], acc_ref[:]))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "plan"))
+def _fwd_window_call(q, k, v, scale, plan):
+    bh, sq, d = q.shape
+    block, band = plan.block_q, plan.band
+
+    def kv_block(b, i, t):
+        # the first steps of the first query blocks reach below key 0:
+        # they load block 0 and compute nothing
+        return b, jnp.maximum(i - (band - 1) + t, 0), 0
+
+    return pl.pallas_call(
+        functools.partial(_fwd_window_kernel, scale=scale, plan=plan),
+        grid=(bh, sq // block, band),
+        in_specs=[
+            pl.BlockSpec((1, block, d), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, block, d), kv_block),
+            pl.BlockSpec((1, block, d), kv_block),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block, d), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, block, 1), lambda b, i, t: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+        ],
+        scratch_shapes=[] if band == 1 else [
+            pltpu.VMEM((block, 1), jnp.float32),
+            pltpu.VMEM((block, 1), jnp.float32),
+            pltpu.VMEM((block, d), jnp.float32),
+        ],
+        interpret=_interpret(),
+        compiler_params=_PARAMS,
+        name="flash_window_fwd",
     )(q, k, v)
 
 
@@ -591,7 +765,7 @@ def _unfold_heads(x, b, h):
 def flash_attention(q, k, v, causal: bool = False,
                     scale: float | None = None,
                     block_q: int = _BLOCK_Q, block_k: int = _BLOCK_K,
-                    sub_tile: int | None = None):
+                    sub_tile: int | None = None, window: int | None = None):
     """Memory-efficient attention; drop-in for ``dense_attention``.
 
     Args:
@@ -603,6 +777,8 @@ def flash_attention(q, k, v, causal: bool = False,
         1024x1024 — see the module-top sizing note).
       sub_tile: side of the sub-tiles a causal block is walked in
         (``tile_plan``; default ``_SUB_TILE``).
+      window: sliding-window width (causal self-attention): query ``i``
+        sees keys ``i - window < j <= i``.  Forward only.
     Returns:
       [batch, seq_q, heads, head_dim] in q's dtype.
     """
@@ -610,7 +786,7 @@ def flash_attention(q, k, v, causal: bool = False,
     sk = k.shape[1]
     scale = (1.0 / d ** 0.5) if scale is None else float(scale)
     plan = tile_plan(sq, sk, block_q, block_k, causal, head_dim=d,
-                     sub_tile=sub_tile)
+                     sub_tile=sub_tile, window=window)
 
     qf, kf, vf = _fold_heads(q), _fold_heads(k), _fold_heads(v)
     # query padding: rows are sliced off below and receive zero cotangents
@@ -619,5 +795,8 @@ def flash_attention(q, k, v, causal: bool = False,
     kf = jnp.pad(kf, ((0, 0), (0, plan.sk_p - sk), (0, 0)))
     vf = jnp.pad(vf, ((0, 0), (0, plan.sk_p - sk), (0, 0)))
 
-    o = _flash(qf, kf, vf, scale, sk, plan)
+    if window is not None:
+        o, _ = _fwd_window_call(qf, kf, vf, scale, plan)
+    else:
+        o = _flash(qf, kf, vf, scale, sk, plan)
     return _unfold_heads(o[:, :sq], b, h)

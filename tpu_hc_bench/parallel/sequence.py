@@ -37,12 +37,14 @@ _NEG_INF = -1e30  # mask value: large-negative, not -inf (keeps exp() clean)
 
 def dense_attention(q, k, v, causal: bool = False, scale: float | None = None,
                     q_offset: int | jax.Array = 0,
-                    k_offset: int | jax.Array = 0):
+                    k_offset: int | jax.Array = 0,
+                    window: int | None = None):
     """Plain softmax attention — the single-device reference implementation.
 
     ``q``/``k``/``v``: [batch, seq, heads, head_dim].  ``q_offset``/
     ``k_offset`` are the global positions of the first query/key row (used
-    for causal masking of sequence shards).
+    for causal masking of sequence shards).  ``window`` (causal only): a
+    query at ``i`` sees keys ``i - window < j <= i``.
     """
     d = q.shape[-1]
     scale = (1.0 / d ** 0.5) if scale is None else scale
@@ -51,7 +53,10 @@ def dense_attention(q, k, v, causal: bool = False, scale: float | None = None,
     if causal:
         qpos = q_offset + jnp.arange(q.shape[1])
         kpos = k_offset + jnp.arange(k.shape[1])
-        s = jnp.where(qpos[:, None] >= kpos[None, :], s, _NEG_INF)
+        seen = qpos[:, None] >= kpos[None, :]
+        if window is not None:
+            seen = seen & (qpos[:, None] - kpos[None, :] < window)
+        s = jnp.where(seen, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 

@@ -226,18 +226,23 @@ class Holding:
     prefix_shared: int = 0
     # the recurrent-state slot (0 = none: the family keeps no state)
     slot: int = 0
+    # the window layers' ring: fixed pages from admit to finish (empty:
+    # the family has no window layers)
+    ring: list[int] = dataclasses.field(default_factory=list)
 
 
 class Grant(NamedTuple):
     """What admission bound for one request: its pages (shared prefix
     pages first), the decode table, the prefill's WRITE table, the
-    state slot (0: none) and how many leading pages are shared."""
+    state slot (0: none), how many leading pages are shared and the
+    window layers' ring (empty: none)."""
 
     pages: list[int]
     table: np.ndarray
     write_table: np.ndarray
     slot: int
     shared: int
+    ring: list[int] | tuple = ()
 
 
 class CacheManager:
@@ -252,7 +257,8 @@ class CacheManager:
     """
 
     def __init__(self, num_pages: int, page_size: int, table_width: int,
-                 *, state_slots: int = 0, kv_reserve: str = "worst",
+                 *, state_slots: int = 0, ring_pages: int = 0,
+                 ring_width: int = 0, kv_reserve: str = "worst",
                  growth_headroom: int = 0, prefix_cache: bool = False,
                  squeezed: Callable[[], int] | None = None):
         self.page_size = page_size
@@ -261,8 +267,14 @@ class CacheManager:
         self.growth_headroom = growth_headroom
         self.allocator = PageAllocator(num_pages)
         self.slots = SlotAllocator(state_slots) if state_slots else None
-        # the table handed to the programs: the pages, then the slot
-        self.table_cols = table_width + (1 if state_slots else 0)
+        # the window layers' ring pool: ``ring_width`` pages a resident,
+        # fixed from admit to finish (a window's pages are reused in
+        # place as it slides, so it never grows)
+        self.ring_width = ring_width
+        self.rings = PageAllocator(ring_pages) if ring_width else None
+        # the table handed to the programs: the pages, the ring, the slot
+        self.table_cols = (table_width + ring_width
+                           + (1 if state_slots else 0))
         self.ledger = KVLedger(page_size)
         # the trie lives per run: it holds references into THIS run's
         # allocator
@@ -328,7 +340,9 @@ class CacheManager:
         frees one) before ``"pool_starved"``."""
         if self.slots is not None and not self.slots.free_slots:
             return "slot_starved"
-        if self.free_now() < self._need_pages(feed):
+        if self.free_now() < self._need_pages(feed) or (
+                self.rings is not None
+                and self.rings.free_pages < self.ring_width):
             return "pool_starved"
         return None
 
@@ -359,6 +373,12 @@ class CacheManager:
         slot = 0
         table = np.pad(np.asarray(pages, np.int32),
                        (0, self.table_width - len(pages)))
+        ring: list[int] | tuple = ()
+        if self.rings is not None:
+            ring = self.rings.alloc(self.ring_width)
+            assert ring is not None, "admission checked the ring pool"
+            # the ring rides in the columns after the pages
+            table = np.append(table, np.asarray(ring, np.int32))
         if self.slots is not None:
             slot = self.slots.alloc()
             assert slot is not None, "admission checked the slots"
@@ -377,7 +397,7 @@ class CacheManager:
             write_table = np.where(
                 np.arange(self.table_cols) < len(shared),
                 0, table).astype(np.int32)
-        return Grant(pages, table, write_table, slot, len(shared))
+        return Grant(pages, table, write_table, slot, len(shared), ring)
 
     def seed(self, feed, pages: list[int], plen: int) -> None:
         """After a finite, non-quarantined prefill: seed the trie with
@@ -436,6 +456,9 @@ class CacheManager:
         back.  Returns its final written-page count."""
         final = self.ledger.retire(len(fl.pages), fl.length)
         self.allocator.free(fl.pages)
+        if fl.ring:
+            self.rings.free(fl.ring)
+            fl.ring = []
         if fl.slot:
             self.slots.free(fl.slot)
             fl.slot = 0

@@ -40,7 +40,7 @@ from tpu_hc_bench.serve import cache as cache_mod
 from tpu_hc_bench.serve import faults as faults_mod
 from tpu_hc_bench.serve import slo as slo_mod
 from tpu_hc_bench.serve.arrivals import Request
-from tpu_hc_bench.serve.decode import pages_held
+from tpu_hc_bench.serve.decode import pages_held, window_pages_held
 from tpu_hc_bench.serve.engine import pick_bucket
 
 # serve records land every this-many engine steps — frequent enough for
@@ -179,7 +179,8 @@ class ServeLoop:
         self.t0 = 0.0
         self.cache = (cache_mod.CacheManager(
             eng.num_pages, eng.page_size, eng.table_width,
-            state_slots=eng.state_slots, kv_reserve=policy.kv_reserve,
+            state_slots=eng.state_slots, ring_pages=eng.ring_pages,
+            ring_width=eng.ring_width, kv_reserve=policy.kv_reserve,
             growth_headroom=eng.cfg.kv_growth_headroom,
             prefix_cache=policy.prefix_cache == "on",
             squeezed=((lambda: faults.squeezed_pages(self.now()))
@@ -193,7 +194,8 @@ class ServeLoop:
                 eng.family.picks_per_token)) if eng.decode_mode else (), 0)
         self.state_slot_steps = [0, 0]      # in use, slots x steps
         # the gather arm's packed cache read: pages its decode steps
-        # visited (whole chunks), beside rows x table width
+        # visited (whole chunks) in every layer that reads pages, beside
+        # rows x table width in each such layer
         self.kv_read = [0, 0]
         # queue-wait cause split (round 22): rid -> accumulated seconds
         # blocked on [pool_starved, batch_full] while sitting in queue
@@ -750,7 +752,7 @@ class ServeLoop:
             t_last=(c["t_last"] if c else None),
             preempts=(c["preempts"] if c else 0),
             produced_res=(0 if c else 1),
-            prefix_shared=grant.shared, slot=grant.slot)
+            prefix_shared=grant.shared, slot=grant.slot, ring=grant.ring)
         if self.policy.guard:
             row = np.asarray(logits)
             if self.faults is not None \
@@ -930,11 +932,19 @@ class ServeLoop:
         self.productive_s += dt * (rows / b)
         self.bucket_acct("decode", b, rows, dt)
         if eng.decode_chunk:
+            full, win = (len(eng.family.kv_layers),
+                         len(eng.family.window_layers))
             chunk = eng.decode_chunk[b]
             held = int(pages_held(lengths, mask, eng.page_size,
                                   eng.table_width).sum())
-            self.kv_read[0] += -(-held // chunk) * chunk
-            self.kv_read[1] += b * eng.table_width
+            self.kv_read[0] += full * (-(-held // chunk) * chunk)
+            if win:
+                # a window layer reads the pages its window reaches
+                chunk = eng.window_chunk[b]
+                held = int(window_pages_held(lengths, mask, eng.page_size,
+                                             eng.family.window).sum())
+                self.kv_read[0] += win * (-(-held // chunk) * chunk)
+            self.kv_read[1] += (full + win) * b * eng.table_width
         cache.charge(dt)
         next_toks = np.asarray(next_toks)
         family, counters = eng.family, self.counters
