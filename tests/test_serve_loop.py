@@ -37,7 +37,7 @@ _SUMMARY_ARMS = [
     "kv_reserve", "prefix_cache", "decode_attention", "quant",
     "decode_block_pages", "aot_decode_temp_bytes", "kv_pool_temp_ratio",
     "state_pool_bytes", "kv_read", "state_slots", "state_slot_steps",
-    "ssd_kernel_calls"]
+    "ssd_kernel_calls", "kda_kernel_calls"]
 _SUMMARY_TAIL = [
     "post_warmup_compiles", "attribution", "tail_queue_wait_frac",
     "tail_decode_stall_frac", "bucket_util", "loop_phases", "loop_wall_s",

@@ -381,6 +381,11 @@ def test_engine_serves_the_family_and_counts_its_pools(engine, requests):
         f"{k}@{n}" for k, n in engine.compiled if k != "page_copy"}
     assert {"kda", "gqa", "moe", "head"} == set(
         summary["op_parts"][f"decode@{engine.cap}"].values())
+    # every decode program is counted; interpreted here, the kernel is no
+    # custom call (what the chip's compiler makes of it:
+    # tests/test_tpu_compile.py)
+    assert summary["kda_kernel_calls"] == {
+        f"decode@{b}": 0 for b in engine.batch_buckets}
 
 
 def test_preemption_reprefills_into_a_clean_slot(engine, requests):

@@ -54,7 +54,8 @@ def mosaic(monkeypatch):
     import jax
     from jax.experimental.compilation_cache import compilation_cache
 
-    for mod in ("flash_attention", "paged_attention", "ssd_decode"):
+    for mod in ("flash_attention", "paged_attention", "ssd_decode",
+                "kda_decode"):
         monkeypatch.setattr(
             importlib.import_module(f"tpu_hc_bench.ops.{mod}"),
             "_interpret", lambda: False)
@@ -256,27 +257,42 @@ def _hybrid_program(one_chip, kind: str, program: str):
     return _HYBRID[kind, program]
 
 
+# each recurrent kind's decode kernel: its module under ``tpu_hc_bench.ops``
+# and the operand that is the state leaf (after the layer, the slots and
+# the rows' vectors), handed back as result 0
+_STATE_KERNELS = {"solar_open2": ("kda_decode", 7),
+                  "granite4h": ("ssd_decode", 6)}
+
+
+def _kernel_call_lines(text: str, name: str) -> list[str]:
+    return [ln for ln in text.splitlines()
+            if re.match(rf"\s*%{name}(\.\d+)? = ", ln)]
+
+
 @pytest.mark.parametrize("kind", ["solar_open2", "granite4h"])
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
                                                           program, kind):
     """One period of each hybrid family (a softmax layer over pages, three
-    recurrent layers over state slots: the gated delta rule's ``S``, or
-    Mamba-2's ``h`` of 64 heads x 64 x 128) at the published head and
-    state sizes, a narrow hidden size and 16 rows: the programs the
-    chip's compiler builds update the page pool AND the recurrent state
-    where they rest (no new array of either's shape; the small
-    convolution tails are a row scatter, held to the bound on
+    recurrent layers over state slots: the gated delta rule's ``S`` of 8
+    heads x 128 x 128, or Mamba-2's ``h`` of 64 heads x 64 x 128) at the
+    published head and state sizes, a narrow hidden size and 16 rows: the
+    programs the chip's compiler builds update the page pool AND the
+    recurrent state where they rest (no new array of either's shape; the
+    small convolution tails are a row scatter, held to the bound on
     temporaries), and their temporaries stay under the largest leaf's
-    bytes (the engine's ``kv_pool_temp_ratio``).  Mamba-2's decode steps
-    the state through ``ops.ssd_decode``, one call a layer, its result
-    the leaf it was handed (aliased), under the ``ssm`` part; no other
-    program calls it, and no array of a layer's slice of ``h`` is made."""
+    bytes (the engine's ``kv_pool_temp_ratio``).  Each decode program
+    steps the state through its kind's kernel (``ops.kda_decode`` or
+    ``ops.ssd_decode``), one call a layer, its result the leaf it was
+    handed (aliased), under the kind's part; no other program calls
+    either kernel, and no array of a layer's slice of the state is
+    made."""
+    import importlib
+
     import jax
     import numpy as np
 
     from tpu_hc_bench.analysis import hlo
-    from tpu_hc_bench.ops import ssd_decode
     from tpu_hc_bench.serve import decode
 
     _, parts_named = _hybrid_model(kind)
@@ -285,7 +301,7 @@ def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
     text = compiled.as_text()
     leaves = jax.tree.leaves(kv)
     state = kv["state"][family.recurrent.leaf]
-    kernel = (kind, program) == ("granite4h", "decode")
+    kernel = program == "decode"
     found = hlo.new_buffers_of_shape(
         text,
         [hlo.shape_text(kv["pages"][0].shape, "bf16"),
@@ -312,25 +328,23 @@ def test_hybrid_cache_tree_holds_no_second_copy_of_a_leaf(one_chip, mosaic,
             kv["pages"][0], rows, width) == 512 < rows * width
     parts = decode.part_of_ops(text)
     assert set(parts.values()) == parts_named
-    calls = [ln for ln in text.splitlines()
-             if re.match(rf"\s*%{ssd_decode.NAME}(\.\d+)? = ", ln)]
-    assert ssd_decode.kernel_calls(text) == len(calls) == (
-        len(family.state_layers) if kernel else 0)
-    for ln in calls:
-        # the leaf is operand 6 (after the layer, the slots and the
-        # rows' four vectors) and comes back as result 0
-        assert "output_to_operand_aliasing={{0}: (6, {})}" in ln
-        key = ln.split(" = ")[0].strip().lstrip("%") + ":" + hlo.shape_text(
-            state.shape, "f32")
-        assert parts[key] == "ssm"
+    for other, (name, leaf_operand) in _STATE_KERNELS.items():
+        ops = importlib.import_module(f"tpu_hc_bench.ops.{name}")
+        calls = _kernel_call_lines(text, ops.NAME)
+        assert ops.kernel_calls(text) == len(calls) == (
+            len(family.state_layers) if kernel and other == kind else 0)
+        for ln in calls:
+            assert (f"output_to_operand_aliasing={{{{0}}: ({leaf_operand}, "
+                    "{})}") in ln
+            key = ln.split(" = ")[0].strip().lstrip("%") + ":" + (
+                hlo.shape_text(state.shape, "f32"))
+            assert parts[key] == family.recurrent.scope
 
 
-# the instruction lines (metadata left out) of Solar's programs above, as
-# they compiled before the Mamba-2 decode kernel: the kernel's seam
-# (``_Recurrent.step`` takes the whole leaf) moves no instruction
+# the instruction lines (metadata left out) of Solar's prefill program
+# above, as it compiled before the recurrent kinds' decode kernels: their
+# seam (``_Recurrent.step`` takes the whole leaf) moves no instruction
 _SOLAR_BEFORE = {
-    "decode":
-        "23dd16ba1fe8153e794fc54f85c684aaa6f246fcf07804cb73db7377a548c812",
     "prefill":
         "d93163f8441b51c589663ed80fc05c789de3c90c9e8dbc213f7734e460d485a8",
 }
@@ -339,13 +353,34 @@ _SOLAR_BEFORE = {
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_solar_programs_are_as_before_instruction_for_instruction(
         one_chip, mosaic, program):
+    """Solar's prefill program is the same, instruction for instruction.
+    Its decode program is changed by design (``ops.kda_decode``) and is
+    held to what the change made of it: three kernel calls, each handing
+    the state operand back as its result, and no XLA fusion left that
+    writes the state (a ``dynamic-update-slice`` of the leaf's shape, the
+    in-place update each KDA layer's step was before the kernel)."""
     import hashlib
 
-    compiled, _, _ = _hybrid_program(one_chip, "solar_open2", program)
-    lines = [re.sub(r", metadata=\{[^}]*\}", "", ln)
-             for ln in compiled.as_text().splitlines() if " = " in ln]
-    assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == (
-        _SOLAR_BEFORE[program])
+    from tpu_hc_bench.analysis import hlo
+    from tpu_hc_bench.ops import kda_decode
+
+    compiled, kv, family = _hybrid_program(one_chip, "solar_open2", program)
+    text = compiled.as_text()
+    if program == "prefill":
+        lines = [re.sub(r", metadata=\{[^}]*\}", "", ln)
+                 for ln in text.splitlines() if " = " in ln]
+        assert hashlib.sha256("\n".join(lines).encode()).hexdigest() == (
+            _SOLAR_BEFORE[program])
+        return
+    calls = _kernel_call_lines(text, kda_decode.NAME)
+    assert len(calls) == len(family.state_layers) == 3
+    assert all("output_to_operand_aliasing={{0}: (7, {})}" in ln
+               for ln in calls)
+    shape = hlo.shape_text(kv["state"]["S"].shape, "f32")
+    writes = [ln.split(" = ")[0].strip() for ln in text.splitlines()
+              if " = " in ln and "dynamic-update-slice" in ln
+              and ln.split(" = ")[1].startswith(shape)]
+    assert not writes, writes
 
 
 @pytest.mark.parametrize("fence", [True, False])
@@ -405,6 +440,58 @@ def test_granite_decode_recomputes_no_state_write(one_chip, mosaic, fence):
              and hlo_shape in line.split(" = ")[1][:60]]
     assert not remat, remat
     assert ssd_decode.kernel_calls(text) == 36
+
+
+@pytest.mark.parametrize("slots", [129, 641])
+def test_solar_decode_recomputes_no_state_write(one_chip, mosaic, slots):
+    """Solar's decode program at the cell's 128 rows and contexts (one
+    period at the published KDA widths, 64 heads x 128 x 128, a narrow
+    hidden size, nothing allocated), over the cell's 129 state slots
+    (1.62 GB of float32 ``S``) and over 641 (8.06 GB, a state that
+    fills most of the chip, where Granite's compiled program once
+    rematerialized a layer's in-place write from the donated buffer it
+    had already updated: D15 of ROADMAP.md): each KDA layer steps the
+    state through one call of ``ops.kda_decode`` (3: what the engine's
+    ``kda_kernel_calls`` reads of the cell's programs on the chip), and
+    no instruction of the state's shape is recomputed."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_hc_bench.models import solar_open2
+    from tpu_hc_bench.ops import kda_decode
+    from tpu_hc_bench.serve import decode
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = solar_open2.SolarOpen2LM(
+        vocab_size=2048, hidden=512, heads=16, kv_heads=8, n_routed=16,
+        experts_held=(0, 2), top_k=4, expert_ffn=256, shared_ffn=256,
+        dtype=jnp.bfloat16)
+    family = decode.build_family(model)
+    assert not family.recurrent.fence
+    params = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32),
+            train=False))["params"])
+    page, width, rows = 16, 144, 128
+    kv = jax.tree.map(
+        lambda x: sd(x.shape, x.dtype),
+        jax.eval_shape(lambda: decode.init_kv_state(
+            family, 1 + rows * width, page, jnp.bfloat16, slots=slots)))
+    state = kv["state"]["S"]
+    assert state.shape == (3, slots, 64, 128, 128)
+    hlo_shape = "f32[" + ",".join(map(str, state.shape)) + "]"
+    text = jax.jit(decode.build_decode_fn(family, page, width),
+                   donate_argnums=(1,)).lower(
+        params, kv, sd((rows,), jnp.int32), sd((rows, width + 1), jnp.int32),
+        sd((rows,), jnp.int32), sd((rows,), jnp.bool_)).compile().as_text()
+    remat = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if " = " in line and ".remat" in line.split(" = ")[0]
+             and hlo_shape in line.split(" = ")[1][:60]]
+    assert not remat, remat
+    assert kda_decode.kernel_calls(text) == 3
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])

@@ -23,6 +23,11 @@ shows a win.  The record (BASELINE.md):
   over a bucket's rows, each row's state block read from its slot,
   updated, read out and written back (the leaf aliased); the
   ``mamba2`` kind's decode step in ``serve.decode`` (no flag).
+- ``kda_decode_step`` — the gated delta-rule ("KDA") decode step of
+  one layer over a bucket's rows, the same frame as ``ssd_decode_step``
+  (each row's block read from its slot, updated, read out and written
+  back, the leaf aliased); the ``kda`` kind's decode step in
+  ``serve.decode`` (no flag).
 - ``fused_residual_norm`` — round 18: fused residual-add + Layer/RMS
   norm used by both paged decode families (one VMEM round-trip where
   the unfused form pays three HBM trips per layer).
@@ -31,6 +36,7 @@ shows a win.  The record (BASELINE.md):
 from tpu_hc_bench.ops.flash_attention import flash_attention  # noqa: F401
 from tpu_hc_bench.ops.fused_conv import fused_bn_relu_conv  # noqa: F401
 from tpu_hc_bench.ops.fused_residual_ln import fused_residual_norm  # noqa: F401
+from tpu_hc_bench.ops.kda_decode import kda_decode_step  # noqa: F401
 from tpu_hc_bench.ops.paged_attention import paged_decode_attention  # noqa: F401
 from tpu_hc_bench.ops.pool_bwd import max_pool as pallas_max_pool  # noqa: F401
 from tpu_hc_bench.ops.ssd_decode import ssd_decode_step  # noqa: F401

@@ -100,14 +100,15 @@ trash page.  Prefill runs the chunked form of the recurrence over the
 padded bucket with the padding made inert and writes the state from
 zero whatever the slot held; decode steps the state in place on the
 donated buffer (a slot no active row names keeps its state: decay 1 and
-no input) — ``kda`` every slot of a layer at once, ``mamba2`` the rows'
-slots alone through ``ops.ssd_decode_step``.  ``jax.named_scope`` names
-the parts (``PARTS``: the recurrent kind's ``kda`` or ``ssm``, ``gqa``,
-the window layers' ``swa``, the FFN's ``moe`` or ``mlp``, ``head``) in
-both programs, and ``part_of_ops`` maps a compiled program's operations
-to them.  A family that asks for it (``flash_prefill``) attends in
-prefill through ``ops.flash_attention``: causal on full layers, over the
-window's band on window layers.
+no input), the rows' slots alone — ``kda`` through
+``ops.kda_decode_step``, ``mamba2`` through ``ops.ssd_decode_step``.
+``jax.named_scope`` names the parts (``PARTS``: the recurrent kind's
+``kda`` or ``ssm``, ``gqa``, the window layers' ``swa``, the FFN's
+``moe`` or ``mlp``, ``head``) in both programs, and ``part_of_ops``
+maps a compiled program's operations to them.  A family that asks for
+it (``flash_prefill``) attends in prefill through
+``ops.flash_attention``: causal on full layers, over the window's band
+on window layers.
 
 Supported families: ``GPTLM`` (gpt2*, moe*: learned positions, dense
 or MoE FFN), ``LlamaLM`` (llama*: RoPE, GQA, SwiGLU), ``SolarOpen2LM``
@@ -1270,8 +1271,8 @@ class _Recurrent:
     """A recurrent mixer kind, as ``init_kv_state`` and the hybrid
     programs see it: one state leaf a slot holds beside the short
     convolution's tail, a prefill from a zero state, and one step for
-    every slot at once.  The programs do the cache's reads and writes;
-    a kind computes."""
+    a bucket's rows, each on its slot.  The programs do the cache's reads
+    and writes; a kind computes."""
 
     scope: str          # its named part in the programs (``PARTS``)
     leaf: str           # its state leaf's key in the cache tree
@@ -1292,17 +1293,13 @@ class _Recurrent:
                             # ``_build_hybrid_decode_fn``)
 
 
-def _to_slots(rows, slots, n_slots: int):
-    """``rows [b, ...]`` scattered to slot order ``[n_slots, ...]``,
-    zeros where no row's slot is (the inactive rows name slot 0)."""
-    return jnp.zeros((n_slots,) + rows.shape[1:],
-                     rows.dtype).at[slots].set(rows)
-
-
 def _kda(m) -> _Recurrent:
     """The gated delta rule (``models/solar_open2``): inert where ``g``
-    = 0 and ``beta`` = 0."""
+    = 0 and ``beta`` = 0.  Its decode step is ``ops.kda_decode_step``:
+    each row's state read once from its slot, updated, read out and
+    written back where it rests."""
     from tpu_hc_bench.models import solar_open2 as so
+    from tpu_hc_bench.ops.kda_decode import kda_decode_step
 
     n = m.kda_heads * m.kda_head_dim
     shape = (m.kda_heads, m.kda_head_dim, m.kda_head_dim)
@@ -1318,16 +1315,14 @@ def _kda(m) -> _Recurrent:
         return so.kda_output(p, o[None], u, m.eps), s_end, padded
 
     def step(p, u, tails, leaf, li, slots, active):
-        S = jax.lax.dynamic_index_in_dim(leaf, li, 0, False)
         q, k, v, g, beta, padded = so.kda_inputs(p, u, tails, m.kda_heads,
                                                  m.neg_eigval)
         g = jnp.where(active[:, None, None], g[:, 0], 0.0)
         beta = jnp.where(active[:, None], beta[:, 0], 0.0)
-        at = lambda rows: _to_slots(rows, slots, S.shape[0])  # noqa: E731
-        S, o = so.kda_step(S, at(q[:, 0]), at(k[:, 0]), at(v[:, 0]), at(g),
-                           at(beta))
-        out = so.kda_output(p, o[slots][:, None], u, m.eps)
-        leaf = jax.lax.dynamic_update_index_in_dim(leaf, S, li, 0)
+        # ``kda_step``'s operands, row by row: the decay, then k, q, v
+        leaf, o = kda_decode_step(leaf, li, slots, jnp.exp(g), k[:, 0],
+                                  q[:, 0], v[:, 0], beta)
+        out = so.kda_output(p, o[:, None], u, m.eps)
         return out, leaf, padded
 
     return _Recurrent("kda", "S", shape, m.conv_kernel, 3 * n, prefill, step)

@@ -1218,6 +1218,7 @@ def summarize(loop: ServeLoop, post_warmup_compiles: int) -> dict:
         "state_slots": loop.state_slot_steps[0],
         "state_slot_steps": loop.state_slot_steps[1],
         "ssd_kernel_calls": eng.ssd_kernel_calls,
+        "kda_kernel_calls": eng.kda_kernel_calls,
         **loop.counters,
         "post_warmup_compiles": post_warmup_compiles,
         # round 20 (obs.requests): the tail-attribution fold, its
