@@ -335,13 +335,6 @@ def test_compile_cache_default_is_the_checkout(tmp_path, monkeypatch,
     assert not (tmp_path / "run").exists()
 
 
-def test_cache_entry_count(tmp_path):
-    (tmp_path / "sub").mkdir()
-    (tmp_path / "a").write_text("x")
-    (tmp_path / "sub" / "b").write_text("y")
-    assert compile_cache.entry_count(str(tmp_path)) == 2
-
-
 def test_update_manifest_merges(tmp_path):
     w = obs_metrics.MetricsWriter(str(tmp_path), {"schema": 1, "model": "t"},
                                   primary=True)

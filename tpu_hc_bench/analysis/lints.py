@@ -860,7 +860,8 @@ class _FileLinter:
         example="`timeline.span(\"decode\")` inside the AOT'd decode fn")
     def _check_span_in_jit(self, ctx: ast.AST):
         """**span-in-compiled-fn** (error): an ``obs.timeline`` recorder
-        call (``span``/``record_span``/``instant``/``transition``)
+        call (``span``/``setup``/``record_span``/``instant``/
+        ``transition``)
         inside a traced function.  The recorder reads the HOST monotonic
         clock; under trace that read happens once, at trace time, so the
         compiled program carries a frozen timestamp — the span lies
@@ -878,7 +879,7 @@ class _FileLinter:
                 or (isinstance(node.func, ast.Name)
                     and node.func.id in self._timeline_imported_names))
             if not (base in self._SPAN_CALLEES
-                    or (base == "span" and timeline_owned)):
+                    or (base in ("span", "setup") and timeline_owned)):
                 continue
             if base not in ("record_span", "dump_timeline") \
                     and not timeline_owned:
@@ -894,7 +895,8 @@ class _FileLinter:
 
     # -- span-name-registry --------------------------------------------
 
-    _SPAN_NAME_CALLEES = {"record_span", "instant", "span", "enter"}
+    _SPAN_NAME_CALLEES = {"record_span", "instant", "span", "enter",
+                          "setup"}
 
     @register_pass(
         SPAN_REGISTRY, "warning", "file",
@@ -905,7 +907,8 @@ class _FileLinter:
                 "appears in any timeline")
     def _check_span_name_registry(self):
         """**span-name-registry** (warning): a literal span name passed
-        to ``timeline.span``/``record_span``/``instant``, or as the
+        to ``timeline.span``/``setup``/``record_span``/``instant`` (a
+        set-up span as ``setup.<name>``), or as the
         phase or ``parent=`` of a ``phases.enter`` (``timeline.Phases``),
         that is not in ``obs.timeline.KNOWN_SPANS``.
 
@@ -940,11 +943,13 @@ class _FileLinter:
                             # is not the flight recorder's
             # variable span names are the caller's contract, not a typo
             # class: only literals are checked
+            # a set-up span's name is registered as ``setup.<name>``
+            prefix = "setup." if base == "setup" else ""
             for arg in node.args[:1] + [k.value for k in node.keywords
                                         if k.arg == "parent"]:
                 if not (isinstance(arg, ast.Constant)
                         and isinstance(arg.value, str)) \
-                        or arg.value in KNOWN_SPANS:
+                        or prefix + arg.value in KNOWN_SPANS:
                     continue
                 self._emit(
                     SPAN_REGISTRY, node,

@@ -45,6 +45,13 @@ with none open the annotation is an atomic load.  ``--flight_recorder
 off`` silences both sinks.  ``record_span`` stays ring-only, for
 intervals that are not a stretch of the calling thread's time.
 
+Set-up has an account of its own (``setup()``, ``setup_account()``): a
+``setup.<name>`` span on both sinks that also folds into a process-wide
+account kept whatever the switch says, and JAX's own trace, lowering and
+backend-compile events (``utils.compile_cache`` keeps the one listener)
+charged to the innermost set-up span open on the raising thread, by
+program, each backend compile with what the persistent cache did.
+
 Recorder calls are host-side by contract: the ``span-in-compiled-fn``
 analysis lint rejects any recorder call inside traced code (it would
 bake one constant timestamp into the compiled program and recompile or
@@ -107,6 +114,12 @@ KNOWN_SPANS = frozenset((
     "shed", "preempt", "requeue", "quarantine", "drain",
     # checkpoint
     "ckpt_snapshot", "ckpt_write", "ckpt_restore",
+    # set-up (``setup()``; the ring and a trace name them ``setup.<name>``):
+    # the serve engine's model and weights, cache tree, one AOT program
+    # each, and the reading of the compiled programs; the train driver's
+    # ``init`` and ``compile`` goodput phases and its synchronous probe
+    "setup.init", "setup.pool", "setup.aot", "setup.inspect",
+    "setup.warmup", "setup.probe",
 )) | _PHASE_LANE_NAMES
 
 
@@ -440,8 +453,12 @@ def instant(name: str, step: int | None = None, **meta) -> None:
 
 
 def transition(phase: str, step: int | None = None) -> None:
+    """A goodput transition: the coarse lane's span, and the set-up
+    account's ``setup.init`` / ``setup.warmup`` for ``init`` /
+    ``compile``."""
     try:
         _RECORDER.transition(phase, step=step)
+        _ACCOUNT.transition(phase)
     except Exception:
         pass
 
@@ -459,6 +476,201 @@ def flush() -> int:
 
 def detach() -> None:
     _RECORDER.detach()
+
+
+# ---------------------------------------------------------------------
+# set-up account: the process's set-up from inside, by phase and program
+
+#: the goodput phases that are set-up, and the set-up span each opens
+_SETUP_OF_PHASE = {"init": "init", "compile": "warmup"}
+#: what one backend compile came to, in the order in which a key that
+#: holds several reports them: written to the persistent cache
+#: (``compiled``), compiled and not kept (``uncached``: the cache is off,
+#: or its thresholds refuse the program), read from it (``loaded``).  A
+#: key that saw no backend compile took its executable from this
+#: process's memory (``reused``)
+OUTCOMES = ("compiled", "uncached", "loaded")
+#: the account's fields of a program, one per JAX compile event
+EVENT_FIELDS = ("trace_s", "lower_s", "compile_s")
+
+
+def _union_s(spans) -> float:
+    """Seconds covered by ``(t0, t1)`` intervals: nested or overlapping
+    ones count once."""
+    total, end = 0.0, float("-inf")
+    for t0, t1 in sorted(spans):
+        if t1 > end:
+            total += t1 - max(t0, end)
+            end = t1
+    return total
+
+
+class _Program:
+    __slots__ = ("spans", "outcomes", "pending")
+
+    def __init__(self):
+        self.spans = {k: [] for k in EVENT_FIELDS}
+        self.outcomes: list[str] = []
+        self.pending = None     # the cache's word on the open compile
+
+
+class SetupAccount:
+    """Where a process's set-up goes: each ``setup()`` span's seconds by
+    name, and JAX's own compile events (``utils.compile_cache`` forwards
+    them) charged to the innermost set-up span open on the thread that
+    raised them, by program: the span's ``program`` key, else its name.
+    An event with no set-up span open is not counted.  Kept whatever the
+    recorder's switch says, like ``Phases.fold``."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with self._lock:
+            self.fold: dict[str, list] = {}
+            self.programs: dict[str, _Program] = {}
+            self.tops: list[tuple[float, float]] = []
+            self.open: dict[int, list] = {}     # thread -> innermost last
+            self.phase_span = None      # opened by a goodput transition
+
+    def _push(self, span: "_SetupSpan") -> None:
+        with self._lock:
+            stack = self.open.setdefault(span.tid, [])
+            span.top = not stack
+            stack.append(span)
+            if span.program is not None:
+                self.programs.setdefault(span.key, _Program())
+
+    def _pop(self, span: "_SetupSpan") -> None:
+        with self._lock:
+            stack = self.open.get(span.tid, [])
+            if span not in stack:
+                return          # opened before a reset
+            stack.remove(span)
+            acc = self.fold.setdefault(span.name, [0, 0.0])
+            acc[0] += 1
+            acc[1] += span.t1 - span.t0
+            if span.top:
+                self.tops.append((span.t0, span.t1))
+
+    def _innermost(self) -> _Program | None:
+        stack = self.open.get(threading.get_ident())
+        if not stack:
+            return None
+        return self.programs.setdefault(stack[-1].key, _Program())
+
+    def charge(self, field: str, t0: float, t1: float) -> None:
+        """One compile event, for ``field`` of ``EVENT_FIELDS``; a
+        backend compile closes with the outcome the cache gave it."""
+        with self._lock:
+            prog = self._innermost()
+            if prog is None:
+                return
+            prog.spans[field].append((t0, t1))
+            if field == "compile_s":
+                prog.outcomes.append(prog.pending or "uncached")
+                prog.pending = None
+
+    def cache(self, outcome: str) -> None:
+        """The persistent cache's word inside a backend compile:
+        ``loaded`` (a hit) or ``compiled`` (an entry written)."""
+        with self._lock:
+            prog = self._innermost()
+            if prog is not None:
+                prog.pending = outcome
+
+    def transition(self, phase: str) -> None:
+        """A goodput transition: close the set-up span the last one
+        opened, and open the one this phase is, if it is set-up."""
+        span, self.phase_span = self.phase_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+        name = _SETUP_OF_PHASE.get(phase)
+        if name is not None:
+            self.phase_span = _SetupSpan(self, name, None).__enter__()
+
+    def account(self) -> dict:
+        with self._lock:
+            counts = dict.fromkeys(OUTCOMES, 0)
+            programs = {}
+            for key, p in self.programs.items():
+                rec = {k: _union_s(v) for k, v in p.spans.items()}
+                rec["outcome"] = next(
+                    (o for o in OUTCOMES if o in p.outcomes), "reused")
+                if len(p.outcomes) > 1:
+                    rec["compiles"] = len(p.outcomes)
+                for o in p.outcomes:
+                    counts[o] += 1
+                programs[key] = rec
+            return {"phases": {k: list(v) for k, v in self.fold.items()},
+                    "programs": programs, **counts,
+                    "covered_s": _union_s(self.tops)}
+
+
+class _SetupSpan:
+    """``with timeline.setup("aot", program="decode@16"): ...`` — a
+    stretch of set-up on the calling thread: ``hc:setup.<name>`` in any
+    open profiler and a ring span while the recorder is on, and a fold
+    entry of the account whatever its switch says."""
+
+    __slots__ = ("_acc", "name", "program", "key", "tid", "top", "_ann",
+                 "t0", "t1")
+
+    def __init__(self, acc: SetupAccount, name: str, program: str | None):
+        self._acc, self.name, self.program = acc, name, program
+        self.key = name if program is None else program
+
+    def __enter__(self):
+        self._ann = (_annotate("setup." + self.name)
+                     if _RECORDER.enabled else None)
+        self.tid = threading.get_ident()
+        self.t0 = time.monotonic()
+        self._acc._push(self)
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._acc._pop(self)
+        meta = {} if self.program is None else {"program": self.program}
+        _RECORDER.record("setup." + self.name, self.t0, self.t1, **meta)
+        return False
+
+
+_ACCOUNT = SetupAccount()
+
+
+def setup(name: str, program: str | None = None) -> _SetupSpan:
+    """A live set-up span (``KNOWN_SPANS`` holds ``setup.<name>``); the
+    compile events raised inside it are charged to ``program``, or to
+    ``name`` where none is given."""
+    return _SetupSpan(_ACCOUNT, name, program)
+
+
+def setup_account() -> dict:
+    """The process's set-up so far, as plain data: ``phases`` (name ->
+    ``[count, seconds]``), ``programs`` (key -> ``trace_s``, ``lower_s``,
+    ``compile_s`` — each the union of its events' intervals, so a jitted
+    call traced inside a program counts once — and ``outcome``), the
+    ``compiled`` / ``uncached`` / ``loaded`` counts of backend compiles,
+    and ``covered_s``, the union of the top-level set-up spans on the
+    monotonic clock."""
+    return _ACCOUNT.account()
+
+
+def reset_setup_account() -> None:
+    """Forget the account and its open spans (a test's clean slate)."""
+    _ACCOUNT.reset()
+
+
+def setup_event(field: str, t0: float, t1: float) -> None:
+    _ACCOUNT.charge(field, t0, t1)
+
+
+def setup_cache(outcome: str) -> None:
+    _ACCOUNT.cache(outcome)
 
 
 # ---------------------------------------------------------------------

@@ -19,9 +19,10 @@ summarizes it.  The lane's design constraints, in order:
    and the ``serve-bucket-recompile`` analysis lint guards the source
    so no jit/lower call site creeps into the traffic path.  Measured
    over each ``run`` as the programs THIS process compiled meanwhile
-   (``post_warmup_compiles``: ``compile_cache.process_compiles``; the
-   cache dir's entries, which other processes and other engines of this
-   process write too, stay the warm-up's hit/miss banner).
+   (``post_warmup_compiles``: ``compile_cache.process_compiles``).  The
+   warm-up's own account — lowering, compile, inspection, and each
+   program's outcome in the persistent cache — is the set-up account's
+   (``obs.timeline.setup``), which the hit/miss banner reads.
 2. **Continuous batching** (Orca): admission and retirement happen per
    decode step.  A newly arrived request is prefilled as soon as a
    slot and pages are free, joins the running batch at the next step,
@@ -150,10 +151,9 @@ class ServeEngine:
         # persistent compile cache first, so the warmup compiles hit or
         # populate it (the same resolver as the training lane)
         self.cache_dir = compile_cache.resolve(cfg.compile_cache)
-        self._count_cache = (
-            (lambda: compile_cache.entry_count(self.cache_dir))
-            if self.cache_dir else (lambda: 0))
-        entries_before = self._count_cache()
+        # the set-up account before this engine's set-up: the banner and
+        # the compile record read this engine's share of it
+        account_before = timeline_mod.setup_account()
 
         spec = get_model_spec(cfg.model)
         if spec.is_text and not spec.causal_lm:
@@ -185,29 +185,31 @@ class ServeEngine:
                 "shape paged-decode admission and have no meaning here")
 
         dtype = jnp.dtype(cfg.compute_dtype)
-        if self.decode_mode:
-            self.model, self.spec = create_model(
-                cfg.model, dtype=dtype, seq_len=self.max_ctx)
-        else:
-            self.model, self.spec = create_model(
-                cfg.model, num_classes=cfg.num_classes, dtype=dtype)
+        with timeline_mod.setup("init"):
+            if self.decode_mode:
+                self.model, self.spec = create_model(
+                    cfg.model, dtype=dtype, seq_len=self.max_ctx)
+            else:
+                self.model, self.spec = create_model(
+                    cfg.model, num_classes=cfg.num_classes, dtype=dtype)
 
-        rng = jax.random.PRNGKey(cfg.seed)
-        if self.decode_mode:
-            example = jnp.zeros((1, min(8, self.max_ctx)), jnp.int32)
-        else:
-            example = jnp.zeros((1,) + tuple(self.spec.input_shape),
-                                jnp.float32)
-        self.variables = self.model.init(rng, example, train=False)
-        if self.decode_mode and cfg.use_fp16 and not any(
-                x.dtype == dtype
-                for x in jax.tree_util.tree_leaves(self.variables)):
-            # --use_fp16 serves in bfloat16: the matrices are HELD so,
-            # vectors (norm scales, biases) stay float32.  A family that
-            # declares its own parameter types already holds them so
-            self.variables = jax.tree_util.tree_map(
-                lambda x: x.astype(dtype) if x.ndim >= 2 else x,
-                self.variables)
+            rng = jax.random.PRNGKey(cfg.seed)
+            if self.decode_mode:
+                example = jnp.zeros((1, min(8, self.max_ctx)), jnp.int32)
+            else:
+                example = jnp.zeros((1,) + tuple(self.spec.input_shape),
+                                    jnp.float32)
+            self.variables = self.model.init(rng, example, train=False)
+            if self.decode_mode and cfg.use_fp16 and not any(
+                    x.dtype == dtype
+                    for x in jax.tree_util.tree_leaves(self.variables)):
+                # --use_fp16 serves in bfloat16: the matrices are HELD
+                # so, vectors (norm scales, biases) stay float32.  A
+                # family that declares its own parameter types already
+                # holds them so
+                self.variables = jax.tree_util.tree_map(
+                    lambda x: x.astype(dtype) if x.ndim >= 2 else x,
+                    self.variables)
         self.params = self.variables.get("params", self.variables)
 
         # --- bucket ladders + KV pool geometry ---
@@ -277,16 +279,10 @@ class ServeEngine:
         else:
             self._warm_classify()
         warm_s = time.perf_counter() - t0
-        self.entries_after_warmup = self._count_cache()
         self.compile_record = {
             "buckets": len(self.compiled),
             "warmup_s": round(warm_s, 3),
             "cache_dir": self.cache_dir,
-            "entries_before": entries_before,
-            "entries_after_warmup": self.entries_after_warmup,
-            "new_entries": self.entries_after_warmup - entries_before,
-            "warm": (self.entries_after_warmup == entries_before
-                     and entries_before > 0),
             "decode_attention": (self.decode_attention
                                  if self.decode_mode else None),
             "quant": self.quant,
@@ -298,7 +294,9 @@ class ServeEngine:
                 and self.decode_attention == "paged" else None),
         }
         if self.decode_mode:
-            _, worst_decode = self.aot_memory_worst(kinds=("decode",))
+            with timeline_mod.setup("inspect"):
+                _, worst_decode = self.aot_memory_worst(kinds=("decode",))
+                ratio = self.kv_pool_temp_ratio()
             self.compile_record["aot_decode_temp_bytes"] = (
                 worst_decode.get("temp_bytes") if worst_decode else None)
             arm = (f"serve decode arm: attention={self.decode_attention} "
@@ -309,7 +307,6 @@ class ServeEngine:
             if tb is not None:
                 arm += (f"; worst decode bucket AOT temp "
                         f"{tb / 2**20:.1f} MiB")
-            ratio = self.kv_pool_temp_ratio()
             self.compile_record["kv_pool_temp_ratio"] = ratio
             if ratio is not None:
                 arm += f"; kv_pool_temp_ratio {ratio:.3f}"
@@ -324,18 +321,51 @@ class ServeEngine:
             f"serve device: {devs[0].device_kind} d{devs[0].id}"
             + (f" (1 of {len(devs)} local devices: the serve lane is "
                f"single-device)" if len(devs) > 1 else ""))
+        share = self._setup_share(account_before)
+        self.compile_record.update(share)
         kinds = collections.Counter(k for k, _ in self.compiled)
+        new = share["new_entries"]
         print_fn(
             "serve warmup: "
             + ", ".join(f"{n} {k} bucket(s)" for k, n in sorted(
                 kinds.items()))
-            + f" AOT-compiled in {warm_s:.1f}s"
-            + (f"; compile cache: "
-               f"{self.compile_record['new_entries']} new entr"
-               f"{'y' if self.compile_record['new_entries'] == 1 else 'ies'}"
-               f" ({'warm start' if self.compile_record['warm'] else 'cold/partial'})"
+            + f" AOT-compiled in {warm_s:.1f}s (lower "
+            f"{share['lower_s']:.1f}s, compile {share['compile_s']:.1f}s, "
+            f"inspect {share['inspect_s']:.1f}s)"
+            + (f"; compile cache: {new} new entr"
+               f"{'y' if new == 1 else 'ies'}, {share['loaded']} loaded"
+               f" ({'warm start' if share['warm'] else 'cold/partial'})"
                if self.cache_dir else ""))
-        self._check_hbm_budget(print_fn)
+        with timeline_mod.setup("inspect"):
+            self._check_hbm_budget(print_fn)
+
+    def _setup_share(self, before: dict) -> dict:
+        """This engine's share of the set-up account since ``before``:
+        the AOT programs' lowering (trace + lower) and compile seconds,
+        the inspection's seconds, and the backend compiles of the whole
+        construction by outcome — ``new_entries`` those written to the
+        persistent cache, ``warm`` some loaded and none written."""
+        after = timeline_mod.setup_account()
+        keys = {f"{kind}@{n}" for kind, n in self.compiled}
+
+        def total(acc, *fields):
+            return sum(p[f] for k, p in acc["programs"].items()
+                       if k in keys for f in fields)
+
+        def count(name):
+            return after[name] - before[name]
+
+        inspect = [a["phases"].get("inspect", [0, 0.0])[1]
+                   for a in (before, after)]
+        new = count("compiled")
+        return {"lower_s": round(total(after, "trace_s", "lower_s")
+                                 - total(before, "trace_s", "lower_s"), 3),
+                "compile_s": round(total(after, "compile_s")
+                                   - total(before, "compile_s"), 3),
+                "inspect_s": round(inspect[1] - inspect[0], 3),
+                "new_entries": new, "loaded": count("loaded"),
+                "uncached": count("uncached"),
+                "warm": new == 0 and count("loaded") > 0}
 
     def aot_memory_worst(self, kinds=None) -> tuple:
         """``(bucket key, memory_analysis dict)`` of the warmed
@@ -415,7 +445,9 @@ class ServeEngine:
                                     # avoid the per-compile warning
         jitted = jax.jit(fn, donate_argnums=donate)
         self.lower_count += 1
-        self.compiled[key] = obs_efficiency.aot_compile(jitted, *example)
+        with timeline_mod.setup("aot", program=f"{key[0]}@{key[1]}"):
+            self.compiled[key] = obs_efficiency.aot_compile(jitted,
+                                                            *example)
 
     def _warm_decode(self) -> None:
         from tpu_hc_bench.serve import decode as decode_mod
@@ -452,10 +484,11 @@ class ServeEngine:
         self.exec_params = (
             decode_mod.quantize_weights(self.family, self.params)
             if self.quant == "int8_w" else self.params)
-        self._kv = decode_mod.init_kv_state(
-            self.family, self.num_pages, self.page_size,
-            jnp.dtype(self.cfg.compute_dtype), quant=self.quant,
-            slots=self.state_slots, window_pages=self.ring_pages)
+        with timeline_mod.setup("pool"):
+            self._kv = decode_mod.init_kv_state(
+                self.family, self.num_pages, self.page_size,
+                jnp.dtype(self.cfg.compute_dtype), quant=self.quant,
+                slots=self.state_slots, window_pages=self.ring_pages)
         import jax
 
         leaves = jax.tree_util.tree_leaves(self._kv)
@@ -511,8 +544,10 @@ class ServeEngine:
             # operation of each program belongs to, keyed as a device
             # trace names it: the trace's events carry the instruction,
             # not the scope
-            for (kind, n), c in self.compiled.items():
-                if kind in ("prefill", "decode"):
+            with timeline_mod.setup("inspect"):
+                for (kind, n), c in self.compiled.items():
+                    if kind not in ("prefill", "decode"):
+                        continue
                     text = c.as_text()
                     self.op_parts[f"{kind}@{n}"] = decode_mod.part_of_ops(
                         text)
@@ -591,12 +626,10 @@ class ServeEngine:
         loop.play(drain_handler, step_timeout_s, on_watchdog)
         self._kv = loop.kv
         loop.close()
-        entries_final = self._count_cache()
         summary = loop_mod.summarize(
             loop, compile_cache.process_compiles() - compiles_before)
         writer.event("serve_summary", **summary)
         writer.event("serve_compile", **self.compile_record,
-                     entries_final=entries_final,
                      post_warmup_compiles=summary["post_warmup_compiles"])
         timeline_mod.detach()   # flush the serve spans, close the file
         # each program's operations by named part (what a device trace

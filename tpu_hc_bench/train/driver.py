@@ -781,11 +781,11 @@ def run_benchmark(
     hbm_budget = obs_memory.parse_hbm_budget(cfg.hbm_budget)
     # persistent compile cache (utils.compile_cache): activated before
     # anything lowers, so the warmup's compiles hit (warm start) or
-    # populate (cold start) it; hit/miss is measured over the warmup
-    # and recorded in the manifest
+    # populate (cold start) it; hit/miss is this run's share of the
+    # set-up account up to the end of the warmup, recorded in the
+    # manifest
     compile_cache_dir = compile_cache.resolve(cfg.compile_cache)
-    cache_entries_before = (compile_cache.entry_count(compile_cache_dir)
-                            if compile_cache_dir else 0)
+    account_before = timeline_mod.setup_account()
     layout = layout or discover_layout()
     # TP/EP claim the mesh's "model" axis, PP "pipe", SP "seq".  Round 2:
     # minor axes COMPOSE — DPxPPxTP and DPxSPxTP are the supported 3-D
@@ -1005,8 +1005,7 @@ def run_benchmark(
                  f"({flash_plan.sub_q}x{flash_plan.sub_k} in blocks of "
                  f"{flash_plan.block_q}x{flash_plan.block_k})")
     if compile_cache_dir:
-        print_fn(f"compile cache: {compile_cache_dir} "
-                 f"({cache_entries_before} entries at start)")
+        print_fn(f"compile cache: {compile_cache_dir}")
     for line in hw.ici_topology_lines():
         print_fn(line)
 
@@ -1021,9 +1020,7 @@ def run_benchmark(
         # diff` can name a world-size change across a resume boundary
         manifest_extra: dict = {"topology": topo_rec}
         if compile_cache_dir:
-            manifest_extra["compile_cache"] = {
-                "dir": compile_cache_dir,
-                "entries_before": cache_entries_before}
+            manifest_extra["compile_cache"] = {"dir": compile_cache_dir}
         if cfg.variable_update == "zero1":
             # manifest-noted checkpoint policy: single-process zero1
             # saves gather the sharded optimizer state to host
@@ -1602,8 +1599,9 @@ def run_benchmark(
         # SYNCHRONOUSLY here (its compiled handle also serves the MFU
         # probe — one compile, both measurements) and the verdict
         # prints at run start.
-        flops_probe = obs_efficiency.StepFlopsProbe(
-            train_step, state, warm_batch, rng, background=False)
+        with timeline_mod.setup("probe"):
+            flops_probe = obs_efficiency.StepFlopsProbe(
+                train_step, state, warm_batch, rng, background=False)
         budget_bytes, budget_note = obs_memory.resolve_hbm_budget_bytes(
             hbm_budget)
         mem_an = flops_probe.memory_analysis()
@@ -1646,20 +1644,19 @@ def run_benchmark(
         f"{warmup_elapsed:.1f}s (includes compile)"
     )
     if compile_cache_dir:
-        # hit/miss accounting: entries that appeared during warmup are
-        # the compiles this run actually paid for; zero new entries over
-        # a non-empty cache is a warm start (the ledger's compile phase
-        # shows the wall-clock consequence)
-        cache_entries_after = compile_cache.entry_count(compile_cache_dir)
-        cache_new = cache_entries_after - cache_entries_before
-        cache_warm = cache_new == 0 and cache_entries_before > 0
+        # hit/miss accounting: the backend compiles of this run's set-up
+        # so far by what the persistent cache did — written (the
+        # compiles this run paid for and the next one loads), loaded;
+        # none written and some loaded is a warm start (the ledger's
+        # compile phase shows the wall-clock consequence)
+        account = timeline_mod.setup_account()
+        cache_new, cache_loaded = (account[k] - account_before[k]
+                                   for k in ("compiled", "loaded"))
+        cache_warm = cache_new == 0 and cache_loaded > 0
         print_fn(f"compile cache: {cache_new} new entr"
-                 f"{'y' if cache_new == 1 else 'ies'} "
-                 f"({'warm start' if cache_warm else 'cold/partial'}); "
-                 f"{cache_entries_after} total")
-        cache_rec = {"dir": compile_cache_dir,
-                     "entries_before": cache_entries_before,
-                     "entries_after": cache_entries_after,
+                 f"{'y' if cache_new == 1 else 'ies'}, {cache_loaded} "
+                 f"loaded ({'warm start' if cache_warm else 'cold/partial'})")
+        cache_rec = {"dir": compile_cache_dir, "loaded": cache_loaded,
                      "new_entries": cache_new, "warm": cache_warm}
         obs_writer.event("compile_cache", **cache_rec)
         obs_writer.update_manifest({"compile_cache": cache_rec})

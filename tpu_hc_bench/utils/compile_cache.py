@@ -16,12 +16,19 @@ compiles:
 
 ``--compile_cache=off`` opts a run out of the accounting (no banner, no
 manifest record); it is the only value the flag takes.
+
+``resolve`` also starts the process's one ``jax.monitoring`` listener
+(``listen``): it counts backend compiles (``process_compiles``) and
+forwards JAX's compile and cache events to the set-up account
+(``obs.timeline.setup_account``), whose outcomes the banners read.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+
+from tpu_hc_bench.obs import timeline
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
@@ -43,9 +50,11 @@ def check_flag(spec: str | None) -> None:
 
 def resolve(spec: str | None) -> str | None:
     """The active cache dir (created), or None under ``off``.  Call
-    before anything lowers."""
+    before anything lowers: it also starts the process's compile
+    listener, so that the set-up account sees every compile."""
     import jax
 
+    listen()
     check_flag(spec)
     if spec is not None:
         return None
@@ -61,37 +70,58 @@ def resolve(spec: str | None) -> str | None:
     return cache_dir
 
 
-def entry_count(cache_dir: str) -> int:
-    """Files under the cache dir — the hit/miss denominator: entries
-    that appear between run start and end-of-warmup are the compiles
-    this run paid for."""
-    return sum(len(files) for _, _, files in os.walk(cache_dir))
-
-
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+#: JAX's compile events, as time spans -> the set-up account's fields
+_SPAN_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+#: the persistent cache's events inside a backend compile: a hit, and
+#: an entry written (JAX names the write ``cache_misses``)
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "loaded",
+                 "/jax/compilation_cache/cache_misses": "compiled"}
 _compiles = {"n": 0, "listening": False}
 _compiles_lock = threading.Lock()
 
 
-def _on_compile_event(event: str, duration: float, **kw) -> None:
-    if event == _BACKEND_COMPILE:
+def _on_span(event: str, start: float, end: float, **kw) -> None:
+    field = _SPAN_EVENTS.get(event)
+    if field is None:
+        return
+    if field == "compile_s":
         with _compiles_lock:
             _compiles["n"] += 1
+    timeline.setup_event(field, start, end)
+
+
+def _on_event(event: str, **kw) -> None:
+    outcome = _CACHE_EVENTS.get(event)
+    if outcome is not None:
+        timeline.setup_cache(outcome)
+
+
+def listen() -> None:
+    """Start the process's one compile listener (once): it counts
+    backend compiles (``process_compiles``) and forwards JAX's trace,
+    lowering, backend-compile and cache events to the set-up account
+    (``obs.timeline.setup_account``)."""
+    with _compiles_lock:
+        if _compiles["listening"]:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_time_span_listener(_on_span)
+        jax.monitoring.register_event_listener(_on_event)
+        _compiles["listening"] = True
 
 
 def process_compiles() -> int:
     """Programs THIS process has compiled or fetched from the persistent
-    cache, by JAX's own backend-compile events, counted from the first
-    call (which starts the one listener a process keeps): a difference of
-    two calls is the compiles between them.  Unlike ``entry_count`` it
-    sees no other process's entries in a shared cache dir, counts
-    compiles under ``--compile_cache=off`` too, and counts nothing that
-    only reached the cache earlier."""
+    cache, by JAX's own backend-compile events, counted from the start
+    of the listener (``resolve``, or the first call here): a difference
+    of two calls is the compiles between them.  It sees no other
+    process's entries in a shared cache dir, and counts compiles under
+    ``--compile_cache=off`` too."""
+    listen()
     with _compiles_lock:
-        if not _compiles["listening"]:
-            import jax.monitoring
-
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_compile_event)
-            _compiles["listening"] = True
         return _compiles["n"]
